@@ -27,8 +27,10 @@ integration test strategy (SURVEY.md section 4).
 import jax as _jax
 
 # Spark semantics require 64-bit integers (LongType, TimestampType) and
-# float64 (DoubleType). TPU v5 executes both (f64 via emulation), verified
-# at import in runtime/device_manager.py.
+# float64 (DoubleType). TPU v5e emulates int64 exactly and runs f64
+# arithmetic at reduced precision (docs/compatibility.md); what its
+# compiler still refuses (64-bit bitcast) is pinned by
+# tests/test_chip_compile.py.
 _jax.config.update("jax_enable_x64", True)
 
 __version__ = "0.1.0"

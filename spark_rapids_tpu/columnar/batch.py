@@ -376,8 +376,7 @@ def make_column(dtype: DataType, values: np.ndarray,
                 elem_validity: Optional[np.ndarray] = None) -> DeviceColumn:
     """Build a column from host numpy data, padding to capacity. The
     returned column holds NUMPY leaves — the caller uploads the whole
-    batch with ONE jax.device_put (per-array jnp.asarray costs ~6x in
-    transfer setup, and far more over tunneled devices).
+    batch with ONE jax.device_put instead of one transfer per array.
 
     For strings, `values` is a [n, max_bytes] uint8 matrix and `lengths`
     the per-row byte counts. For arrays, `values` is [n, max_elems] of

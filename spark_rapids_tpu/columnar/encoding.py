@@ -1,8 +1,8 @@
 """Dictionary-encoded device columns — compressed execution.
 
-BENCH_r05 measured roofline_frac ~ 0.006 behind a 0.11 GB/s
-host->device link; ROADMAP item 2 names the lever: move fewer bytes by
-executing over compressed, device-resident data ("GPU Acceleration of
+The round-5 review measured a hot query at roofline_frac ~ 0.006;
+one lever is to move fewer bytes by executing over compressed,
+device-resident data ("GPU Acceleration of
 SQL Analytics on Compressed Data", PAPERS.md). This module makes
 dictionary encoding a first-class device representation:
 

@@ -1,15 +1,23 @@
 """ctypes bindings for the native host runtime (native/sparktpu_runtime.cpp)
 — the engine's replacement for the reference's cuDF-Java/JNI host surface
 (SURVEY.md section 2.12). Built on demand with g++ (no pybind11 in this
-image); everything degrades to pure-Python fallbacks when the toolchain
-is unavailable so the engine never hard-depends on the native path.
+image) from the committed source, ON the machine that runs it: the
+output is keyed by the source's hash and the host's CPU, so a library
+built with `-march=native` elsewhere and carried along in the
+git-ignored build directory is never loaded. Everything degrades to
+pure-Python fallbacks when the toolchain is unavailable so the engine
+never hard-depends on the native path; `runtime_in_use()` says which
+one a process got.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
+import tempfile
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -23,7 +31,6 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "sparktpu_runtime.cpp")
 _OUT_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_SO = os.path.join(_OUT_DIR, "libsparktpu.so")
 
 u8p = ctypes.POINTER(ctypes.c_uint8)
 i32p = ctypes.POINTER(ctypes.c_int32)
@@ -53,23 +60,54 @@ def compile_runtime(src: str, out_so: str, timeout: int = 120,
         return None
 
 
+def _build_key() -> str:
+    """What a `-march=native` build of the committed source depends on:
+    the source bytes and this host's CPU (model + feature flags)."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(platform.machine().encode())
+    try:
+        seen = set()
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                name = line.split(":", 1)[0].strip()
+                if name in ("model name", "flags", "Features") \
+                        and name not in seen:
+                    seen.add(name)
+                    h.update(line.encode())
+    except OSError:
+        h.update(platform.processor().encode())
+    return h.hexdigest()[:16]
+
+
 def _build() -> Optional[str]:
-    # prebuilt library shipped inside the wheel (setup.py build_py)
+    # prebuilt library shipped inside the wheel (setup.py build_py,
+    # portable flags)
     packaged = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "libsparktpu.so")
     if os.path.exists(packaged):
         return packaged
     try:
-        os.makedirs(_OUT_DIR, exist_ok=True)
-        if os.path.exists(_SO) and (
-                not os.path.exists(_SRC) or
-                os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            return _SO
-        if not os.path.exists(_SRC):
-            return None
+        so_dir = os.path.join(_OUT_DIR, _build_key())
+        so = os.path.join(so_dir, "libsparktpu.so")
+        if os.path.exists(so):
+            return so
+        os.makedirs(so_dir, exist_ok=True)
+        # several processes (test workers) may build at once: each
+        # compiles to its own file and renames it into place
+        fd, tmp = tempfile.mkstemp(dir=so_dir, suffix=".so.tmp")
+        os.close(fd)
     except OSError:
-        return None
-    return compile_runtime(_SRC, _SO)
+        return None  # no source, or no place to build
+    try:
+        if compile_runtime(_SRC, tmp) is None:
+            return None
+        os.replace(tmp, so)
+        return so
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _declare(lib):
@@ -148,6 +186,12 @@ def get_lib():
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def runtime_in_use() -> str:
+    """"native" when the C++ runtime loaded (building it if needed),
+    "python" when this process runs the pure-Python fallbacks."""
+    return "native" if available() else "python"
 
 
 # ----------------------------------------------------------- wire format

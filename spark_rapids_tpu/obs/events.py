@@ -53,7 +53,8 @@ EVENT_TYPES: Dict[str, str] = {
     "telemetry.summary":
         "bytesMoved, bytesMovedTotal, hbmPeakBytes, rooflineFrac, "
         "linkFrac, bytesPerOutputRow, wallMs",
-    "compile": "kind (miss|hit|warm|quarantine), seconds",
+    "compile": "kind (miss|hit|warm|quarantine|warmRebuild|"
+               "exportFailed), seconds, error",
     "degrade": "kind, from, to, reason",
     "chaos": "site",
     "admission.queued": "queryId, depth, running",

@@ -124,7 +124,8 @@ def profile_data(source: Source, top_n: int = 10) -> dict:
     shuffle = {"bytesWritten": 0, "bytesFetched": 0, "writes": 0,
                "fetches": 0, "retries": 0}
     spill = {"toHostBytes": 0, "toDiskBytes": 0, "unspillBytes": 0}
-    compile_c = {"miss": 0, "hit": 0, "warm": 0, "quarantine": 0}
+    compile_c = {"miss": 0, "hit": 0, "warm": 0, "quarantine": 0,
+                 "warmRebuild": 0, "exportFailed": 0}
     recovery = {"attempts": 0, "retried": 0, "speculated": 0,
                 "discarded": 0, "lost": 0, "failed": 0,
                 "degradations": 0, "chaosInjections": 0}

@@ -1,7 +1,7 @@
 """Data-movement telemetry: transfer ledger, HBM occupancy, roofline.
 
-BENCH_r05 measured roofline_frac ~ 0.006 over a 0.11 GB/s host->device
-link — and every planned optimization (ICI-resident shuffle, compressed
+The round-5 review measured a hot query at roofline_frac ~ 0.006, and
+every planned optimization (ICI-resident shuffle, compressed
 execution, out-of-core streaming) is a bytes-moved optimization. The
 reference stack's profiling tool attributes transfer volume per
 operator to drive exactly that tuning loop; this module is the engine's
@@ -26,10 +26,13 @@ equivalent measurement substrate:
 
 - **Roofline accounting**: `link_peaks()` measures the H2D/D2H link
   once per process (a timed `device_put`/`device_get` of a fixed
-  buffer) and reads the device HBM peak bandwidth from the public spec
-  table; the result is cached as JSON inside the compile cache's
-  VERSIONED directory (runtime/compile_cache.py) so a backend/version
-  switch re-probes and a warm process never pays the probe.
+  buffer, and the round trip of one small dispatch + fetch) and reads
+  the device HBM peak bandwidth from the public spec table — a device
+  kind the table does not list is an error, never a default. The
+  result is cached as JSON beside the compile cache's index
+  (runtime/compile_cache.py), stamped with the device kind it was
+  measured on, so another kind re-probes and a warm process never pays
+  the probe.
   `query_summary()` combines the peaks with the per-query ledger into
   `rooflineFrac` (achieved bytes/s over the query wall time vs the
   device HBM peak — the same definition bench.py has always used),
@@ -65,8 +68,10 @@ from spark_rapids_tpu.obs import events as _events
 DIRECTIONS = ("h2d", "d2h", "spill-disk", "shuffle", "ici", "dcn")
 
 #: Peak HBM bandwidth per chip, bytes/s (public TPU specs; the cpu
-#: backend gets a nominal DDR figure so fractions stay meaningful).
-#: bench.py reads this table too — one source of truth.
+#: backend the tests run on gets a nominal DDR figure so fractions stay
+#: defined there). bench.py and chip_smoke.py read this table too — one
+#: source of truth. A kind that is not listed is an error
+#: (`device_peak_bw`): add the row with its source, do not default.
 DEVICE_PEAK_BW = {
     "TPU v4": 1.2e12,
     "TPU v5e": 8.19e11,
@@ -77,6 +82,7 @@ DEVICE_PEAK_BW = {
 }
 
 _PROBE_BYTES = 8 << 20          # link probe transfer size
+_PROBE_REPEATS = 5              # timed repeats per direction (median)
 _QUERY_KEEP = 64                # per-query ledgers retained
 _TIMELINE_KEEP = 4096           # (ts, reservedBytes) samples retained
 _INTERVAL_KEEP = 4096           # per-query busy intervals per kind
@@ -666,10 +672,34 @@ _peaks_lock = threading.Lock()
 _PEAKS_FILE = "telemetry_peaks.json"
 
 
-def _device_peak_bw(kind: str) -> float:
-    return next((v for k, v in DEVICE_PEAK_BW.items()
-                 if k.lower() in str(kind).lower()),
-                DEVICE_PEAK_BW["cpu"])
+def device_peak_bw(kind: str) -> float:
+    """Spec-table HBM peak for a `device_kind`; KeyError for a kind the
+    table does not list (a CPU's peak under a chip's name is the wrong
+    answer, not a safe one)."""
+    for k, v in DEVICE_PEAK_BW.items():
+        if k.lower() in str(kind).lower():
+            return v
+    raise KeyError(
+        f"device kind {kind!r} is not in obs.telemetry.DEVICE_PEAK_BW "
+        f"({sorted(DEVICE_PEAK_BW)}): add its published peak HBM "
+        f"bandwidth there")
+
+
+def require_tpu(who: str):
+    """The device `who` measures, or SystemExit: a measurement path
+    that finds no accelerator fails, it does not fall back to the CPU
+    (the chip is touched by the calling process only — no probing
+    child). Nothing goes to stdout: off the chip there is no result."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"{who} measures a TPU; JAX found platform "
+            f"{dev.platform!r} ({dev.device_kind}). Tests and "
+            f"rehearsals run on the CPU (README 'Running'); a number "
+            f"comes from a chip run only.")
+    return dev
 
 
 def _peaks_path() -> Optional[str]:
@@ -678,41 +708,66 @@ def _peaks_path() -> Optional[str]:
     root = compile_cache.cache_dir()
     if root is None:
         return None
-    # the versioned dir: _check_version_stamp wipes it (and this file)
-    # whenever the jax/jaxlib/plugin/backend tuple changes, which is
-    # exactly the set of events that invalidates a link measurement
     return os.path.join(root, _PEAKS_FILE)
 
 
+def _device_kind() -> str:
+    import jax
+
+    dev = jax.devices()[0]
+    return str(getattr(dev, "device_kind", dev.platform))
+
+
 def _probe_link() -> dict:
-    """Measure the host<->device link once: a timed device_put (H2D)
-    and device_get (D2H) of a fixed buffer, plus the device HBM peak
-    from the spec table."""
+    """Measure the host<->device link: device_put (H2D) and device_get
+    (D2H) of a fixed buffer and the round trip of one small dispatch +
+    fetch — each the median of a few timed repeats AFTER one untimed
+    pass (the first transfer and the first dispatch of a process pay
+    one-time set-up and the slice's compile) — plus the device HBM
+    peak from the spec table."""
+    import statistics
+
     import jax
     import numpy as np
 
-    dev = jax.devices()[0]
-    kind = str(getattr(dev, "device_kind", dev.platform))
+    kind = _device_kind()
+    peak = device_peak_bw(kind)
     buf = np.zeros(_PROBE_BYTES // 8, dtype=np.float64)
-    t0 = time.perf_counter()
-    on_dev = jax.block_until_ready(jax.device_put(buf))
-    h2d_s = max(time.perf_counter() - t0, 1e-9)
-    t0 = time.perf_counter()
+
+    def timed(fn):
+        t0 = time.perf_counter()
+        out = fn()
+        return out, max(time.perf_counter() - t0, 1e-9)
+
+    def put():
+        return jax.block_until_ready(jax.device_put(buf))
+
+    on_dev = put()
     jax.device_get(on_dev)
-    d2h_s = max(time.perf_counter() - t0, 1e-9)
+    jax.device_get(on_dev[:8])
+    h2d, d2h, trips = [], [], []
+    for _ in range(_PROBE_REPEATS):
+        on_dev, dt = timed(put)
+        h2d.append(dt)
+        d2h.append(timed(lambda: jax.device_get(on_dev))[1])
+        trips.append(timed(lambda: jax.device_get(on_dev[:8]))[1])
     return {
         "deviceKind": kind,
-        "devicePeakBytesPerS": _device_peak_bw(kind),
-        "h2dBytesPerS": round(buf.nbytes / h2d_s, 1),
-        "d2hBytesPerS": round(buf.nbytes / d2h_s, 1),
+        "devicePeakBytesPerS": peak,
+        "h2dBytesPerS": round(buf.nbytes / statistics.median(h2d), 1),
+        "d2hBytesPerS": round(buf.nbytes / statistics.median(d2h), 1),
+        "roundTripMs": round(statistics.median(trips) * 1000, 4),
         "probeBytes": buf.nbytes,
+        "probeRepeats": _PROBE_REPEATS,
     }
 
 
 def link_peaks(refresh: bool = False) -> dict:
     """Measured link + device peaks, probed once and cached — first in
     process memory, then (when the compile cache is configured) as JSON
-    in its versioned directory so restarted processes skip the probe."""
+    beside its index so restarted processes skip the probe. A cached
+    file counts only for the device kind it was measured on: CPU
+    rehearsals and chip runs may share one cache directory."""
     global _peaks
     with _peaks_lock:
         if _peaks is not None and not refresh:
@@ -722,20 +777,14 @@ def link_peaks(refresh: bool = False) -> dict:
             try:
                 with open(path) as f:
                     loaded = json.load(f)
-                if isinstance(loaded, dict) and "devicePeakBytesPerS" \
-                        in loaded:
+                if (isinstance(loaded, dict)
+                        and loaded.get("deviceKind") == _device_kind()
+                        and "roundTripMs" in loaded):
                     _peaks = loaded
                     return _peaks
             except (OSError, ValueError):
                 pass
-        try:
-            _peaks = _probe_link()
-        except Exception:
-            # no backend (stubbed jax, probe crash): spec-table only
-            _peaks = {"deviceKind": "unknown",
-                      "devicePeakBytesPerS": DEVICE_PEAK_BW["cpu"],
-                      "h2dBytesPerS": 0.0, "d2hBytesPerS": 0.0,
-                      "probeBytes": 0}
+        _peaks = _probe_link()
         if path is not None:
             try:
                 tmp = path + ".tmp"

@@ -435,6 +435,10 @@ class MeshQueryExecutor:
                          if conf is not None
                          else rc.MULTICHIP_EXPANSION.default)
         self._expansion = max(1, int(expansion))
+        #: ids of the devices that held the last run's result shards
+        #: (session.last_execution["meshDevices"]): on real chips the
+        #: proof that the program spread out and did not land on one
+        self.result_devices: List[int] = []
 
     #: (n_devices, chip_epoch) -> Mesh. Keyed by the chip epoch so a
     #: fence/unfence never hands back a mesh laid out over a dead chip;
@@ -1239,6 +1243,9 @@ class MeshQueryExecutor:
             jax.block_until_ready(jax.tree_util.tree_leaves(out))
         finally:
             tape = collective.end_ici_tape()
+        self.result_devices = sorted(
+            int(s.device.id)
+            for s in out.columns[0].data.addressable_shards)
         if tape:
             # first call traced the program: persist the static
             # per-shard collective bytes for replay on cache hits

@@ -2,35 +2,47 @@
 
 The structural jit cache (runtime/jit_cache.py) evaporates with the
 process, so a fresh session pays full XLA compilation for every fused
-program variant — BENCH round 5 measured 482 s of cold start against a
-7.7 s CPU cold read, almost all of it compilation of the multiplied
-fused-program variants. The reference pays no such tax (cuDF kernels
-are precompiled); Theseus (arxiv 2508.05029) and the Presto-on-GPU
-work treat time-to-first-query as a first-class engine metric. This
-module is the XLA-native answer, three layers deep:
+program variant — the round-5 review measured 482 s of cold start
+against a 7.7 s CPU cold read, almost all of it compilation of the
+multiplied fused-program variants. The reference pays no such tax
+(cuDF kernels are precompiled); Theseus (arxiv 2508.05029) and the
+Presto-on-GPU work treat time-to-first-query as a first-class engine
+metric. This module is the XLA-native answer, three layers deep:
 
-1. DISK-BACKED PROGRAM CACHE — JAX's persistent compilation cache is
-   pointed at a versioned engine directory, so any process re-tracing
-   a structurally identical program loads the serialized XLA
-   executable instead of recompiling (tracing is host seconds;
-   compilation was the minutes). Entry keys are XLA's own
-   (HLO + compile options + jaxlib build), so cross-version collisions
-   are impossible by construction.
+1. DISK-BACKED PROGRAM CACHE — JAX's persistent compilation cache, so
+   any process re-tracing a structurally identical program loads the
+   serialized XLA executable instead of recompiling (tracing is host
+   seconds; compilation was the minutes). Entry keys are XLA's own
+   (HLO + compile options + jaxlib build + target device), so
+   cross-version and cross-backend collisions are impossible by
+   construction and CPU rehearsals share a directory with chip runs.
 
 2. KEY -> ARTIFACT INDEX — our own index over the structural keys
-   (Expression.key() trees + schema + _env_token()): per-program hit
-   counts, compile seconds, and (for fused whole-stage programs) a
-   serialized `jax.export` artifact. The index is stamped with the
-   jax/jaxlib/plugin/backend version tuple and WIPED on any mismatch
-   (stale-artifact invalidation); every write is
+   (Expression.key() trees + schema + _env_token(), which names the
+   backend): per-program hit counts, compile seconds, and (for fused
+   whole-stage programs) a serialized `jax.export` artifact. The index
+   is stamped with the jax/jaxlib/plugin version tuple and WIPED on any
+   mismatch (stale-artifact invalidation); every write is
    write-temp-then-rename so concurrent sessions never observe torn
    entries, and artifacts carry the full key repr so a digest
    collision is detected at load instead of serving a wrong program.
 
 3. ASYNC WARMUP — a conf-gated background thread AOT-compiles the
-   top-K most-used artifacts from prior runs while the first scan's
-   decode/upload I/O is in flight; `cached_jit` then serves the
-   ready executable, skipping even re-tracing for the hot programs.
+   top-K most-used artifacts THIS backend recorded in prior runs while
+   the first scan's decode/upload I/O is in flight; `cached_jit` then
+   serves the ready executable, skipping even re-tracing for the hot
+   programs.
+
+WHERE IT LIVES is decided from outside, because the path is part of
+what makes a cache hit (`resolve_dirs`):
+
+- `JAX_COMPILATION_CACHE_DIR` set: JAX's cache lives exactly there —
+  JAX reads the variable itself and this module sets no other path —
+  and the index + artifacts go in its `srtpu/` sub-directory.
+- else `spark.rapids.tpu.compileCache.dir`: JAX's cache in `<dir>/xla`.
+- else a fixed, git-ignored directory inside the checkout
+  (`FIXED_DIR`). Never the system temp directory, a pid or a time: a
+  cache that moves never hits.
 
 Observability rides along: a process-wide `CompileStats` ledger
 (programs compiled / cache hits / warm hits / compile seconds) that
@@ -68,6 +80,13 @@ class CompileStats:
         self.warm_hits = 0             # artifact-served programs
         self.compile_seconds = 0.0     # trace+compile time of builds
         self.artifacts_quarantined = 0  # corrupt entries set aside
+        self.warm_rebuilds = 0         # warm executables that failed
+        #                                to run and were rebuilt live
+        self.export_failures = 0       # fused programs jax.export
+        #                                could not turn into artifacts
+        self.xla_cache_hits = 0        # jax's disk cache served the
+        #                                executable (layer 1)
+        self.xla_cache_misses = 0      # XLA compiled it and wrote it
 
     @staticmethod
     def _emit(kind: str, **fields) -> None:
@@ -96,6 +115,27 @@ class CompileStats:
             self.artifacts_quarantined += 1
         self._emit("quarantine")
 
+    def on_warm_rebuild(self, error: str) -> None:
+        with self._lock:
+            self.warm_rebuilds += 1
+        self._emit("warmRebuild", error=error)
+
+    def on_export_failure(self, error: str) -> None:
+        with self._lock:
+            self.export_failures += 1
+        self._emit("exportFailed", error=error)
+
+    def on_jax_event(self, event: str, **_kw) -> None:
+        """jax.monitoring listener: layer 1 says for itself whether a
+        build in this process was an XLA compile or a disk load — the
+        difference between `programsCompiled` and minutes."""
+        if event == "/jax/compilation_cache/cache_hits":
+            with self._lock:
+                self.xla_cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            with self._lock:
+                self.xla_cache_misses += 1
+
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
             return {
@@ -104,6 +144,10 @@ class CompileStats:
                 "warmHits": self.warm_hits,
                 "compileSeconds": round(self.compile_seconds, 3),
                 "artifactsQuarantined": self.artifacts_quarantined,
+                "warmRebuilds": self.warm_rebuilds,
+                "artifactExportFailures": self.export_failures,
+                "xlaCacheHits": self.xla_cache_hits,
+                "xlaCacheMisses": self.xla_cache_misses,
             }
 
     @staticmethod
@@ -126,13 +170,15 @@ _warm_lock = threading.Lock()
 _warmup_thread: Optional[threading.Thread] = None
 _warmed_dir: Optional[str] = None   # warmup ran for this dir already
 _export_serialization_ready = False
+_jax_listener_installed = False
 
 
 def version_token() -> Dict[str, str]:
     """Everything that invalidates serialized artifacts: jax traces
-    differently across versions, jaxlib executables are ABI-bound, the
-    plugin's lowerings change per release, and a backend switch changes
-    every program."""
+    differently across versions, jaxlib executables are ABI-bound, and
+    the engine's lowerings change per release. The backend is NOT here:
+    every index key and XLA cache key already names it, so a CPU
+    rehearsal and a chip run keep their entries side by side."""
     import jax
     import jaxlib
 
@@ -142,7 +188,6 @@ def version_token() -> Dict[str, str]:
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "plugin": getattr(spark_rapids_tpu, "__version__", "0"),
-        "backend": jax.default_backend(),
     }
 
 
@@ -153,8 +198,28 @@ def key_digest(full_key: Tuple) -> str:
     return hashlib.sha256(repr(full_key).encode()).hexdigest()[:32]
 
 
-def default_dir() -> str:
-    return os.path.join(tempfile.gettempdir(), "srtpu_compile_cache")
+#: Where the cache lives when nothing outside says otherwise: inside
+#: the checkout (listed in .gitignore), the same path in every run.
+FIXED_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".srtpu_compile_cache")
+
+_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def resolve_dirs(conf=None) -> Tuple[str, Optional[str]]:
+    """-> (root of index + artifacts, directory to point JAX's cache at
+    or None when JAX already has it from the environment). Precedence:
+    the environment variable, the conf entry, the fixed path."""
+    from spark_rapids_tpu.config import rapids_conf as rc
+
+    env = os.environ.get(_ENV_DIR)
+    if env:
+        return os.path.join(os.path.abspath(env), "srtpu"), None
+    root = os.path.abspath(
+        (conf.get(rc.COMPILE_CACHE_DIR) if conf is not None else "")
+        or FIXED_DIR)
+    return root, os.path.join(root, "xla")
 
 
 def enabled() -> bool:
@@ -192,9 +257,11 @@ def _atomic_write(path: str, data: bytes) -> None:
 
 
 def _check_version_stamp(root: str) -> None:
-    """Wipe index + artifacts + XLA entries on any version-tuple
-    mismatch; stamp the current tuple. A second process racing the wipe
-    at worst re-wipes — entries are re-creatable by definition."""
+    """Wipe index + artifacts on any version-tuple mismatch; stamp the
+    current tuple. JAX's own entries stay: their keys carry the jaxlib
+    build, and the directory may be the user's. A second process
+    racing the wipe at worst re-wipes — entries are re-creatable by
+    definition."""
     stamp = os.path.join(root, "VERSION.json")
     tok = version_token()
     try:
@@ -203,7 +270,7 @@ def _check_version_stamp(root: str) -> None:
                 return
     except (OSError, ValueError):
         pass
-    for sub in ("index", "artifacts", "xla"):
+    for sub in ("index", "artifacts"):
         shutil.rmtree(os.path.join(root, sub), ignore_errors=True)
     _atomic_write(stamp, json.dumps(tok).encode())
 
@@ -218,23 +285,22 @@ def configure(conf=None) -> None:
         _artifact_min_s = conf.get(rc.COMPILE_CACHE_ARTIFACT_MIN_S)
     if conf is not None and not conf.get(rc.COMPILE_CACHE_ENABLED):
         with _lock:
-            if _configured_dir is not None:
+            if _configured_dir is not None and \
+                    not os.environ.get(_ENV_DIR):
                 import jax
 
                 jax.config.update("jax_compilation_cache_dir", None)
             _configured_dir = None
         return
-    root = (conf.get(rc.COMPILE_CACHE_DIR) if conf is not None
-            else "") or default_dir()
-    root = os.path.abspath(root)
+    root, xla_dir = resolve_dirs(conf)
     with _lock:
         already = _configured_dir == root
         if not already:
             os.makedirs(root, exist_ok=True)
             _check_version_stamp(root)
-            for sub in ("index", "artifacts", "xla"):
+            for sub in ("index", "artifacts"):
                 os.makedirs(os.path.join(root, sub), exist_ok=True)
-            _enable_jax_persistent_cache(os.path.join(root, "xla"))
+            _enable_jax_persistent_cache(xla_dir)
             _configured_dir = root
         if _saver is None:
             _saver = _AsyncSaver()
@@ -242,20 +308,24 @@ def configure(conf=None) -> None:
         start_warmup(conf.get(rc.COMPILE_CACHE_WARMUP_TOP_K))
 
 
-def _enable_jax_persistent_cache(xla_dir: str) -> None:
+def _enable_jax_persistent_cache(xla_dir: Optional[str]) -> None:
     """Layer 1: every XLA compile (eager operators included) round-trips
-    through jax's disk cache. min thresholds drop to zero — cold start
-    is the SUM of many sub-second compiles, so the defaults' 1 s floor
-    would leave most of the tax in place."""
+    through jax's disk cache. `xla_dir` None = JAX took its directory
+    from JAX_COMPILATION_CACHE_DIR and no other path is set here. min
+    thresholds drop to zero — cold start is the SUM of many sub-second
+    compiles, so the defaults' 1 s floor would leave most of the tax in
+    place."""
+    global _jax_listener_installed
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", xla_dir)
-    for k, v in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                 ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(k, v)
-        except (AttributeError, ValueError):  # older jax: keep floors
-            pass
+    if xla_dir is not None:
+        os.makedirs(xla_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", xla_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if not _jax_listener_installed:  # under _lock (configure)
+        jax.monitoring.register_event_listener(stats.on_jax_event)
+        _jax_listener_installed = True
 
 
 # ------------------------------------------------------------- index
@@ -286,9 +356,15 @@ def read_index() -> Dict[str, Dict[str, Any]]:
 
 def _record_index(digest: str, key_repr: str, tag: str,
                   seconds: float, has_artifact: bool) -> None:
+    import jax
+
     path = _index_path(digest)
+    # the backend the program was traced for: warmup must not feed one
+    # backend's exported artifact to another (it would fail to compile
+    # there and be quarantined, in silence, for both)
     entry = {"key": key_repr, "tag": tag, "count": 0,
-             "compile_s": 0.0, "artifact": has_artifact}
+             "compile_s": 0.0, "artifact": has_artifact,
+             "backend": jax.default_backend()}
     try:
         with open(path) as f:
             prev = json.load(f)
@@ -374,8 +450,11 @@ class _AsyncSaver(threading.Thread):
             _register_export_serialization()
             exp = jex.export(jitted)(*avals)
             blob = exp.serialize()
-        except Exception:
-            return False  # program outside export's subset: index-only
+        except Exception as e:
+            # program outside export's subset: index-only — counted,
+            # because a layer that cannot record cannot warm either
+            stats.on_export_failure(f"{type(e).__name__}: {e}"[:200])
+            return False
         _atomic_write(os.path.join(_artifact_dir(), digest + ".key"),
                       key_repr.encode())
         _atomic_write(os.path.join(_artifact_dir(), digest + ".bin"),
@@ -493,8 +572,11 @@ def warmup_join(timeout: Optional[float] = None) -> None:
 
 
 def _warmup_run(top_k: int) -> None:
+    import jax
+
+    backend = jax.default_backend()
     entries = [(d, e) for d, e in read_index().items()
-               if e.get("artifact")]
+               if e.get("artifact") and e.get("backend") == backend]
     entries.sort(key=lambda de: (-int(de[1].get("count", 0)), de[0]))
     for digest, entry in entries[:top_k]:
         try:

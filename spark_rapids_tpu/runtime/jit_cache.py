@@ -111,10 +111,13 @@ def _make_entry(full: Tuple, key: Tuple, build: Callable[[], Callable],
         if warm is not None and state["jitted"] is None:
             try:
                 return warm(*args, **kwargs)
-            except Exception:
+            except Exception as e:
                 # aval/env drift between the recording and this
-                # process: rebuild live, never fail the query
-                pass
+                # process: rebuild live, never fail the query — but
+                # count it (and time the rebuild like any compile), so
+                # a warm layer that never serves shows
+                cc.stats.on_warm_rebuild(f"{type(e).__name__}: {e}"[:200])
+                state["timed"] = False
         fn = state["jitted"]
         if fn is not None and state["timed"]:
             return fn(*args, **kwargs)

@@ -4,11 +4,13 @@ The reference supports 24 Spark versions by compiling per-version
 "parallel worlds" source trees and mounting the right one at runtime
 (sql-plugin-api/.../ShimLoader.scala:182, SparkShimServiceProvider SPI,
 build/shimplify.py). The moving target here is JAX, whose public API
-shifted across releases (shard_map moved from jax.experimental to the
+shifts across releases (shard_map moved from jax.experimental to the
 jax namespace and renamed check_rep -> check_vma, among others). Each
 shim module is a provider declaring which jax versions it serves; the
 loader probes providers at first use and every caller goes through the
-selected world.
+selected world. One installation is supported and pinned
+(pyproject.toml), so there is one world; a version no provider serves
+raises ShimError instead of running untested.
 
 Adding support for a new jax release = adding one provider module, the
 same mechanics as adding a spark3xx world in the reference.
@@ -21,7 +23,6 @@ from typing import List, Optional
 
 _PROVIDERS = (
     "spark_rapids_tpu.shims.jax_current",
-    "spark_rapids_tpu.shims.jax_legacy",
 )
 
 # Every provider must export exactly this surface (api_validation

@@ -1,8 +1,9 @@
-"""Shim world for jax >= 0.6: `jax.shard_map` with `check_vma`."""
+"""Shim world for the installed jax (0.9): `jax.shard_map` with
+`check_vma`."""
 
 from __future__ import annotations
 
-VERSIONS = ("0.6", "0.7", "0.8", "0.9", "1.")
+VERSIONS = ("0.9.",)
 
 
 def matches(version: str) -> bool:
@@ -10,7 +11,7 @@ def matches(version: str) -> bool:
 
 
 def description() -> str:
-    return "jax.shard_map world (jax >= 0.6)"
+    return "jax.shard_map world (jax 0.9)"
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check: bool = False):

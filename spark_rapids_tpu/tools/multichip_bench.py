@@ -1,30 +1,26 @@
-"""Multichip scaling bench: REAL q5 throughput at 1/2/4/8 shards.
+"""Multichip scaling bench: q5 throughput by shard count, on real chips.
 
-The measurement ROADMAP item 1 asks for: the q5 join+agg shape executed
-at increasing shard counts with the mesh SPMD engine (hash exchanges
-compiled to on-device all-to-all over ICI, encoded codes on the wire),
-against the incumbent single-chip engine at its DEFAULT configuration
-(fused stage compiler, host-serialized MULTITHREADED shuffle).
-Scaling is reported as ``throughput(mesh@n) / throughput(single@1)``:
-the speedup a query sees when its execution spreads over n chips and
-its shuffles stop leaving the device fabric.
+The q5 join+agg shape executed at increasing shard counts with the
+mesh SPMD engine (hash exchanges compiled to on-device all-to-all over
+ICI, encoded codes on the wire), against the incumbent single-chip
+engine at its DEFAULT configuration (fused stage compiler,
+host-serialized MULTITHREADED shuffle). Scaling is reported as
+``throughput(mesh@n) / throughput(single@1)`` from raw wall clock: the
+speedup a query sees when its execution spreads over n chips and its
+shuffles stop leaving the device fabric.
 
-On a machine without n real TPU chips the mesh is virtual (XLA host
-devices timesharing the host cores): program shape, collective
-semantics, and byte accounting are identical, but the n per-chip
-programs run serially, so wall-clock measures their SUM where real
-chips run them concurrently. Each mesh row therefore reports both the
-serialized wall-clock (``median_s``) and the per-chip critical-path
-estimate ``chip_est_s = median_s / n`` (q5's hash exchange balances
-shards to within the slot-skew bound, so the per-chip max ~= the
-mean); ``scaling`` uses the estimate on a virtual mesh and raw
-wall-clock when the chips are real. ``virtual_mesh`` in the block says
-which one you are reading.
+Shard counts are the powers of two up to the chips this process can
+see. A virtual mesh (XLA host devices timesharing the host cores) runs
+the same program shapes and counts the same bytes, which is what the
+tests and ci/multichip_check.sh use it for, but its wall clock says
+nothing about chips: ``main()`` refuses a platform other than `tpu`,
+and nothing computed on virtual devices is printed beside a
+device_kind.
 
-Runnable in-process (``run_scaling``) when the interpreter already has
-enough devices, or as ``python -m spark_rapids_tpu.tools.multichip_bench``
-which prints one JSON line (bench.py spawns that in a virtual-mesh
-subprocess).
+Its own command, its own process (a chip belongs to one process):
+``python -m spark_rapids_tpu.tools.multichip_bench`` prints one JSON
+line. ``chip_smoke.py --chips 4`` is the quicker proof that the mesh
+path runs at all.
 """
 
 from __future__ import annotations
@@ -32,6 +28,7 @@ from __future__ import annotations
 import json
 import os
 import statistics
+import tempfile
 import time
 from typing import Dict, Sequence
 
@@ -40,8 +37,9 @@ FILES = 8
 STORES = 2000
 REGIONS = 12
 REPEATS = 3
-DATA_DIR = f"/tmp/srtpu_multichip_{ROWS}"
-DIM_DIR = f"/tmp/srtpu_multichip_{ROWS}_dim"
+DATA_DIR = os.path.join(tempfile.gettempdir(),
+                        f"srtpu_multichip_{ROWS}")
+DIM_DIR = DATA_DIR + "_dim"
 
 
 def ensure_data() -> int:
@@ -122,8 +120,15 @@ def _timed_run(spark, repeats: int = REPEATS):
     return out, statistics.median(times), rec
 
 
-def run_scaling(shards: Sequence[int] = (1, 2, 4, 8),
-                repeats: int = REPEATS) -> Dict:
+def device_shards() -> Sequence[int]:
+    """1, 2, 4, ... up to the devices this process can see."""
+    import jax
+
+    have = len(jax.devices())
+    return tuple(n for n in (1, 2, 4, 8) if n <= have)
+
+
+def run_scaling(shards: Sequence[int], repeats: int = REPEATS) -> Dict:
     """The MULTICHIP block: q5 throughput per shard count + the ledger's
     ici-vs-host byte split for the mesh execution."""
     import jax
@@ -135,11 +140,8 @@ def run_scaling(shards: Sequence[int] = (1, 2, 4, 8),
     have = len(jax.devices())
     if have < need:
         raise RuntimeError(
-            f"run_scaling needs {need} devices, have {have} "
-            "(spawn under a virtual mesh: "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+            f"run_scaling needs {need} devices, have {have}")
 
-    virtual = jax.devices()[0].platform == "cpu"
     rows = {}
     baseline_thr = None
     oracle = None
@@ -179,16 +181,13 @@ def run_scaling(shards: Sequence[int] = (1, 2, 4, 8),
                 assert abs(got[k][0] - oracle[k][0]) <= max(
                     1e-6 * abs(oracle[k][0]), 0.05), (k, got[k],
                                                       oracle[k])
-            # on a virtual mesh one host core executes the n per-chip
-            # programs serially: the chip critical path is med / n
-            chip_est = med / n if virtual else med
-            thr = input_bytes / chip_est / 1e9
+            thr = input_bytes / med / 1e9
             tel = (rec.get("telemetry") or {})
             moved = tel.get("bytesMoved") or {}
             rows[n] = {
                 "engine": rec.get("engine"),
+                "meshDevices": rec.get("meshDevices"),
                 "median_s": round(med, 3),
-                "chip_est_s": round(chip_est, 3),
                 "gbps": round(thr, 3),
                 "scaling": round(thr / baseline_thr, 3),
                 "iciBytes": tel.get("iciBytes"),
@@ -207,8 +206,9 @@ def run_scaling(shards: Sequence[int] = (1, 2, 4, 8),
                   "(mesh SPMD over ICI vs default single-chip engine)",
         "rows": ROWS,
         "input_mib": input_bytes >> 20,
-        "device_kind": str(getattr(dev, "device_kind", dev.platform)),
-        "virtual_mesh": virtual,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
         "baseline": "single-chip engine, default conf "
                     "(fused, MULTITHREADED host shuffle)",
         "shards": {str(k): v for k, v in sorted(rows.items())},
@@ -235,17 +235,18 @@ def _agg_only(spark):
                  F.count("*").alias("sales")))
 
 
-def run_hosts(repeats: int = REPEATS) -> Dict:
-    """The multi-host axis (PR 17): the SAME 8 chips flat (1x8 — every
+def run_hosts(n: int) -> Dict:
+    """The multi-host axis (PR 17): the SAME n chips flat (1xn — every
     exchange on ICI) vs split into two simulated host failure domains
-    (2x4 — hash exchanges keep their heavy stage on ICI, only the
+    (2 x n/2 — hash exchanges keep their heavy stage on ICI, only the
     cross-host stage and reduced partial-agg buffers cross DCN). On one
     machine both fabrics are the same host backplane, so wall-clock is
     flat by construction; the measurement is the LEDGER split the
-    DCN-aware planner produces: `dcn_vs_ici` for the q5 exchange-bearing
-    plan (must stay < 1), and `dcn_reduction_factor` (ici/dcn) for an
-    agg-only shape — the factor by which the reduce-then-DCN placement
-    keeps traffic on the fast fabric rather than the cross-host links."""
+    DCN-aware planner produces — byte COUNTS, which hold on any
+    backend: `dcn_vs_ici` for the q5 exchange-bearing plan (must stay
+    < 1), and `dcn_reduction_factor` (ici/dcn) for an agg-only shape —
+    the factor by which the reduce-then-DCN placement keeps traffic on
+    the fast fabric rather than the cross-host links."""
     ensure_data()
 
     def ledger(spark, q):
@@ -258,13 +259,13 @@ def run_hosts(repeats: int = REPEATS) -> Dict:
             "dcnBytes": moved.get("dcn", 0),
         }
 
-    spark = _session({"spark.rapids.tpu.mesh": 8})
+    spark = _session({"spark.rapids.tpu.mesh": n})
     try:
         out_flat, eng_flat, flat = ledger(spark, _q5)
     finally:
         spark.stop()
 
-    spark = _session({"spark.rapids.tpu.mesh": 8,
+    spark = _session({"spark.rapids.tpu.mesh": n,
                       "spark.rapids.tpu.multihost.simulatedHosts": 2})
     try:
         out_2x4, eng_2x4, q5_2x4 = ledger(spark, _q5)
@@ -284,19 +285,28 @@ def run_hosts(repeats: int = REPEATS) -> Dict:
     dcn, ici = q5_2x4["dcnBytes"], q5_2x4["iciBytes"]
     adcn, aici = agg_2x4["dcnBytes"], agg_2x4["iciBytes"]
     return {
-        "metric": "q5 byte placement, 1x8 flat vs 2x4 host domains "
-                  "(hash exchanges on ICI, reduced traffic on DCN)",
-        "q5_1x8": flat,
-        "q5_2x4": {**q5_2x4,
-                   "dcn_vs_ici": round(dcn / ici, 3) if ici else None},
-        "agg_2x4": agg_2x4,
+        "metric": f"q5 byte placement, 1x{n} flat vs 2x{n // 2} host "
+                  f"domains (hash exchanges on ICI, reduced traffic "
+                  f"on DCN)",
+        "q5_flat": flat,
+        "q5_2hosts": {**q5_2x4,
+                      "dcn_vs_ici": (round(dcn / ici, 3) if ici
+                                     else None)},
+        "agg_2hosts": agg_2x4,
         "dcn_reduction_factor": round(aici / adcn, 3) if adcn else None,
     }
 
 
 def main() -> None:
-    block = run_scaling()
-    block["hosts"] = run_hosts()
+    from spark_rapids_tpu.obs.telemetry import require_tpu
+
+    # a virtual mesh checks answers and byte counts
+    # (tests/test_mesh_query.py, ci/multichip_check.sh), never speed
+    require_tpu("multichip_bench")
+    shards = device_shards()
+    block = run_scaling(shards)
+    if max(shards) >= 4:
+        block["hosts"] = run_hosts(max(shards))
     print(json.dumps(block))
 
 

@@ -1,0 +1,302 @@
+"""What only the TPU's compiler can say, asked without a chip.
+
+The engine's TPU-only branches (one-hot-matmul segmented reductions,
+the f32 detour around 64-bit bitcasts, narrowed uploads widened
+in-trace) are reached on the CPU backend only through test switches,
+so a CPU-green suite says nothing about whether today's TPU compiler
+still accepts them at the bench's real width. These tests compile the
+main path's kernels for a DESCRIBED v5e chip (`v5e:2x2`, no chip
+attached) at 4.5M-row partitions — 36M rows in 8 files — with x64 on.
+Each takes seconds; whole fused stages (two of which take ~2 minutes
+of compiler time each, CHANGES.md PR 21) are rehearsed by hand, not
+here. A compile that passes is not a chip run and is never reported as
+one.
+
+Everything that touches the topology lives in the module-scoped
+fixtures below: only the worker that runs this file loads the TPU's
+library, and only once a test of this file has started.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from spark_rapids_tpu.columnar.batch import ColumnBatch, DeviceColumn
+from spark_rapids_tpu.sqltypes.datatypes import (
+    StructField,
+    StructType,
+    double,
+    long,
+)
+
+ROWS = 4_500_000          # one of the bench's 8 fact files
+BUILD_CAP = 1 << 16       # the 2000-row dim and the 4000-row dup-key
+#                           dim both upload at the 64Ki floor
+A2A_SHARD = 1 << 18       # rows per device in the all-to-all case
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device is written to jax's persistent
+    cache but cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch, no_persistent_cache):
+    """Code that asks `jax.default_backend()` while it is traced takes
+    its TPU branch: a described device does not change the default."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _col(dtype, np_dtype, n, sharding, vrange=None):
+    return DeviceColumn(dtype, _sds((n,), np_dtype, sharding),
+                        _sds((n,), jnp.bool_, sharding), vrange=vrange)
+
+
+def _batch(cols, names, sharding):
+    schema = StructType([StructField(nm, c.dtype, True)
+                         for nm, c in zip(names, cols)])
+    return ColumnBatch(schema, list(cols),
+                       _sds((), jnp.int32, sharding))
+
+
+def _compile(fn, *avals):
+    return jax.jit(fn).lower(*avals).compile()
+
+
+# ------------------------------------------------ the refusal itself
+
+def test_f64_bitcast_still_refused(one_chip, no_persistent_cache):
+    """`ops.common.supports_64bit_bitcast()` answers False on a TPU
+    because the x64 rewrite refuses this HLO. The day this test fails,
+    the compiler accepts it: drop the f32 detours in ops/common.py and
+    ops/hashing.py (and this test)."""
+    x = _sds((ROWS,), jnp.float64, one_chip)
+    with pytest.raises(Exception, match="X64 element types"):
+        _compile(lambda a: jax.lax.bitcast_convert_type(a, jnp.int64), x)
+
+
+# ----------------------------------------------- segmented reductions
+
+@pytest.mark.parametrize("bins", [14, 2050],
+                         ids=["12-groups", "2000-groups"])
+def test_matmul_segmented_sum_count(one_chip, as_tpu, bins):
+    """The binned group-by's hot kernel: f64 sum + count as one-hot
+    matmuls (ops/segmented.py), at the q5 region key (12 values + null
+    and dead bins) and at the store key (quantized to [0, 2047])."""
+    from spark_rapids_tpu.columnar.batch import next_capacity
+    from spark_rapids_tpu.ops import segmented
+
+    cap = next_capacity(bins)
+
+    def kernel(values, valid, gid):
+        with segmented.unsorted_gids(), segmented.binned_bins(bins):
+            assert segmented.mm_bins_active() == bins
+            return segmented.seg_sum_count(values, valid, gid, cap)
+
+    sweeps = segmented.mm_traced_sweeps
+    c = _compile(kernel, _sds((ROWS,), jnp.float64, one_chip),
+                 _sds((ROWS,), jnp.bool_, one_chip),
+                 _sds((ROWS,), jnp.int32, one_chip))
+    assert segmented.mm_traced_sweeps > sweeps, "matmul path not taken"
+    assert c.memory_analysis().temp_size_in_bytes < (2 << 30)
+
+
+def test_matmul_bounded_int_sum(one_chip, as_tpu):
+    """Exact int64 sums of a vrange-bounded column (qty in [0, 127])
+    ride the MXU in f32 chunks with an i64 carry."""
+    from spark_rapids_tpu.ops import segmented
+
+    def kernel(values, valid, gid):
+        with segmented.unsorted_gids(), segmented.binned_bins(2050):
+            return segmented.seg_sum(values, valid, gid, 4096,
+                                     vbound=(0, 127))
+
+    sweeps = segmented.mm_traced_sweeps
+    _compile(kernel, _sds((ROWS,), jnp.int64, one_chip),
+             _sds((ROWS,), jnp.bool_, one_chip),
+             _sds((ROWS,), jnp.int32, one_chip))
+    assert segmented.mm_traced_sweeps > sweeps, "matmul path not taken"
+
+
+# ------------------------------------------------------- lookup join
+
+def _sorted_build(one_chip):
+    """A BuildTable as the buildprep program hands it to the chains:
+    sorting it is that program's job (and two minutes of compiler
+    time), probing it is what runs per partition."""
+    from spark_rapids_tpu.ops import joinops
+
+    batch = _batch([_col(long, jnp.int64, BUILD_CAP, one_chip),
+                    _col(double, jnp.float64, BUILD_CAP, one_chip)],
+                   ["store", "discount"], one_chip)
+    return joinops.BuildTable(
+        batch, [_sds((BUILD_CAP,), jnp.int64, one_chip)],
+        _sds((), jnp.int32, one_chip))
+
+
+def _probe(one_chip):
+    return _batch([_col(long, jnp.int64, ROWS, one_chip),
+                   _col(double, jnp.float64, ROWS, one_chip)],
+                  ["store", "amount"], one_chip)
+
+
+def test_lookup_join_probe_and_gather(one_chip, as_tpu):
+    """Binary search of a sorted build + single-match gather over
+    int64 keys (ops/joinops.py; exec/fused.py lookup_join): a 4.5M-row
+    probe partition against the dim, uniqueness flag included."""
+    from spark_rapids_tpu.ops import joinops
+
+    def kernel(bt, probe):
+        lo, counts = joinops.probe_ranges(bt, probe, [0])
+        safe = jnp.clip(lo, 0, bt.batch.capacity - 1)
+        gathered = bt.batch.columns[1].gather(safe)
+        return (gathered.data, gathered.validity & (counts > 0),
+                jnp.any(counts > 1))
+
+    _compile(kernel, _sorted_build(one_chip), _probe(one_chip))
+
+
+def test_expanded_join_gather_maps(one_chip, as_tpu):
+    """The dup-key join's blocking lowering: (lo, counts) expanded to
+    probe/build gather maps at a static output capacity (2 matches per
+    probe row -> 2^24 slots for one partition), both sides gathered."""
+    from spark_rapids_tpu.ops import joinops
+
+    out_cap = 1 << 24
+
+    def kernel(bt, probe):
+        lo, counts = joinops.probe_ranges(bt, probe, [0])
+        pi, bi, total = joinops.expand_gather_maps(lo, counts, out_cap)
+        return (probe.columns[1].gather(pi).data,
+                bt.batch.columns[1].gather(bi).data, total)
+
+    c = _compile(kernel, _sorted_build(one_chip), _probe(one_chip))
+    assert c.memory_analysis().temp_size_in_bytes < (4 << 30)
+
+
+# ------------------------------------------------ murmur3 partitioning
+
+@pytest.mark.parametrize("key", ["int64", "float64"])
+def test_murmur3_partition_ids(one_chip, as_tpu, key):
+    """Spark-compatible murmur3 + pmod over 64-bit keys; the float64
+    key takes the `supports_64bit_bitcast() == False` detour."""
+    from spark_rapids_tpu.ops import common, partition
+
+    assert not common.supports_64bit_bitcast()
+    col = (_col(long, jnp.int64, ROWS, one_chip) if key == "int64"
+           else _col(double, jnp.float64, ROWS, one_chip))
+    batch = _batch([col], ["k"], one_chip)
+    _compile(lambda b: partition.hash_partition_ids(b, [0], 8), batch)
+
+
+def test_float64_sort_keys_take_the_f32_detour(one_chip, as_tpu):
+    """Orderable keys of a DoubleType column (sort / group / join
+    keys): f32 total-order bits on a TPU (ops/common.py)."""
+    from spark_rapids_tpu.ops import common
+
+    col = _col(double, jnp.float64, ROWS, one_chip)
+    live = _sds((ROWS,), jnp.bool_, one_chip)
+    _compile(lambda c, lv: common.orderable_keys(c, True, True, lv),
+             col, live)
+
+
+# ------------------------------------------------------ widen_traced
+
+def test_widen_traced_narrowed_scan_batch(one_chip, as_tpu):
+    """The bench's fact partition as uploaded (store int16, qty int8,
+    day int16, amount f64) widened back to int64 in-trace and consumed
+    (exec/fused.py upload_narrowed / widen_traced)."""
+    from spark_rapids_tpu.exec.fused import widen_traced
+
+    cols = [_col(long, jnp.int16, ROWS, one_chip, vrange=(0, 2047)),
+            _col(double, jnp.float64, ROWS, one_chip),
+            _col(long, jnp.int8, ROWS, one_chip, vrange=(0, 127)),
+            _col(long, jnp.int16, ROWS, one_chip, vrange=(0, 511))]
+    batch = _batch(cols, ["store", "amount", "qty", "day"], one_chip)
+
+    def kernel(b):
+        w = widen_traced(b)
+        assert all(c.data.dtype == jnp.int64
+                   for c in (w.columns[0], w.columns[2], w.columns[3]))
+        revenue = w.columns[1].data * w.columns[2].data
+        return jnp.sum(jnp.where(w.live_mask(), revenue, 0.0)), \
+            jnp.max(w.columns[0].data + w.columns[3].data)
+
+    _compile(kernel, batch)
+
+
+# ------------------------------------------------ 4-device all-to-all
+
+def test_all_to_all_batch_on_four_chips(topo, as_tpu):
+    """The mesh engine's exchange (parallel/collective.py) as one SPMD
+    program over the four described chips: an all-to-all is in the
+    compiled text and each device holds its quarter, not the whole."""
+    from spark_rapids_tpu.ops import partition
+    from spark_rapids_tpu.parallel import collective, mesh_exec
+    from spark_rapids_tpu.shims import get_shim
+
+    n = len(topo.devices)
+    assert n == 4
+    mesh = Mesh(topo.devices, (mesh_exec.AXIS,))
+    rows = NamedSharding(mesh, P(mesh_exec.AXIS))
+    total = n * A2A_SHARD
+    batch = ColumnBatch(
+        StructType([StructField("store", long, True),
+                    StructField("amount", double, True)]),
+        [_col(long, jnp.int64, total, rows),
+         _col(double, jnp.float64, total, rows)],
+        _sds((n,), jnp.int32, rows))
+    slot = collective.slot_capacity(A2A_SHARD, n)
+
+    def step(shard):
+        pid = partition.hash_partition_ids(shard, [0], n)
+        out, ovf = collective.all_to_all_batch(shard, pid, n, slot,
+                                               mesh_exec.AXIS)
+        out = ColumnBatch(out.schema, out.columns,
+                          jnp.asarray(out.num_rows, jnp.int32).reshape(1))
+        return out, ovf.reshape(1)
+
+    spec = mesh_exec.batch_arg_specs(batch, P(mesh_exec.AXIS))
+    collective.begin_ici_tape()
+    try:
+        c = _compile(get_shim().shard_map(
+            step, mesh, (spec,),
+            (P(mesh_exec.AXIS), P(mesh_exec.AXIS))), batch)
+    finally:
+        tape = collective.end_ici_tape()
+    assert "all-to-all" in c.as_text()
+    assert tape and tape[0][1] > 0, "no ICI bytes on the tape"
+    per_device = c.memory_analysis().argument_size_in_bytes
+    whole = total * (8 + 1 + 8 + 1) + 4 * n
+    assert per_device <= whole // n + 1024, (per_device, whole)

@@ -338,3 +338,179 @@ def test_disabled_conf_writes_nothing(tmp_path):
     finally:
         s.stop()
         cc.reset_for_tests()
+
+
+# ------------------------------------- placement: a cache that stays put
+
+def test_dir_precedence_env_then_conf_then_fixed(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, then compileCache.dir, then the fixed
+    path inside the checkout — never the temp dir, a pid or a time."""
+    from spark_rapids_tpu.config import rapids_conf as rc
+
+    conf = rc.RapidsConf(
+        {"spark.rapids.tpu.compileCache.dir": str(tmp_path / "conf")})
+    no_dir = rc.RapidsConf({"spark.rapids.tpu.compileCache.dir": ""})
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    # the variable wins, jax keeps the directory it read from it (None =
+    # this module sets no path), our layers take a sub-directory
+    assert cc.resolve_dirs(conf) == (str(tmp_path / "env" / "srtpu"),
+                                     None)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert cc.resolve_dirs(conf) == (str(tmp_path / "conf"),
+                                     str(tmp_path / "conf" / "xla"))
+    root, xla = cc.resolve_dirs(no_dir)
+    assert root == cc.FIXED_DIR and xla == os.path.join(root, "xla")
+    assert cc.resolve_dirs(None) == (root, xla)  # the same every time
+    assert root.startswith(REPO + os.sep)
+    import tempfile
+
+    assert not root.startswith(tempfile.gettempdir() + os.sep)
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert os.path.basename(root) + "/" in f.read().split()
+
+
+def test_env_dir_is_left_to_jax(tmp_path, monkeypatch):
+    """With the variable set, configure() points jax nowhere else and
+    keeps index + artifacts under <dir>/srtpu."""
+    import jax
+
+    env_dir = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    before = jax.config.jax_compilation_cache_dir
+    cc.reset_for_tests()
+    try:
+        cc.configure()
+        assert jax.config.jax_compilation_cache_dir == before
+        assert cc.cache_dir() == os.path.join(env_dir, "srtpu")
+        assert sorted(os.listdir(cc.cache_dir())) == [
+            "VERSION.json", "artifacts", "index"]
+    finally:
+        cc.reset_for_tests()
+
+
+def test_backend_change_wipes_nothing(tmp_path):
+    """A CPU rehearsal and a chip run share one directory: the stamp
+    names versions, not the backend, and a version mismatch clears the
+    engine's index + artifacts but never jax's own entries."""
+    root = str(tmp_path / "shared")
+    for sub in ("index", "artifacts", "xla"):
+        os.makedirs(os.path.join(root, sub))
+        open(os.path.join(root, sub, "entry"), "w").close()
+    assert "backend" not in cc.version_token()
+    with open(os.path.join(root, "VERSION.json"), "w") as f:
+        json.dump(cc.version_token(), f)
+    cc._check_version_stamp(root)  # same versions: nothing touched
+    assert all(os.path.exists(os.path.join(root, sub, "entry"))
+               for sub in ("index", "artifacts", "xla"))
+    with open(os.path.join(root, "VERSION.json"), "w") as f:
+        json.dump({**cc.version_token(), "jax": "0.0.1"}, f)
+    cc._check_version_stamp(root)
+    assert not os.path.exists(os.path.join(root, "index", "entry"))
+    assert not os.path.exists(os.path.join(root, "artifacts", "entry"))
+    assert os.path.exists(os.path.join(root, "xla", "entry"))
+
+
+def test_warmup_skips_other_backends_artifacts(tmp_path):
+    """An artifact another backend exported would fail to compile here
+    and be quarantined for both: warmup reads only this backend's."""
+    import jax
+
+    cc.reset_for_tests()
+    s = TpuSparkSession({
+        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "c"),
+        "spark.rapids.tpu.compileCache.warmup.enabled": False,
+    })
+    try:
+        key = repr(("fused", "other-backend"))
+        digest = cc.key_digest(("fused", "other-backend"))
+        cc._record_index(digest, key, "fused", 1.0, True)
+        entry = cc.read_index()[digest]
+        assert entry["backend"] == jax.default_backend()
+        entry["backend"] = "tpu"
+        with open(cc._index_path(digest), "w") as f:
+            json.dump(entry, f)
+        adir = os.path.join(cc.cache_dir(), "artifacts")
+        with open(os.path.join(adir, digest + ".key"), "w") as f:
+            f.write(key)
+        with open(os.path.join(adir, digest + ".bin"), "wb") as f:
+            f.write(b"an export for another platform")
+        before = cc.stats.snapshot()["artifactsQuarantined"]
+        cc._warmup_run(top_k=8)
+        assert cc.warm_count() == 0
+        assert cc.stats.snapshot()["artifactsQuarantined"] == before
+        assert os.path.exists(os.path.join(adir, digest + ".bin"))
+    finally:
+        s.stop()
+        cc.reset_for_tests()
+
+
+def test_warm_executable_that_fails_is_rebuilt_and_counted():
+    """runtime/jit_cache.py keeps a query alive when a warm artifact
+    does not run here (rebuilds live) — and now says so."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.runtime import jit_cache
+
+    key = ("fused", "warm-rebuild-test")
+
+    def broken(*_a, **_k):
+        raise TypeError("aval drift")
+
+    with cc._warm_lock:
+        cc._warm[repr(key + jit_cache._env_token())] = broken
+    before = cc.stats.snapshot()
+    fn = jit_cache.cached_jit(key, lambda: (lambda x: x + 1))
+    assert int(fn(jnp.int32(41))) == 42
+    assert int(fn(jnp.int32(1))) == 2  # second call: no warm retry
+    after = cc.stats.snapshot()
+    assert after["warmRebuilds"] == before["warmRebuilds"] + 1
+    assert after["warmHits"] == before["warmHits"] + 1
+    assert after["programsCompiled"] == before["programsCompiled"] + 1
+
+
+def test_xla_disk_cache_hits_are_counted(cache_session):
+    """Layer 1 reports itself: a structurally identical program built
+    again in this process after the in-memory caches are dropped is an
+    XLA disk HIT, not a compile."""
+    import jax
+
+    from spark_rapids_tpu.runtime import jit_cache
+
+    _mini_q5(cache_session).collect_arrow()
+    first = cache_session.last_execution["compile"]
+    assert first["xlaCacheMisses"] >= first["programsCompiled"] > 0
+    jit_cache.clear()
+    jax.clear_caches()
+    _mini_q5(cache_session).collect_arrow()
+    again = cache_session.last_execution["compile"]
+    assert again["programsCompiled"] == first["programsCompiled"]
+    assert again["xlaCacheHits"] >= first["programsCompiled"]
+
+
+def test_export_failure_is_counted_not_silent(cache_session, tmp_path):
+    """A fused program jax.export cannot serialize stays index-only —
+    and the ledger says so. Programs over dictionary-encoded columns
+    are such programs today (DeviceDictionary has no export
+    serialization): every program of the bench's q5 and dup-key join."""
+    import pyarrow.parquet as pq
+
+    import spark_rapids_tpu.config.rapids_conf as rc
+
+    pq.write_table(pa.table({
+        "store": pa.array(np.arange(50), type=pa.int64()),
+        "region": pa.array([f"r{i % 4}" for i in range(50)]),
+    }), str(tmp_path / "dim.parquet"), use_dictionary=["region"])
+    dim = cache_session.read.parquet(str(tmp_path / "dim.parquet"))
+    cc._artifact_min_s = 0.0
+    try:
+        before = cc.stats.snapshot()["artifactExportFailures"]
+        dim.groupBy("region").agg(F.count("*").alias("n")).collect_arrow()
+        cc.flush()
+        failed = cc.stats.snapshot()["artifactExportFailures"] - before
+        index = cc.read_index()
+        without = [e for e in index.values()
+                   if e["tag"] == "fused" and not e["artifact"]]
+        assert failed == len(without) > 0, (failed, index)
+    finally:
+        cc._artifact_min_s = rc.COMPILE_CACHE_ARTIFACT_MIN_S.default

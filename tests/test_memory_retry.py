@@ -338,3 +338,41 @@ def test_restore_on_retry_storm_checkpointed_value(tmp_path):
         assert cat.buffer_count() == 0
     finally:
         mem._catalog = old
+
+
+# ----------------------------------------------- HBM size: read, not guessed
+
+class _FakeDevice:
+    def __init__(self, platform, kind, stats):
+        self.platform, self.device_kind, self._stats = platform, kind, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("device,want", [
+    (_FakeDevice("tpu", "TPU v5 lite", {"bytes_limit": 16_909_336_576}),
+     16_909_336_576),
+    (_FakeDevice("cpu", "cpu", None), 16 << 30),
+], ids=["tpu-reports-its-limit", "cpu-backend-nominal-pool"])
+def test_detect_hbm_bytes_reads_the_device(monkeypatch, device, want):
+    import jax
+
+    from spark_rapids_tpu.runtime import memory
+
+    monkeypatch.setattr(jax, "local_devices", lambda *a: [device])
+    assert memory._detect_hbm_bytes() == want
+
+
+def test_detect_hbm_bytes_never_guesses_for_a_chip(monkeypatch):
+    """A TPU that reports no limit is an error: a pretended 16 GiB
+    budgets the wrong chip."""
+    import jax
+
+    from spark_rapids_tpu.runtime import memory
+
+    monkeypatch.setattr(
+        jax, "local_devices",
+        lambda *a: [_FakeDevice("tpu", "TPU v9", {})])
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        memory._detect_hbm_bytes()

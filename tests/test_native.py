@@ -225,3 +225,27 @@ def test_string_minmax_agg_falls_back():
     assert_tpu_and_cpu_are_equal_collect(
         q, conf={"spark.sql.shuffle.partitions": 3,
                  "spark.rapids.shuffle.mode": "MULTITHREADED"})
+
+
+def test_build_is_keyed_by_source_and_host():
+    """The git-ignored build directory may travel with the tree; only a
+    library built from THIS source on THIS host's CPU is loaded."""
+    import os
+
+    _require_native()
+    key = native._build_key()
+    assert len(key) == 16 and key == native._build_key()
+    so = os.path.join(native._OUT_DIR, key, "libsparktpu.so")
+    assert os.path.exists(so)
+    # a stray library at the old, unkeyed path is not what gets loaded
+    assert native._build() == so
+    assert native.runtime_in_use() == "native"
+
+
+def test_build_key_follows_the_source(tmp_path, monkeypatch):
+    src = tmp_path / "rt.cpp"
+    src.write_text("int a;")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    first = native._build_key()
+    src.write_text("int b;")
+    assert native._build_key() != first

@@ -19,14 +19,21 @@ def test_shim_loader_picks_current_jax():
 
 
 def test_shim_provider_selection_by_version():
-    from spark_rapids_tpu.shims import ShimError, detect_shim_provider
+    from spark_rapids_tpu.shims import detect_shim_provider
 
-    legacy = detect_shim_provider("0.4.30")
-    assert "legacy" in legacy.__name__
     current = detect_shim_provider("0.9.0")
     assert "current" in current.__name__
-    with pytest.raises(ShimError):
-        detect_shim_provider("0.3.25")
+
+
+@pytest.mark.parametrize("version", ["0.4.30", "0.8.2", "0.10.0", "1.0.0"])
+def test_unserved_jax_version_raises(version):
+    """One installation is supported and pinned (pyproject.toml): any
+    other jax raises ShimError naming what was probed, instead of
+    running lowerings nothing has tested."""
+    from spark_rapids_tpu.shims import ShimError, detect_shim_provider
+
+    with pytest.raises(ShimError, match="jax_current"):
+        detect_shim_provider(version)
 
 
 def test_shim_worlds_export_identical_api():

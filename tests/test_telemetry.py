@@ -146,7 +146,49 @@ def test_telemetry_disabled_is_inert():
 def test_link_peaks_probe_and_cache():
     peaks = telemetry.link_peaks()
     assert peaks["devicePeakBytesPerS"] > 0
+    assert peaks["roundTripMs"] > 0
+    assert peaks["h2dBytesPerS"] > 0 and peaks["d2hBytesPerS"] > 0
     assert peaks is telemetry.link_peaks()  # in-process cache
+
+
+@pytest.mark.parametrize("kind", ["TPU v7x", "NVIDIA H100", "unknown"])
+def test_unknown_device_kind_is_an_error(kind):
+    """No default row: a CPU's (or any other chip's) peak under an
+    unlisted device's name is a wrong roofline, not a safe one."""
+    with pytest.raises(KeyError, match="DEVICE_PEAK_BW"):
+        telemetry.device_peak_bw(kind)
+
+
+@pytest.mark.parametrize("kind,peak", [
+    ("TPU v5 lite", 8.19e11), ("TPU v5e", 8.19e11), ("cpu", 5.0e10)])
+def test_known_device_kinds_resolve(kind, peak):
+    assert telemetry.device_peak_bw(kind) == peak
+
+
+def test_link_probe_of_an_unlisted_device_raises(monkeypatch):
+    monkeypatch.setattr(telemetry, "_device_kind", lambda: "TPU v9")
+    monkeypatch.setattr(telemetry, "_peaks", None)
+    monkeypatch.setattr(telemetry, "_peaks_path", lambda: None)
+    with pytest.raises(KeyError, match="TPU v9"):
+        telemetry.link_peaks(refresh=True)
+
+
+def test_cached_peaks_of_another_device_kind_are_reprobed(
+        tmp_path, monkeypatch):
+    """CPU rehearsals and chip runs may share one cache directory: a
+    peaks file measured on another kind must not be served."""
+    path = str(tmp_path / "telemetry_peaks.json")
+    with open(path, "w") as f:
+        json.dump({"deviceKind": "TPU v5 lite",
+                   "devicePeakBytesPerS": 8.19e11,
+                   "h2dBytesPerS": 1.0, "d2hBytesPerS": 1.0,
+                   "roundTripMs": 1.0, "probeBytes": 1}, f)
+    monkeypatch.setattr(telemetry, "_peaks", None)
+    monkeypatch.setattr(telemetry, "_peaks_path", lambda: path)
+    peaks = telemetry.link_peaks()
+    assert peaks["deviceKind"] == "cpu"
+    with open(path) as f:
+        assert json.load(f)["deviceKind"] == "cpu"  # rewritten
 
 
 # ----------------------------------------------- telemetry.summary event

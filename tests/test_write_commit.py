@@ -298,16 +298,35 @@ def test_sweep_restores_old_after_crashed_swap(tmp_path):
 
 # --------------------------------------------- optimistic delta commits
 
-def test_concurrent_delta_appends_both_land(spark, tmp_path):
+def test_concurrent_delta_appends_both_land(spark, tmp_path,
+                                            monkeypatch):
+    """Two appenders that COLLIDE BY CONSTRUCTION: each is held at its
+    first claim until both have read snapshot v0 and built version 1,
+    so exactly one claim wins and the loser must retry on v2. (Left to
+    the scheduler, the two often commit one after the other and the
+    conflict path never runs.)"""
+    from spark_rapids_tpu.lakehouse import delta as dmod
+
     p = str(tmp_path / "d")
     spark.createDataFrame(_table(10)).write.format("delta").save(p)
-    barrier = threading.Barrier(2)
+    both_built = threading.Barrier(2)
+    first_claim = threading.local()
+    real_commit = dmod._commit
+
+    def held_commit(table_path, version, actions, *rest):
+        if not getattr(first_claim, "made", False):
+            first_claim.made = True
+            assert version == 1, version
+            both_built.wait(timeout=30)
+        return real_commit(table_path, version, actions, *rest)
+
+    monkeypatch.setattr(dmod, "_commit", held_commit)
+    conflicts_before = iocommit.write_totals()["conflicts"]
     errs = []
 
     def appender(n):
         try:
             df = spark.createDataFrame(_table(n))
-            barrier.wait(timeout=10)
             df.write.format("delta").mode("append").save(p)
         except BaseException as e:  # pragma: no cover - surfaced below
             errs.append(e)
@@ -324,7 +343,7 @@ def test_concurrent_delta_appends_both_land(spark, tmp_path):
     from spark_rapids_tpu.lakehouse.delta import _list_versions
 
     assert _list_versions(p) == [0, 1, 2]
-    assert iocommit.write_totals()["conflicts"] >= 1
+    assert iocommit.write_totals()["conflicts"] == conflicts_before + 1
 
 
 def test_delta_rewrite_conflict_is_concurrent_modification(spark,
