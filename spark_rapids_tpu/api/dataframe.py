@@ -82,6 +82,10 @@ def _stamp_session(expr: Expression, session) -> Expression:
     return expr.transform(fn)
 
 
+def _count_nodes(node) -> int:
+    return 1 + sum(_count_nodes(c) for c in node.children)
+
+
 def _pin_query_time(plan):
     """Replace current_date/current_timestamp markers with ONE literal
     per query (Spark pins both at query start), applied at physical
@@ -658,6 +662,9 @@ class DataFrame:
         # via explain() and session.query_metrics — a fused/mesh compile
         # error must never silently land a query on the dispatch-bound
         # eager path.
+        import contextlib
+        import time as _time
+
         from spark_rapids_tpu.obs import events as obs_events
         from spark_rapids_tpu.runtime import admission
 
@@ -672,7 +679,9 @@ class DataFrame:
         # exit; nested collects ride the enclosing query's handle
         scope = admission.AdmissionScope(
             self.session, description=type(self._plan).__name__)
+        submitted_ns = _time.time_ns()
         with scope as handle:
+            admitted_ns = _time.time_ns()
             # the query scope brackets the event stream (query.start /
             # query.end frame the event log + span tree); nested
             # collects fold into the outer query's stream
@@ -680,52 +689,67 @@ class DataFrame:
             rec["queryId"] = qid
             rec["admission"] = {"queueWaitMs": handle.queue_wait_ms,
                                 "priority": handle.priority}
-            if not scope.nested and handle.queue_wait_ms:
-                # queue wait on the query's span tree (no task scope
-                # here, so the span hangs off the query root)
-                obs_events.emit(
-                    "operator.span", operator="AdmissionQueue",
-                    metric="queueWaitMs",
-                    wallNs=int(handle.queue_wait_ms * 1_000_000),
-                    deviceNs=0)
-            import time as _time
-
-            from spark_rapids_tpu.obs import telemetry as _tel
-
-            t0 = _time.perf_counter()
-            out_rows = None
+            # the `query` span is the tree's root, from the submission
+            # (so the queue wait lies inside it) to the last of the
+            # entry's own work; a nested collect's spans hang under
+            # whatever span of the enclosing query is open
+            root = None if scope.nested else \
+                obs_events.span("query", start_ns=submitted_ns)
             try:
-                out = self._collect_arrow_traced(rec)
-                out_rows = out.num_rows
-                return out
+                with root or contextlib.nullcontext():
+                    if root is not None and handle.queue_wait_ms:
+                        obs_events.record_span(
+                            "admission", submitted_ns, admitted_ns,
+                            metric="queueWaitMs",
+                            queueWaitMs=handle.queue_wait_ms)
+                    return self._collect_arrow_summarized(rec, qid, root)
             finally:
-                # data-movement report for this query: the transfer
-                # ledger's per-query view + roofline fractions over the
-                # measured wall time. The OUTERMOST scope owns the
-                # summary event (nested collects would snapshot the
-                # same qid mid-flight); every rec still carries the
-                # view so callers see bytes for their slice too.
-                tel = _tel.query_summary(
-                    qid, wall_s=_time.perf_counter() - t0,
-                    output_rows=out_rows)
-                rec["telemetry"] = tel or None
-                if tel and not scope.nested:
-                    _tel.ledger.finalize_query(qid, tel)
-                    obs_events.emit(
-                        "telemetry.summary",
-                        bytesMoved=tel.get("bytesMoved"),
-                        bytesMovedTotal=tel.get("bytesMovedTotal"),
-                        hbmPeakBytes=tel.get("hbmPeakBytes"),
-                        rooflineFrac=tel.get("rooflineFrac"),
-                        linkFrac=tel.get("linkFrac"),
-                        bytesPerOutputRow=tel.get("bytesPerOutputRow"),
-                        wallMs=tel.get("wallMs"))
                 obs_events.finish_query(
                     qid, engine=rec["engine"],
                     status="ok" if rec["engine"] is not None
                     else "error",
                     fallbacks=len(rec["fallbacks"]),
                     degradations=len(rec["degradations"]))
+
+    def _collect_arrow_summarized(self, rec, qid: int, root) -> pa.Table:
+        """The traced collect, then the query's data-movement report:
+        the transfer ledger's per-query view + roofline fractions over
+        the measured wall time. The OUTERMOST scope (the one that has
+        the `query` span, `root`) owns the summary event (nested
+        collects would snapshot the same qid mid-flight); every rec
+        still carries the view so callers see bytes for their slice
+        too."""
+        import time as _time
+
+        from spark_rapids_tpu.obs import events as obs_events
+        from spark_rapids_tpu.obs import telemetry as _tel
+
+        t0 = _time.perf_counter()
+        out_rows = None
+        try:
+            out = self._collect_arrow_traced(rec)
+            out_rows = out.num_rows
+            return out
+        finally:
+            tel = _tel.query_summary(
+                qid, wall_s=_time.perf_counter() - t0,
+                output_rows=out_rows)
+            rec["telemetry"] = tel or None
+            if tel and root is not None:
+                _tel.ledger.finalize_query(qid, tel)
+                obs_events.emit(
+                    "telemetry.summary",
+                    bytesMoved=tel.get("bytesMoved"),
+                    bytesMovedTotal=tel.get("bytesMovedTotal"),
+                    hbmPeakBytes=tel.get("hbmPeakBytes"),
+                    rooflineFrac=tel.get("rooflineFrac"),
+                    linkFrac=tel.get("linkFrac"),
+                    bytesPerOutputRow=tel.get("bytesPerOutputRow"),
+                    wallMs=tel.get("wallMs"))
+            if root is not None:
+                root.set(engine=rec["engine"],
+                         status="ok" if rec["engine"] is not None
+                         else "error")
 
     def _collect_arrow_traced(self, rec) -> pa.Table:
         from spark_rapids_tpu.obs import events as obs_events
@@ -747,7 +771,9 @@ class DataFrame:
         if cached is not None:
             return ran("hostCache", cached, store=False)
 
-        phys, meta = self._physical()
+        with obs_events.span("plan") as sp:
+            phys, meta = self._physical()
+            sp.set(nodes=_count_nodes(phys))
         # structured twin of the NOT_ON_TPU explain: one placement
         # event per plan node, with the verbatim fallback reason —
         # what obs.report.qualification() reads

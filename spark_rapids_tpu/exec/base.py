@@ -73,24 +73,19 @@ class PhysicalPlan:
         attributed from the numOutputRows delta when the operator
         tracks it."""
         from spark_rapids_tpu.obs import events as obs_events
-        from spark_rapids_tpu.runtime.profiler import annotate
 
-        name = type(self).__name__
         m = self.metrics.metric(metric_name, level)
         rows_before = self.metrics.peek(M.NUM_OUTPUT_ROWS)
         t0 = time.monotonic_ns()
-        try:
-            with annotate(name):
+        with obs_events.span(type(self).__name__, metric=metric_name,
+                             device=self.is_tpu) as sp:
+            try:
                 yield
-        finally:
-            dt = time.monotonic_ns() - t0
-            m.add(dt)
-            if obs_events.armed():
+            finally:
+                m.add(time.monotonic_ns() - t0)
                 dr = self.metrics.peek(M.NUM_OUTPUT_ROWS) - rows_before
-                obs_events.emit(
-                    "operator.span", operator=name, metric=metric_name,
-                    wallNs=dt, deviceNs=dt if self.is_tpu else 0,
-                    rows=dr if dr > 0 else None)
+                if dr > 0:
+                    sp.set(rows=dr)
 
     def _maybe_dump(self, table: pa.Table, pid: int) -> None:
         """Debug batch dump (DumpUtils.dumpToParquetFile role): when

@@ -39,6 +39,8 @@ out-of-core engine, which remains the path for HBM-exceeding inputs.
 
 from __future__ import annotations
 
+import functools
+import hashlib
 import os
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -169,6 +171,19 @@ def upload_narrowed(table: pa.Table, capacity: Optional[int] = None,
     """pyarrow Table -> device ColumnBatch with integer columns shipped
     at their observed width (widened back in-trace by `widen_traced`).
     One device_put for the whole batch, like arrow_to_device."""
+    from spark_rapids_tpu.obs import events as obs_events
+    from spark_rapids_tpu.obs import telemetry
+
+    with obs_events.span("scan.decode") as sp:
+        host, nbytes = _narrowed_host_batch(table, capacity, narrow,
+                                            bucket)
+        sp.set(rows=table.num_rows, bytes=nbytes)
+    return telemetry.put_watched(host, "scan.upload", nbytes)
+
+
+def _narrowed_host_batch(table: pa.Table, capacity: Optional[int],
+                         narrow: bool, bucket: bool):
+    """-> (the ColumnBatch of numpy leaves to upload, its bytes)."""
     table = table.combine_chunks()
     n = table.num_rows
     cap = capacity or (
@@ -210,14 +225,8 @@ def upload_narrowed(table: pa.Table, capacity: Optional[int] = None,
         )
 
         cols.append(column_from_arrow(arr, field, cap))
-    from spark_rapids_tpu.obs import telemetry
-
-    nbytes = sum(c.device_size_bytes() for c in cols)
-    t0 = time.monotonic_ns()
-    out = jax.device_put(ColumnBatch(schema, cols, n))
-    telemetry.record("h2d", "scan.upload", nbytes,
-                     ns=time.monotonic_ns() - t0)
-    return out
+    return (ColumnBatch(schema, cols, n),
+            sum(c.device_size_bytes() for c in cols))
 
 
 def widen_traced(batch: ColumnBatch) -> ColumnBatch:
@@ -248,6 +257,16 @@ def shrink_traced(batch: ColumnBatch, cap2: int):
     ovf = nr > cap2
     cols = [c.truncate(cap2) for c in batch.columns]
     return ColumnBatch(batch.schema, cols, jnp.minimum(nr, cap2)), ovf
+
+
+@functools.lru_cache(maxsize=4096)
+def program_name(key_tag: str, nodes_key) -> str:
+    """`fused_<kind>_<8 hex digits>`: what a fused program is called in
+    the device trace (XLA module `jit_<name>`) and on its
+    `fused.dispatch` span. A digest of the structural key, so the same
+    plan gives the same name in every process; shapes are not in it."""
+    digest = hashlib.sha256(repr(nodes_key).encode()).hexdigest()[:8]
+    return f"fused_{key_tag}_{digest}"
 
 
 # --------------------------------------------------------- the executor
@@ -302,35 +321,36 @@ class FusedSingleChipExecutor:
 
         return get_catalog().pool.limit
 
-    def _plain_file_batch(self, scan: ops.TpuFileScanExec,
-                          path: str) -> Optional[ColumnBatch]:
+    def _plain_file_batch(self, scan: ops.TpuFileScanExec, path: str,
+                          parent=None) -> Optional[ColumnBatch]:
         """Device-direct scan of one PLAIN parquet file
         (io/parquet_plain.py): page payloads become zero-copy typed
         views, integers narrow for the link, capacity == rows so no pad
         copy touches the big float columns. None -> general reader."""
         from spark_rapids_tpu.io.parquet_plain import read_plain_columns
+        from spark_rapids_tpu.obs import events as obs_events
+        from spark_rapids_tpu.obs import telemetry
 
         if scan.fmt != "parquet" or scan.pushed_filters:
             return None
         names = [f.name for f in scan.schema.fields]
-        cols_np = read_plain_columns(path, names)
-        if cols_np is None:
-            return None
-        n = len(cols_np[names[0]])
-        cols: List[DeviceColumn] = []
-        for f in scan.schema.fields:
-            vals, vrange = _narrow(cols_np[f.name])
-            cols.append(DeviceColumn(
-                f.dataType, vals, np.ones(n, dtype=np.bool_),
-                vrange=vrange))
-        from spark_rapids_tpu.obs import telemetry
-
-        nbytes = sum(c.device_size_bytes() for c in cols)
-        t0 = time.monotonic_ns()
-        out = jax.device_put(ColumnBatch(scan.schema, list(cols), n))
-        telemetry.record("h2d", "scan.plain", nbytes,
-                         ns=time.monotonic_ns() - t0)
-        return out
+        with obs_events.span("scan.decode", parent=parent,
+                             path=path) as sp:
+            cols_np = read_plain_columns(path, names)
+            if cols_np is None:
+                return None
+            n = len(cols_np[names[0]])
+            cols: List[DeviceColumn] = []
+            for f in scan.schema.fields:
+                vals, vrange = _narrow(cols_np[f.name])
+                cols.append(DeviceColumn(
+                    f.dataType, vals, np.ones(n, dtype=np.bool_),
+                    vrange=vrange))
+            nbytes = sum(c.device_size_bytes() for c in cols)
+            sp.set(rows=n, bytes=nbytes)
+        return telemetry.put_watched(
+            ColumnBatch(scan.schema, list(cols), n), "scan.plain",
+            nbytes, parent)
 
     def _scan_parts(self, scan: ops.TpuFileScanExec) -> List[ColumnBatch]:
         tasks = [t for t in scan._tasks if t]
@@ -343,10 +363,17 @@ class FusedSingleChipExecutor:
         if fsz * 6 > self._hbm_budget():
             raise FusedCompileError("scan working set exceeds HBM budget")
 
+        from spark_rapids_tpu.obs import events as obs_events
+        from spark_rapids_tpu.obs import telemetry
+
+        # the reader threads have no query scope and no open span:
+        # theirs hang under this thread's (`fused.prepare`), by name
+        parent = obs_events.current_span()
+
         def one(task):
             out, rest = [], []
             for path in task:
-                b = (self._plain_file_batch(scan, path)
+                b = (self._plain_file_batch(scan, path, parent)
                      if scan.fmt == "parquet" else None)
                 if b is not None:
                     out.append(b)
@@ -354,9 +381,23 @@ class FusedSingleChipExecutor:
                     rest.append(path)
             if rest or scan.fmt != "parquet":
                 files = rest if scan.fmt == "parquet" else task
-                out.extend(upload_narrowed(t,
-                                           bucket=self._shape_buckets)
-                           for t in scan._host_tables(files))
+                tables = iter(scan._host_tables(files))
+                path = files[0] if len(files) == 1 else \
+                    f"{files[0]} (+{len(files) - 1})"
+                while True:
+                    # the reader is lazy: the parquet read happens in
+                    # next(), so that is where the decode span opens
+                    with obs_events.span("scan.decode", parent=parent,
+                                         path=path) as sp:
+                        t = next(tables, None)
+                        if t is None:
+                            sp.discard()
+                            break
+                        host, nbytes = _narrowed_host_batch(
+                            t, None, True, self._shape_buckets)
+                        sp.set(rows=t.num_rows, bytes=nbytes)
+                    out.append(telemetry.put_watched(
+                        host, "scan.upload", nbytes, parent))
             return out
 
         if len(tasks) == 1:
@@ -415,34 +456,55 @@ class FusedSingleChipExecutor:
         execute() and execute_repeated() run through here so the
         benchmark path cannot drift from the production path."""
         from spark_rapids_tpu.exec.base import new_task_context
+        from spark_rapids_tpu.obs import events as obs_events
         from spark_rapids_tpu.runtime import semaphore as sem
 
-        # validate the plan BEFORE decoding/uploading anything
-        self._validate(phys)
-        # materialize cold cache entries BEFORE taking permits: entry
-        # materialization runs a nested execute() with a FRESH task id,
-        # and a nested acquire under held permits deadlocks the
-        # semaphore (its re-entrancy is per-task-id)
-        self._premater_cached(phys)
-        ctx = new_task_context(self.conf)
-        sem.get().acquire_if_necessary(ctx.task_id)
-        self._rewrite_memo = {}  # keyed on node ids: valid per run
-        self._compile_metrics = {"keys": set(), "programsRequested": 0,
-                                 "cacheHits": 0}
+        # `fused.prepare`: everything the first launch waits for
+        with obs_events.span("fused.prepare") as sp:
+            # validate the plan BEFORE decoding/uploading anything
+            self._validate(phys)
+            # materialize cold cache entries BEFORE taking permits:
+            # entry materialization runs a nested execute() with a
+            # FRESH task id, and a nested acquire under held permits
+            # deadlocks the semaphore (its re-entrancy is per-task-id)
+            self._premater_cached(phys)
+            ctx = new_task_context(self.conf)
+            sem.get().acquire_if_necessary(ctx.task_id)
+            self._rewrite_memo = {}  # keyed on node ids: valid per run
+            self._compile_metrics = {"keys": set(),
+                                     "programsRequested": 0,
+                                     "cacheHits": 0}
+            try:
+                parts = self._prepare(
+                    phys, root_may_be_source=root_may_be_source)
+            except BaseException:
+                self._release(ctx)
+                raise
+            if sp.ref is not None:
+                sp.set(sources=len(parts),
+                       parts=sum(len(ps) for ps in parts.values()),
+                       bytes=sum(b.device_size_bytes()
+                                 for ps in parts.values() for b in ps))
         try:
-            self._prepare(phys, root_may_be_source=root_may_be_source)
             return body()
         finally:
-            sem.get().release_if_necessary(ctx.task_id)
-            self._src_parts = None
-            self._sources = None
-            self._rewrite_memo = {}
-            m = self._compile_metrics
-            self.last_compile_metrics = {
-                "variantCount": len(m["keys"]),
-                "programsCompiled": m["programsRequested"],
-                "cacheHits": m["cacheHits"],
-            }
+            self._release(ctx)
+
+    def _release(self, ctx) -> None:
+        """The way out of `_scaffold`: permits, per-run state, and the
+        run's compile accounting."""
+        from spark_rapids_tpu.runtime import semaphore as sem
+
+        sem.get().release_if_necessary(ctx.task_id)
+        self._src_parts = None
+        self._sources = None
+        self._rewrite_memo = {}
+        m = self._compile_metrics
+        self.last_compile_metrics = {
+            "variantCount": len(m["keys"]),
+            "programsCompiled": m["programsRequested"],
+            "cacheHits": m["cacheHits"],
+        }
 
     def _run_with_retry(self, phys: PhysicalPlan, as_parts: bool):
         """One settled run under the retry loop; returns
@@ -490,26 +552,13 @@ class FusedSingleChipExecutor:
             return self._oom_injection_eager_fallback(phys)
         from spark_rapids_tpu.obs import events as obs_events
 
-        if not obs_events.armed():
-            return self._scaffold(
-                phys, as_parts,
-                lambda: self._run_with_retry(phys, as_parts)[0])
         # the fused engine runs whole stages as single XLA programs, so
-        # operator-level spans don't exist; one pipeline-level span
-        # keeps fused queries visible in the tree/report attribution
-        import time as _time
-
-        t0 = _time.monotonic_ns()
-        try:
+        # there are no operator spans: its tree is prepare, one
+        # dispatch per program, fetch
+        with obs_events.span("fused.execute", root=type(phys).__name__):
             return self._scaffold(
                 phys, as_parts,
                 lambda: self._run_with_retry(phys, as_parts)[0])
-        finally:
-            dt = _time.monotonic_ns() - t0
-            obs_events.emit(
-                "operator.span",
-                operator=f"FusedPipeline({type(phys).__name__})",
-                metric="opTime", wallNs=dt, deviceNs=dt, rows=None)
 
     def _oom_injection_eager_fallback(self, phys: PhysicalPlan):
         """Run the plan on the per-operator eager engine (whose
@@ -628,6 +677,7 @@ class FusedSingleChipExecutor:
              group_cap: int, as_parts: bool = False,
              defer_flags: bool = False, use_lookup: bool = True,
              use_pushdown: bool = True):
+        from spark_rapids_tpu.obs import events as obs_events
         from spark_rapids_tpu.obs import telemetry
         from spark_rapids_tpu.parallel.plan_compiler import (
             _plan_key,
@@ -661,9 +711,7 @@ class FusedSingleChipExecutor:
                  _enc.encoding_key(b))
                 for b in batches)
 
-        def run_program(key_tag, nodes_key, fn, inputs,
-                        uses_expansion=False, uses_group_cap=False,
-                        uses_ansi=False):
+        def run_program(key_tag, nodes_key, fn, inputs, **uses):
             # program dispatch = the fused engine's cooperative yield
             # point (the per-attempt check of the stage scheduler,
             # scaled to this engine's unit of work): a cancelled query
@@ -671,7 +719,15 @@ class FusedSingleChipExecutor:
             # the pipeline to completion
             from spark_rapids_tpu.runtime import cancellation
 
-            cancellation.check_current()
+            name = program_name(key_tag, nodes_key)
+            with obs_events.span("fused.dispatch", program=name) as sp:
+                cancellation.check_current()
+                return dispatch(name, sp, key_tag, nodes_key, fn, inputs,
+                                **uses)
+
+        def dispatch(name, sp, key_tag, nodes_key, fn, inputs,
+                     uses_expansion=False, uses_group_cap=False,
+                     uses_ansi=False):
             # chaos site device.dispatch: an injected fault here is the
             # fused engine "dying mid-dispatch"; the dispatch ladder
             # (api/dataframe.py) demotes the query to the eager engine
@@ -700,9 +756,11 @@ class FusedSingleChipExecutor:
             from spark_rapids_tpu.runtime import jit_cache as jc
 
             m = self._compile_metrics
+            hit = True  # a key this run has seen was built by then
             if key not in m["keys"]:
                 m["keys"].add(key)
-                if jc.probe(key):
+                hit = jc.probe(key)
+                if hit:
                     m["cacheHits"] += 1
                     cc.stats.on_hit()
                     # keep the disk index's usage ranking honest:
@@ -710,6 +768,11 @@ class FusedSingleChipExecutor:
                     cc.record_use(key + jc._env_token(), "fused")
                 else:
                     m["programsRequested"] += 1
+            sp.set(cacheHit=hit)
+            # the XLA module is `jit_<name>` whoever builds it: this
+            # process, or the warm-up thread from a disk artifact
+            # (runtime/compile_cache.py gives the artifact this name)
+            fn.__name__ = fn.__qualname__ = name
             jitted = cached_jit(key, lambda: fn)
             # fatal-classification + chaos site device.fatal: a dead
             # PJRT client surfacing here fences the engine for warm
@@ -1093,15 +1156,27 @@ class FusedSingleChipExecutor:
             return (jnp.concatenate(ovf + uq + pf + ansi_flags),
                     len(ovf), len(uq), len(pf))
 
+        def assembled_flags(sp):
+            """all_flags_arr() inside the `fetch` span `sp`: a dozen
+            tiny device operations enqueued from the host, timed apart
+            (`flagsNs`) from the wait that follows."""
+            t0 = time.monotonic_ns()
+            out = all_flags_arr()
+            sp.set(flagsNs=time.monotonic_ns() - t0)
+            return out
+
         parts = emit_parts(phys)
         if as_parts:
-            arr, n_ovf, n_uniq, n_push = all_flags_arr()
             if defer_flags:
                 # benchmark path: caller syncs flags itself
+                arr, n_ovf, n_uniq, n_push = all_flags_arr()
                 return parts, arr, (n_ovf, n_uniq, n_push)
             # one host sync for overflow + ANSI; parts stay on device
-            _check_host_flags(telemetry.ledgered_get(
-                arr, "fused.flags"), n_ovf, n_uniq, n_push)
+            with obs_events.span("fetch", rows=0) as sp:
+                arr, n_ovf, n_uniq, n_push = assembled_flags(sp)
+                _check_host_flags(telemetry.ledgered_get(
+                    arr, "fused.flags"), n_ovf, n_uniq, n_push)
+                sp.set(bytes=arr.nbytes)
             return parts
         if len(parts) > 1:
             def collect_fn(*ps):
@@ -1115,19 +1190,27 @@ class FusedSingleChipExecutor:
                 return widen_traced(b), jnp.zeros((), bool)
 
             result = run_program("collect1", ("collect1",), one_fn, parts)
-        flags_arr, n_ovf, n_uniq, n_push = all_flags_arr()
-        if result.device_size_bytes() <= self._fetch_fused_bytes:
-            # small result: ONE round trip for rows+flags+data (the
-            # standard path pays three — row_count, flags, fetch)
-            from spark_rapids_tpu.columnar.arrow_bridge import (
-                device_to_arrow_fused,
-            )
+        # `fetch` blocks on the device: its length is the device time
+        # the dispatches left outstanding plus the transfer's own
+        with obs_events.span("fetch") as sp:
+            flags_arr, n_ovf, n_uniq, n_push = assembled_flags(sp)
+            nbytes = result.device_size_bytes()
+            if nbytes <= self._fetch_fused_bytes:
+                # small result: ONE round trip for rows+flags+data (the
+                # standard path pays three — row_count, flags, fetch)
+                from spark_rapids_tpu.columnar.arrow_bridge import (
+                    device_to_arrow_fused,
+                )
 
-            table, host_flags = device_to_arrow_fused(result, flags_arr)
-            _check_host_flags(np.asarray(host_flags), n_ovf, n_uniq,
-                              n_push)
-            return table
-        # one host sync for all flags before fetching results
-        _check_host_flags(telemetry.ledgered_get(
-            flags_arr, "fused.flags"), n_ovf, n_uniq, n_push)
-        return device_to_arrow(result)
+                table, host_flags = device_to_arrow_fused(result,
+                                                          flags_arr)
+                _check_host_flags(np.asarray(host_flags), n_ovf, n_uniq,
+                                  n_push)
+            else:
+                # one host sync for all flags before fetching results
+                _check_host_flags(telemetry.ledgered_get(
+                    flags_arr, "fused.flags"), n_ovf, n_uniq, n_push)
+                table = device_to_arrow(result)  # cut to its rows first
+                nbytes = table.nbytes
+            sp.set(bytes=nbytes, rows=table.num_rows)
+        return table

@@ -26,6 +26,7 @@ from typing import List, Optional
 
 from spark_rapids_tpu.obs import events as events  # noqa: F401
 from spark_rapids_tpu.obs.events import EventBus, EventHistory
+from spark_rapids_tpu.obs import spans as _spans
 from spark_rapids_tpu.obs.spans import Span, SpanBuilder
 
 
@@ -53,7 +54,7 @@ class ObsManager:
             return
         self.bus = EventBus()
         self.history = EventHistory(get(rc.OBS_HISTORY_EVENTS))
-        self.spans = SpanBuilder()
+        self.spans = SpanBuilder(ring=_spans.ring)
         self.bus.subscribe(self.history)
         self.bus.subscribe(self.spans)
         if get(rc.EVENTLOG_ENABLED):

@@ -29,7 +29,7 @@ import itertools
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 SCHEMA_VERSION = 1
 
@@ -43,7 +43,8 @@ EVENT_TYPES: Dict[str, str] = {
     "stage.end": "stage, name, status",
     "task.attempt.start": "stage, task, attempt, worker, speculative",
     "task.attempt.end": "stage, task, attempt, status, wallMs, rows",
-    "operator.span": "operator, metric, wallNs, deviceNs, rows",
+    "operator.span": "operator, metric, wallNs, deviceNs, rows, spanId, "
+                     "parentId, startNs, endNs (+ the span's own fields)",
     "shuffle.write": "shuffleId, reducePid, bytes, staged",
     "shuffle.fetch": "shuffleId, reducePid, blocks, bytes",
     "shuffle.retry": "shuffleId, reducePid, block",
@@ -276,6 +277,153 @@ def effective_query_id() -> int:
     if ctx and ctx.get("queryId"):
         return ctx["queryId"]
     return current_query_id()
+
+
+# --------------------------------------------------------------- spans
+#
+# One scope = one `jax.profiler.TraceAnnotation("srtpu:<name>")` (a host
+# event of the profiler's xplane) + one `operator.span` event at exit.
+# `startNs`/`endNs` are `time.time_ns()`: CLOCK_REALTIME, the clock the
+# xplane's host events are stamped with (docs/observability.md has the
+# offset a profiler session applies, as measured on a v5e). Spans nest
+# by a thread-local stack; work handed to another thread names its
+# parent explicitly.
+
+_span_ids = itertools.count(1)
+_span_tls = threading.local()
+_annotation = None  # jax.profiler.TraceAnnotation, on the first span
+
+
+class SpanRef(NamedTuple):
+    """What a span's children on OTHER threads need of it."""
+    span_id: Optional[int]
+    query_id: int
+
+
+def current_span() -> SpanRef:
+    """The innermost open span of this thread and the thread's query:
+    the `parent=` to hand to a pool thread."""
+    stack = getattr(_span_tls, "stack", None)
+    return SpanRef(stack[-1] if stack else None, effective_query_id())
+
+
+def _emit_span(bus: EventBus, name: str, span_id: int,
+               parent_id: Optional[int], start_ns: int, end_ns: int,
+               fields: dict) -> None:
+    """The one place an `operator.span` event is put together. Field
+    `device=True` copies the wall time into `deviceNs` (the eager
+    engine's convention: no device time is measured here); field
+    `operator` overrides the label the event carries."""
+    wall = end_ns - start_ns
+    bus.emit("operator.span",
+             operator=fields.pop("operator", name),
+             metric=fields.pop("metric", None), wallNs=wall,
+             deviceNs=wall if fields.pop("device", False) else 0,
+             rows=fields.pop("rows", None), spanId=span_id,
+             parentId=parent_id, startNs=start_ns, endNs=end_ns,
+             **fields)
+
+
+def record_span(name: str, start_ns: int, end_ns: int,
+                parent: Optional[SpanRef] = None, **fields) -> None:
+    """Emit one finished span. For a scope that was not a `with` block
+    on one thread: a wait that ended before its owner was known, a
+    transfer whose end another thread observed. `parent` defaults to
+    this thread's innermost open span; an explicit one also names the
+    query when this thread has none."""
+    bus = _bus
+    if bus is None:
+        return
+    if parent is None:
+        parent = current_span()
+    elif parent.query_id and not effective_query_id():
+        fields["queryId"] = parent.query_id
+    _emit_span(bus, name, next(_span_ids), parent.span_id,
+               int(start_ns), int(end_ns), fields)
+
+
+class span:
+    """`with span(name, parent=None, **fields) as sp:` — the span
+    primitive. With the bus off it costs the TraceAnnotation and one
+    None check. `sp.set(...)` adds fields known only inside the scope
+    (rows, bytes); `sp.ref` is the handle a child on another thread
+    passes as `parent=`, and such a child also lends its thread the
+    parent's query id while it is open, so ledger rows recorded under
+    it are the query's. `start_ns` backdates a scope whose beginning
+    was observed before the scope could be opened."""
+
+    __slots__ = ("name", "fields", "ref", "_parent", "_start", "_ann",
+                 "_lent_qid")
+
+    def __init__(self, name: str, parent: Optional[SpanRef] = None,
+                 start_ns: Optional[int] = None, **fields):
+        self.name = name
+        self.fields = fields
+        self.ref: Optional[SpanRef] = None  # set while armed and open
+        self._parent = parent
+        self._start = start_ns
+        self._lent_qid = False
+
+    def set(self, **fields) -> None:
+        if self.ref is not None and self.fields is not None:
+            self.fields.update(fields)
+
+    def discard(self) -> None:
+        """Leave no event: the scope found nothing to do (the read
+        that finds its iterator exhausted)."""
+        self.fields = None
+
+    def __enter__(self) -> "span":
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self._ann = _annotation("srtpu:" + self.name)
+        self._ann.__enter__()
+        if _bus is None:
+            return self
+        parent = self._parent
+        if parent is None:
+            parent = current_span()
+        elif parent.query_id and not effective_query_id():
+            _query_tls.qid = parent.query_id
+            self._lent_qid = True
+        self._parent = parent
+        self.ref = SpanRef(next(_span_ids),
+                           parent.query_id or effective_query_id())
+        stack = getattr(_span_tls, "stack", None)
+        if stack is None:
+            stack = _span_tls.stack = []
+        stack.append(self.ref.span_id)
+        if self._start is None:
+            self._start = time.time_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        ref = self.ref
+        if ref is not None:
+            end = time.time_ns()
+            stack = _span_tls.stack
+            # generators that hold a scope open across a yield may
+            # close out of order: remove this span wherever it sits
+            if stack and stack[-1] == ref.span_id:
+                stack.pop()
+            elif ref.span_id in stack:
+                stack.remove(ref.span_id)
+            self.ref = None
+            bus = _bus
+            if bus is not None and self.fields is not None:
+                if exc_type is not None:
+                    self.fields.setdefault("status", "error")
+                _emit_span(bus, self.name, ref.span_id,
+                           self._parent.span_id, self._start, end,
+                           self.fields)
+            if self._lent_qid:
+                _query_tls.qid = 0
+                self._lent_qid = False
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
 
 
 # -------------------------------------------------------- task context

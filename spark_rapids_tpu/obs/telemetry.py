@@ -47,6 +47,7 @@ boolean check.
 
 from __future__ import annotations
 
+import atexit
 import json
 import os
 import threading
@@ -217,8 +218,27 @@ class TransferLedger:
                 cell["count"] += 1
             self._site_dir[site] = direction
         if emit:
+            # an explicit owner (a pool thread's upload) names the
+            # query on the event too, so log and ledger agree
+            owner = {"queryId": qid} if query_id else {}
             _events.emit("transfer", direction=direction, site=site,
-                         bytes=int(nbytes), ns=int(ns))
+                         bytes=int(nbytes), ns=int(ns), **owner)
+
+    def add_ns(self, direction: str, site: str, ns: int,
+               query_id: int) -> None:
+        """The time of a transfer whose bytes `record(..., ns=0)`
+        counted when it was enqueued, added when its completion was
+        observed (`put_watched`): the row's `ns` is then time to
+        completion, never the time of an asynchronous enqueue."""
+        if not self.enabled or ns <= 0:
+            return
+        with self._lock:
+            q = self._query(query_id)
+            for cell in (self.totals.setdefault(direction, _cell()),
+                         self.sites.setdefault(site, _cell()),
+                         q.by_direction.setdefault(direction, _cell()),
+                         q.by_site.setdefault(site, _cell())):
+                cell["ns"] += int(ns)
 
     def record_encoded(self, site: str, actual_bytes: int,
                        plain_bytes: int,
@@ -653,6 +673,100 @@ def ledgered_get(x, site: str):
         out = jax.device_get(x)
     record("d2h", site, _tree_bytes(out),
            ns=_time.monotonic_ns() - t0)
+    return out
+
+
+# -------------------------------------------- uploads, to completion
+
+def _wait_ready(arrays) -> None:
+    import jax
+
+    jax.block_until_ready(arrays)
+
+
+class _UploadWatcher:
+    """One daemon thread that waits for uploaded arrays and then
+    closes their `scan.h2d` span and adds the time to the ledger row.
+    It only observes: the thread that uploaded and the dispatch that
+    consumes the arrays never wait for it."""
+
+    def __init__(self):
+        self._cv = threading.Condition()
+        self._items: deque = deque()
+        self._pending = 0
+        self._thread: Optional[threading.Thread] = None
+
+    def watch(self, arrays, site: str, nbytes: int, start_ns: int,
+              parent: "_events.SpanRef") -> None:
+        with self._cv:
+            self._items.append((arrays, site, nbytes, start_ns, parent))
+            self._pending += 1
+            if self._thread is None or not self._thread.is_alive():
+                if self._thread is None:
+                    # a daemon thread that interpreter shutdown catches
+                    # inside block_until_ready (C++ frames) aborts the
+                    # process: let the last uploads close first
+                    atexit.register(self.drain, 5.0)
+                self._thread = threading.Thread(
+                    target=self._run, name="srtpu-upload-watcher",
+                    daemon=True)
+                self._thread.start()
+            self._cv.notify_all()
+
+    def _run(self) -> None:
+        from jax.profiler import TraceAnnotation
+
+        while True:
+            with self._cv:
+                while not self._items:
+                    self._cv.wait()
+                arrays, site, nbytes, start_ns, parent = \
+                    self._items.popleft()
+            fields = {"site": site, "bytes": nbytes}
+            try:
+                with TraceAnnotation("srtpu:scan.h2d"):
+                    _wait_ready(arrays)
+            except Exception as e:  # a lost device: the query's to raise
+                fields.update(status="error",
+                              error=f"{type(e).__name__}: {e}"[:200])
+            end_ns = time.time_ns()
+            del arrays
+            ledger.add_ns("h2d", site, end_ns - start_ns, parent.query_id)
+            _events.record_span("scan.h2d", start_ns, end_ns,
+                                parent=parent, **fields)
+            with self._cv:
+                self._pending -= 1
+                self._cv.notify_all()
+
+    def drain(self, timeout: float = 30.0) -> bool:
+        """Wait until every watched upload has been closed (tests, and
+        whoever reads spans right after a query)."""
+        with self._cv:
+            return self._cv.wait_for(lambda: self._pending == 0, timeout)
+
+
+_upload_watcher = _UploadWatcher()
+drain_uploads = _upload_watcher.drain
+
+
+def put_watched(x, site: str, nbytes: int,
+                parent: Optional["_events.SpanRef"] = None):
+    """`jax.device_put` of a scan's upload, timed to its COMPLETION
+    off the caller's path. The bytes are ledgered now, under the query
+    `parent` names (a reader-pool thread has no query scope of its
+    own); a `scan.h2d` span from this call to the arrays' readiness,
+    and the same nanoseconds on the ledger row, follow from the
+    watcher thread. `parent`: `events.current_span()` of the thread
+    that owns the query, this thread's by default."""
+    import jax
+
+    if parent is None:
+        parent = _events.current_span()
+    start_ns = time.time_ns()
+    out = jax.device_put(x)
+    record("h2d", site, nbytes, query_id=parent.query_id)
+    if ledger.enabled or _events.armed():
+        _upload_watcher.watch(out, site, nbytes, start_ns, parent)
     return out
 
 

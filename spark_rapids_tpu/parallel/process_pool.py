@@ -74,7 +74,7 @@ def run_scan_agg_fragment(spec: dict):
         # forked worker: no CancelToken exists in this process — the
         # driver-side scheduler handles stragglers via speculation
         time.sleep(float(spec["sleep_s"]))  # srtpu-lint: disable=raw-sleep
-    t0 = time.monotonic_ns()
+    t0 = time.time_ns()
     t = pa.concat_tables([pq.read_table(p) for p in spec["files"]])
     f = spec.get("filter")
     if f is not None:
@@ -93,18 +93,18 @@ def run_scan_agg_fragment(spec: dict):
     # local bus and are forwarded with the task result (ProcessBackend
     # re-emits them under the driver's query/task identity).
     telemetry.record("shuffle", "worker.result", out.nbytes)
-    obs_events.emit("operator.span", operator="ScanAggFragment",
-                    metric="fragmentTime",
-                    wallNs=time.monotonic_ns() - t0, deviceNs=0,
-                    rows=out.num_rows)
+    obs_events.record_span("ScanAggFragment", t0, time.time_ns(),
+                           metric="fragmentTime", rows=out.num_rows)
     return out
 
 
 #: Envelope + task-identity keys stripped from forwarded events: the
 #: driver re-emits through its own bus, which reassigns all of them
 #: under the driver's query scope and the attempt's task identity.
+#: Span ids count per process, so a worker's would collide with the
+#: driver's: its spans hang by their task identity instead.
 _FWD_STRIP = ("seq", "ts", "schemaVersion", "queryId", "stage", "task",
-              "attempt", "speculative", "worker")
+              "attempt", "speculative", "worker", "spanId", "parentId")
 
 
 def _worker_main(worker_id: str, task_q, result_q, hb_addr,

@@ -32,7 +32,7 @@ writes that read) rides the enclosing query's admission, so nesting can
 never self-deadlock the queue.
 
 Observability: `admission.*` events (queued/admitted/shed/cancelled/
-deadline/quarantined) land on the obs bus, an `AdmissionQueue` operator
+deadline/quarantined) land on the obs bus, an `admission` operator
 span records the queue wait on the query's span tree, and the counter
 ledger surfaces in `session.robustness_metrics["admission"]` and
 bench.py's admission block. Chaos sites `admission.slow_drain` (delayed

@@ -355,7 +355,12 @@ def read_index() -> Dict[str, Dict[str, Any]]:
 
 
 def _record_index(digest: str, key_repr: str, tag: str,
-                  seconds: float, has_artifact: bool) -> None:
+                  seconds: float, has_artifact: bool,
+                  name: Optional[str] = None) -> None:
+    """`name`: the traced function's name, which the XLA module
+    carries (`jit_<name>`); warm-up gives a loaded artifact the same
+    one, so a program is called the same in the device trace whoever
+    built it."""
     import jax
 
     path = _index_path(digest)
@@ -374,6 +379,8 @@ def _record_index(digest: str, key_repr: str, tag: str,
                 has_artifact
     except (OSError, ValueError):
         pass
+    if name:
+        entry["name"] = name
     entry["count"] = int(entry.get("count", 0)) + 1
     entry["compile_s"] = round(
         float(entry.get("compile_s", 0.0)) + seconds, 4)
@@ -441,7 +448,8 @@ class _AsyncSaver(threading.Thread):
         if (jitted is not None and avals is not None
                 and tag in _ARTIFACT_TAGS):
             has_artifact = self._export(digest, key_repr, jitted, avals)
-        _record_index(digest, key_repr, tag, seconds, has_artifact)
+        _record_index(digest, key_repr, tag, seconds, has_artifact,
+                      getattr(jitted, "__name__", None))
 
     def _export(self, digest, key_repr, jitted, avals) -> bool:
         try:
@@ -575,12 +583,15 @@ def _warmup_run(top_k: int) -> None:
     import jax
 
     backend = jax.default_backend()
+    # an entry without a name predates stable program names: it is
+    # built live once more and recorded with one
     entries = [(d, e) for d, e in read_index().items()
-               if e.get("artifact") and e.get("backend") == backend]
+               if e.get("artifact") and e.get("backend") == backend
+               and e.get("name")]
     entries.sort(key=lambda de: (-int(de[1].get("count", 0)), de[0]))
     for digest, entry in entries[:top_k]:
         try:
-            fn = _load_artifact(digest, entry["key"])
+            fn = _load_artifact(digest, entry["key"], entry["name"])
         except Exception:
             fn = None
         if fn is not None:
@@ -602,10 +613,12 @@ def quarantine_artifact(digest: str) -> None:
     stats.on_quarantine()
 
 
-def _load_artifact(digest: str, key_repr: str) -> Optional[Callable]:
-    """Deserialize + AOT-compile one artifact. The .key sidecar must
-    equal the index's key repr — a mismatch means a digest collision or
-    a torn write, and the artifact is ignored.
+def _load_artifact(digest: str, key_repr: str,
+                   name: str = "call") -> Optional[Callable]:
+    """Deserialize + AOT-compile one artifact as XLA module
+    `jit_<name>`. The .key sidecar must equal the index's key repr — a
+    mismatch means a digest collision or a torn write, and the
+    artifact is ignored.
 
     Failure contract (PR 2): a corrupt/truncated artifact — or an
     injected compile.cache_load fault — is a CACHE MISS, never a query
@@ -629,7 +642,12 @@ def _load_artifact(digest: str, key_repr: str) -> Optional[Callable]:
         exp = jex.deserialize(blob)
         args, kwargs = jax.tree_util.tree_unflatten(
             exp.in_tree, exp.in_avals)
-        return jax.jit(exp.call).lower(*args, **kwargs).compile()
+
+        def call(*a, **k):
+            return exp.call(*a, **k)
+
+        call.__name__ = call.__qualname__ = name
+        return jax.jit(call).lower(*args, **kwargs).compile()
     except FileNotFoundError:
         return None  # plain miss: nothing to quarantine
     except Exception:

@@ -248,10 +248,10 @@ class DeviceMonitor:
             # the recovery window on the (cross-query) span surface —
             # the fence has no single owning query, so the span hangs
             # off whatever scope observes it (usually none)
-            obs_events.emit(
-                "operator.span", operator="DeviceRecovery",
-                metric="recoveryMs", wallNs=int(ms * 1_000_000),
-                deviceNs=0)
+            end_ns = time.time_ns()
+            obs_events.record_span(
+                "DeviceRecovery", end_ns - int(ms * 1_000_000), end_ns,
+                metric="recoveryMs")
             self._notify_admission()
 
     def _rebuild_backend(self) -> None:

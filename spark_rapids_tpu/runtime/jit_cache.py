@@ -126,13 +126,18 @@ def _make_entry(full: Tuple, key: Tuple, build: Callable[[], Callable],
                 state["jitted"] = jax.jit(build(), **jit_kwargs)
             if not state["timed"]:
                 state["timed"] = True
+                from spark_rapids_tpu.obs import events as obs_events
+
                 t0 = time.perf_counter()
-                out = state["jitted"](*args, **kwargs)
-                # async dispatch returns once tracing+compilation are
-                # done (execution overlaps) — the cold-start quantity
+                with obs_events.span("compile", kind=tag) as sp:
+                    out = state["jitted"](*args, **kwargs)
+                    # async dispatch returns once tracing+compilation
+                    # are done (execution overlaps) — the cold-start
+                    # quantity
+                    seconds = time.perf_counter() - t0
+                    sp.set(seconds=round(seconds, 6))
                 cc.record_build(
-                    full, tag, time.perf_counter() - t0,
-                    state["jitted"],
+                    full, tag, seconds, state["jitted"],
                     args if not (kwargs or jit_kwargs) else None)
                 return out
         return state["jitted"](*args, **kwargs)

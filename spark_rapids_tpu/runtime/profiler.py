@@ -59,30 +59,20 @@ def annotate_with_metric(name: str, metric, span: Optional[dict] = None):
     """Named range COUPLED with a nanosecond metric — the exact
     NvtxWithMetrics contract (one scope, both the timeline range and
     the operator metric accumulate) — and, when the obs bus is armed,
-    an `operator.span` event so the scope lands in the query's span
-    tree (obs/spans.py). `span` supplies extra span fields (operator
-    name override, device flag, rows); the thread's scheduler task
-    scope is inherited by the event automatically."""
+    a span in the query's tree: a caller of `obs.events.span`, the one
+    span mechanism. `span` supplies extra span fields (operator name
+    override, device flag, rows); the thread's scheduler task scope is
+    inherited by the event automatically."""
     import time as _time
-
-    import jax
 
     from spark_rapids_tpu.obs import events as _events
 
     t0 = _time.monotonic_ns()
     try:
-        with jax.profiler.TraceAnnotation(name):
+        with _events.span(name, metric=metric.name, **(span or {})):
             yield
     finally:
-        dt = _time.monotonic_ns() - t0
-        metric.add(dt)
-        if _events.armed():
-            fields = dict(span or {})
-            fields.setdefault("operator", name)
-            device = bool(fields.pop("device", False))
-            _events.emit("operator.span", metric=metric.name,
-                         wallNs=dt, deviceNs=dt if device else 0,
-                         **fields)
+        metric.add(_time.monotonic_ns() - t0)
 
 
 def save_device_memory_profile(path: str) -> Optional[str]:

@@ -604,8 +604,10 @@ def test_admission_events_and_queue_wait_span(tmp_path):
         assert s.last_execution["admission"]["queueWaitMs"] >= 40
         # the queue wait hangs on the query's span tree
         root = s.obs.last_spans
-        names = [sp.name for sp in root.walk()]
-        assert "AdmissionQueue" in names
+        waits = [sp for sp in root.children if sp.name == "admission"]
+        assert len(waits) == 1
+        assert waits[0].wall_ns >= 40_000_000
+        assert waits[0].start_ns == root.start_ns  # inside the query
     finally:
         s.stop()
 

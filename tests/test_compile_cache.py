@@ -514,3 +514,45 @@ def test_export_failure_is_counted_not_silent(cache_session, tmp_path):
         assert failed == len(without) > 0, (failed, index)
     finally:
         cc._artifact_min_s = rc.COMPILE_CACHE_ARTIFACT_MIN_S.default
+
+
+def test_a_warm_artifact_carries_its_programs_name(tmp_path):
+    """A fused program is one XLA module name in the device trace
+    whoever built it: the index records the traced function's name,
+    and warm-up compiles the loaded artifact under it (an entry from
+    before there were names is left to be built live once more)."""
+    from spark_rapids_tpu.runtime import jit_cache
+
+    jit_cache.clear()
+    cc.reset_for_tests()
+    s = TpuSparkSession({
+        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "c"),
+        "spark.rapids.tpu.compileCache.warmup.enabled": False,
+        "spark.rapids.tpu.compileCache.artifact.minCompileSecs": 0.0,
+    })
+    try:
+        _mini_q5(s).collect_arrow()
+        assert s.last_execution["engine"] == "fused"
+        cc.flush()
+        served = {d: e for d, e in cc.read_index().items()
+                  if e.get("artifact")}
+        assert served
+        for e in served.values():
+            assert e["tag"] == "fused" and e["name"].startswith("fused_")
+        old = sorted(served)[0]  # one entry forgets its name
+        entry = dict(served[old])
+        del entry["name"]
+        with open(cc._index_path(old), "w") as f:
+            json.dump(entry, f)
+        cc._warmup_run(top_k=64)
+        assert cc.warm_count() == len(served) - 1
+        with cc._warm_lock:
+            warm = dict(cc._warm)
+        assert served[old]["key"] not in warm
+        for d, e in served.items():
+            if d != old:
+                assert f"jit_{e['name']}" in warm[e["key"]].as_text()
+    finally:
+        s.stop()
+        cc.reset_for_tests()
+        jit_cache.clear()

@@ -418,3 +418,260 @@ def test_obs_disabled_session_emits_nothing():
         assert s.last_execution["engine"] is not None
     finally:
         s.stop()
+
+
+# ------------------------------------- spans inside a query (ISSUE 24)
+
+def _span_events(spans, qid=1, end=True):
+    """A query's event stream from (name, spanId, parentId, startNs,
+    endNs) rows, in the order given (a child's event comes at ITS end,
+    so before its parent's)."""
+    seq = itertools.count(1)
+
+    def ev(event, **f):
+        return {"event": event, "seq": next(seq), "ts": 0.0,
+                "schemaVersion": SCHEMA_VERSION, "queryId": qid, **f}
+
+    out = [ev("query.start")]
+    for name, sid, pid, s, e in spans:
+        out.append(ev("operator.span", operator=name, metric=None,
+                      wallNs=e - s, deviceNs=0, rows=None, spanId=sid,
+                      parentId=pid, startNs=s, endNs=e))
+    if end:
+        out.append(ev("query.end", engine="fused", status="ok"))
+    return out
+
+
+def test_spans_nest_by_parent_id_whatever_the_order_of_arrival():
+    root, = S.build_from_events(_span_events([
+        ("scan.decode", 4, 3, 110, 150),       # before its parent
+        ("fused.prepare", 3, 2, 100, 200),     # before ITS parent
+        ("plan", 5, 1, 10, 90),
+        ("fused.execute", 2, 1, 95, 900),
+        ("orphan", 7, 99, 20, 30),             # parent never reports
+        ("query", 1, None, 0, 1000),
+    ]))
+    assert root.kind == "query" and root.name == "query-1"
+    assert (root.start_ns, root.end_ns, root.wall_ns) == (0, 1000, 1000)
+    # children in order of start; the orphan hangs off the root
+    assert [c.name for c in root.children] == [
+        "plan", "orphan", "fused.execute"]
+    execute = root.children[2]
+    assert [c.name for c in execute.children] == ["fused.prepare"]
+    assert [c.name for c in execute.children[0].children] == [
+        "scan.decode"]
+    assert S.tree_depth(root) == 4
+    # the orphan's [20, 30] lies inside plan's [10, 90]: counted once
+    assert root.self_ns() == 1000 - 80 - 805
+
+
+def test_self_time_with_overlapping_pool_thread_children():
+    root, = S.build_from_events(_span_events([
+        # two reader threads decode at once; an upload outlives the
+        # scope that began it
+        ("scan.decode", 3, 2, 100, 300),
+        ("scan.decode", 4, 2, 200, 400),
+        ("scan.h2d", 5, 2, 450, 700),
+        ("fused.prepare", 2, 1, 50, 500),
+        ("query", 1, None, 0, 1000),
+    ]))
+    prepare, = root.children
+    # [100, 400] and [450, 500] of [50, 500]: 350 covered
+    assert prepare.self_ns() == 450 - 350
+    assert root.self_ns() == 1000 - 450
+    totals = S.operator_totals(root)
+    assert totals["scan.decode"]["wallNs"] == 400  # thread time
+    assert totals["fused.prepare"]["wallNs"] == 100
+
+
+def test_operator_totals_count_no_nanosecond_twice():
+    """explain's `total:` line and the profile's top list sum
+    operator_totals: a parent must not bring its children's time
+    again."""
+    root, = S.build_from_events(_span_events([
+        ("fused.dispatch", 3, 2, 10, 40),
+        ("fetch", 4, 2, 40, 90),
+        ("fused.execute", 2, 1, 5, 95),
+        ("query", 1, None, 0, 100),
+    ]))
+    totals = S.operator_totals(root)
+    assert sum(t["wallNs"] for t in totals.values()) == 90
+    assert totals["fused.execute"]["wallNs"] == 10
+    s = _session()
+    try:
+        from spark_rapids_tpu.obs import telemetry
+
+        q = _query(s)
+        q.collect_arrow()
+        assert telemetry.drain_uploads(10.0)
+        live = s.obs.last_spans
+        assert live.extra["engine"] == "fused"
+        totals = S.operator_totals(live)
+        # (an upload's span runs beside the thread that began it)
+        h2d = totals["scan.h2d"]["wallNs"]
+        summed = sum(t["wallNs"] for t in totals.values())
+        assert 0 < summed - h2d <= live.wall_ns
+        from spark_rapids_tpu.explain import explain_potential_tpu_plan
+
+        txt = explain_potential_tpu_plan(q, mode="EXECUTED")
+        total_ms = float(txt.split("total: wall=")[1].split("ms")[0])
+        assert total_ms == pytest.approx(summed / 1e6, abs=0.01)
+    finally:
+        s.stop()
+
+
+def test_operator_spans_start_before_they_end():
+    evs = _span_events([("plan", 2, 1, 10, 90),
+                        ("query", 1, None, 0, 100)], end=False)
+    # an emitter that gives a duration only (at its end)
+    evs.append({"event": "operator.span", "seq": 99, "ts": 2.0,
+                "schemaVersion": SCHEMA_VERSION, "queryId": 1,
+                "operator": "DeviceRecovery", "wallNs": 500_000_000,
+                "deviceNs": 0})
+    root, = S.build_from_events(evs)
+    ops = [sp for sp in root.walk() if sp.kind == "operator"]
+    assert len(ops) == 2
+    for sp in ops:
+        assert sp.start_ts < sp.end_ts
+        assert sp.end_ns - sp.start_ns == sp.wall_ns
+    old, = [sp for sp in ops if sp.name == "DeviceRecovery"]
+    assert (old.start_ts, old.end_ts) == (1.5, 2.0)
+
+
+def test_a_late_span_is_counted_and_still_hung_in_its_tree():
+    evs = _span_events([("fused.prepare", 2, 1, 10, 50),
+                        ("query", 1, None, 0, 100)])
+    late, = _span_events([("scan.h2d", 3, 2, 20, 120)])[1:2]
+    b = S.SpanBuilder()
+    for ev in evs + [late]:
+        b(ev)
+    assert b.late_spans == 1
+    assert [c.name for c in b.last.children[0].children] == ["scan.h2d"]
+    # a span of a query the builder never saw is counted, nothing more
+    b({**late, "queryId": 77})
+    assert b.late_spans == 2
+
+
+def test_explicit_parent_and_query_id_across_a_thread_pool():
+    from concurrent.futures import ThreadPoolExecutor
+
+    from spark_rapids_tpu.obs import ObsManager, telemetry
+    from spark_rapids_tpu.obs import events as E
+
+    obs = ObsManager()
+    try:
+        qid = E.begin_query()
+        before = telemetry.ledger.query_summary(qid)["bytesMovedTotal"]
+        with E.span("fused.prepare") as prepare:
+            parent = E.current_span()
+            assert parent == prepare.ref and parent.query_id == qid
+
+            def work(i):
+                assert E.effective_query_id() == 0  # a bare pool thread
+                with E.span("scan.decode", parent=parent, path=str(i)):
+                    assert E.effective_query_id() == qid
+                    telemetry.record("h2d", "test.pool", 1000)
+                    with E.span("inner"):  # nests by the thread's stack
+                        pass
+                assert E.effective_query_id() == 0
+                E.record_span("scan.h2d", 1, 2, parent=parent, site="x")
+
+            with ThreadPoolExecutor(max_workers=3) as pool:
+                list(pool.map(work, range(3)))
+        E.finish_query(qid, engine="fused", status="ok")
+        root = obs.last_spans
+        assert root.query_id == qid
+        prepare_sp, = root.children
+        names = sorted(c.name for c in prepare_sp.children)
+        assert names == ["scan.decode"] * 3 + ["scan.h2d"] * 3
+        for c in prepare_sp.children:
+            assert c.query_id == qid
+            if c.name == "scan.decode":
+                assert [g.name for g in c.children] == ["inner"]
+        moved = telemetry.ledger.query_summary(qid)["bytesMovedTotal"]
+        assert moved - before == 3000  # the pool threads' rows are ITS
+    finally:
+        obs.close()
+
+
+def test_fused_query_tree_and_the_ring_that_survives_stop(tmp_path):
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from spark_rapids_tpu.obs import telemetry
+
+    d = tmp_path / "t"
+    d.mkdir()
+    for i in range(3):
+        pq.write_table(pa.table({
+            "k": [j % 5 for j in range(2000)],
+            "v": [float(j + i) for j in range(2000)]}),
+            str(d / f"p{i}.parquet"))
+    s = _session()
+    try:
+        q = (s.read.parquet(str(d)).filter(F.col("v") > 5.0)
+             .groupBy("k").agg(F.sum("v").alias("sv")))
+        q.collect_arrow()
+        assert s.last_execution["engine"] == "fused"
+        assert telemetry.drain_uploads(10.0)
+        root = s.obs.last_spans
+        assert root.span_id is not None and root.end_ns > root.start_ns
+        assert root.extra["engine"] == "fused"
+        top = [c.name for c in root.children]
+        assert top.count("plan") == 1 and top.count("fused.execute") == 1
+        execute, = [c for c in root.children if c.name == "fused.execute"]
+        inner = [c.name for c in execute.children]
+        assert inner[0] == "fused.prepare" and inner[-1] == "fetch"
+        assert inner.count("fused.dispatch") >= 2
+        for sp in execute.children:
+            assert sp.device_ns == 0  # no device time was measured
+            if sp.name == "fused.dispatch":
+                assert sp.extra["program"].startswith("fused_")
+        prepare = execute.children[0]
+        scans = [c.name for c in prepare.children]
+        assert scans.count("scan.decode") == scans.count("scan.h2d") >= 1
+        for sp in root.walk():  # every span lies on one clock
+            assert root.start_ns <= sp.start_ns <= sp.end_ns
+        # closure: the root's children and its self time are all of it
+        assert root.self_ns() + sum(
+            c.wall_ns for c in root.children) == root.wall_ns
+        assert s.obs.spans.late_spans == 0
+    finally:
+        s.stop()
+    assert S.ring.last(1)[0] is root  # outlives the session
+
+
+def test_eventlog_rebuilds_the_nested_tree(tmp_path):
+    d = str(tmp_path / "log")
+    s = _session(**{"spark.rapids.tpu.eventLog.enabled": True,
+                    "spark.rapids.tpu.eventLog.dir": d})
+    try:
+        _query(s).collect_arrow()
+        assert s.last_execution["engine"] == "fused"
+        qid = s.last_execution["queryId"]
+        live = s.obs.last_spans
+    finally:
+        s.stop()
+    loaded, = eventlog.load_spans(d, qid)
+    assert loaded.to_dict() == live.to_dict()
+    execute, = [c for c in loaded.children if c.name == "fused.execute"]
+    assert {"fused.prepare", "fused.dispatch", "fetch"} <= {
+        c.name for c in execute.children}
+    for ev in eventlog.load(d, qid):
+        if ev["event"] == "operator.span":
+            assert ev["startNs"] <= ev["endNs"] and ev["spanId"]
+
+
+def test_span_with_the_bus_off_emits_nothing():
+    from spark_rapids_tpu.obs import events as E
+
+    assert not E.armed()
+    seen = []
+    bus = EventBus()
+    bus.subscribe(seen.append)  # a bus nobody installed
+    with E.span("plan", nodes=3) as sp:
+        assert sp.ref is None
+        sp.set(rows=1)
+        assert E.current_span().span_id is None
+    E.record_span("scan.h2d", 1, 2)
+    assert seen == [] and sp.fields == {"nodes": 3}

@@ -471,3 +471,68 @@ def test_http_disabled_by_default():
         assert s.obs.http is None
     finally:
         s.stop()
+
+
+# ------------------------------- uploads timed to completion (ISSUE 24)
+
+def test_scan_upload_belongs_to_its_query_and_is_timed_to_completion(
+        tmp_path, monkeypatch):
+    """An uncached scan uploads from reader-pool threads, which have no
+    query scope: the bytes must land in the QUERY's ledger all the
+    same, and the `scan.upload` row's ns is the time to the transfer's
+    completion (here held back by a forced delay), which the query
+    itself never waits for."""
+    import time
+
+    import pyarrow.parquet as pq
+
+    d = tmp_path / "t"
+    d.mkdir()
+    for i in range(3):  # three files: three tasks, a reader pool
+        pq.write_table(_table(2048), str(d / f"p{i}.parquet"))
+    delay_s = 0.25
+    real_wait = telemetry._wait_ready
+
+    def slow_wait(arrays):
+        time.sleep(delay_s)  # srtpu-lint: disable=raw-sleep
+        real_wait(arrays)
+
+    s = _session()
+    try:
+        df = (s.read.parquet(str(d)).filter(F.col("v") >= 0.0)
+              .groupBy("k").agg(F.sum("v").alias("sv")))
+        df.collect_arrow()  # compiles
+        assert telemetry.drain_uploads(10.0)
+        monkeypatch.setattr(telemetry, "_wait_ready", slow_wait)
+        before = {r["site"]: dict(r)
+                  for r in telemetry.ledger.site_rows()}.get(
+            "scan.upload", {"bytes": 0, "ns": 0, "count": 0})
+        t0 = time.perf_counter()
+        df.collect_arrow()
+        took_s = time.perf_counter() - t0
+        assert s.last_execution["engine"] == "fused"
+        qid = s.last_execution["queryId"]
+        tel = s.last_execution["telemetry"]
+        site = tel["perSite"]["scan.upload"]
+        assert site["count"] == 3 and site["bytes"] > 0
+        assert tel["bytesMoved"]["h2d"] == site["bytes"]
+        assert took_s < 3 * delay_s  # nobody waited for the watcher
+        assert telemetry.drain_uploads(10.0)
+        row = {r["site"]: r for r in telemetry.ledger.site_rows()}[
+            "scan.upload"]
+        assert row["bytes"] - before["bytes"] == site["bytes"]
+        assert row["count"] - before["count"] == 3
+        # one watcher closes them in turn: 1, 2 and 3 delays
+        assert row["ns"] - before["ns"] >= 3 * delay_s * 1e9
+        mine = telemetry.ledger.query_summary(qid)["perSite"][
+            "scan.upload"]
+        assert mine["ns"] == row["ns"] - before["ns"]
+        # the span and the ledger agree
+        spans = [sp for sp in s.obs.last_spans.walk()
+                 if sp.name == "scan.h2d"]
+        assert len(spans) == 3
+        assert sum(sp.wall_ns for sp in spans) == mine["ns"]
+        assert {sp.query_id for sp in spans} == {qid}
+        assert all(sp.extra["site"] == "scan.upload" for sp in spans)
+    finally:
+        s.stop()
