@@ -26,7 +26,7 @@ Operator -> reference mapping:
 from __future__ import annotations
 
 import itertools
-from contextlib import closing
+from contextlib import closing, nullcontext
 from typing import Dict, Iterator, List, Optional, Sequence
 
 import jax
@@ -812,7 +812,7 @@ class TpuHashAggregateExec(PhysicalPlan):
                           if conf is not None else None)
         base_key = ("agg", mode, self._mm_ok, self._mm_max_bins,
                     self._mm_chunk, aliases_key(grouping),
-                    aliases_key(aggs))
+                    aliases_key(aggs)) + self.lowering_key()
         det = detached(self)
         if any(not a.children[0].jittable for a in aggs):
             # collect_list/percentile family: update/merge output widths
@@ -833,10 +833,29 @@ class TpuHashAggregateExec(PhysicalPlan):
         self._ansi_jit = None if mode == "final" else _build_ansi_check(
             conf, list(grouping) + list(aggs), base_key)
 
+    def lowering_key(self) -> tuple:
+        """What a program key must carry of HOW this aggregate reduces,
+        beyond its expressions: a keyless aggregate reduces densely
+        (`_reductions`), and a program cache that has seen the scatter
+        lowering under the bare key must not serve it. Empty for a keyed
+        aggregate, whose keys and program names stay what they were."""
+        return () if self.grouping else ("dense",)
+
+    def _node_string(self) -> str:
+        s = type(self).__name__
+        return s if self.grouping else f"{s} [reduce=dense]"
+
     # --- phases (each a single XLA program) ---
 
     def _grouped(self, batch: ColumnBatch, key_idx, live=None):
         return segmented.group_by(batch, key_idx, live)
+
+    def _reductions(self):
+        """Trace-time context for this aggregate's update/merge calls:
+        without a grouping key every row is in segment 0, so the
+        segmented primitives reduce densely instead of scattering each
+        row into one slot (segmented.one_segment)."""
+        return nullcontext() if self.grouping else segmented.one_segment()
 
     @staticmethod
     def _bin_ranges(work: ColumnBatch, nkeys: int):
@@ -916,7 +935,8 @@ class TpuHashAggregateExec(PhysicalPlan):
             else:
                 vals = [g.sorted_batch.columns[ci + j] for j in range(k)]
             ci += k
-            out_cols.extend(fn.update(vals, g.live, g.gid, cap))
+            with self._reductions():
+                out_cols.extend(fn.update(vals, g.live, g.gid, cap))
         return ColumnBatch(_buffer_schema(self.grouping, self.aggs),
                            out_cols, g.num_groups)
 
@@ -946,8 +966,6 @@ class TpuHashAggregateExec(PhysicalPlan):
                              c.data.astype(jnp.int64) - lo + 1, 0)
             gid64 = gid64 + code * stride
             stride *= hi - lo + 2
-        from contextlib import nullcontext
-
         bcap = next_capacity(stride)
         gid = jnp.clip(gid64, 0, bcap - 1).astype(jnp.int32)
         mm_ok = self._mm_ok
@@ -1116,7 +1134,8 @@ class TpuHashAggregateExec(PhysicalPlan):
             nb = len(fn.buffer_types())
             bufs = [g.sorted_batch.columns[ci + j] for j in range(nb)]
             ci += nb
-            merged = fn.merge(bufs, g.live, g.gid, cap)
+            with self._reductions():
+                merged = fn.merge(bufs, g.live, g.gid, cap)
             out_cols.append(fn.evaluate(merged))
         return ColumnBatch(self.schema, out_cols, g.num_groups)
 
@@ -1135,7 +1154,8 @@ class TpuHashAggregateExec(PhysicalPlan):
             nb = len(fn.buffer_types())
             bufs = [g.sorted_batch.columns[ci + j] for j in range(nb)]
             ci += nb
-            out_cols.extend(fn.merge(bufs, g.live, g.gid, cap))
+            with self._reductions():
+                out_cols.extend(fn.merge(bufs, g.live, g.gid, cap))
         return ColumnBatch(_buffer_schema(self.grouping, self.aggs),
                            out_cols, g.num_groups)
 

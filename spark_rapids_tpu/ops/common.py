@@ -19,12 +19,12 @@ implements multi-column ORDER BY / GROUP BY / join-key ordering directly:
 Descending order is bitwise NOT of the key (total order reversal without
 overflow).
 
-TPU 64-bit caveat: XLA:TPU v5e emulates s64 exactly but demotes f64
-arithmetic to f32 precision and cannot bitcast 64-bit types. DoubleType
-sort keys therefore go through the f32 total-order bits on TPU (order is
-approximate only for doubles closer than 2^-24 relative — the values
-themselves are already f32-demoted there) and through exact f64 bits on
-the CPU backend.
+TPU 64-bit caveat: XLA:TPU v5e emulates s64 exactly and f64 as a pair
+of f32 (sums read ~1e-13 relative on the chip: docs/compatibility.md)
+but cannot bitcast 64-bit types. DoubleType sort keys therefore go
+through the f32 total-order bits on TPU (order is approximate for
+doubles closer than 2^-24 relative) and through exact f64 bits on the
+CPU backend.
 """
 
 from __future__ import annotations

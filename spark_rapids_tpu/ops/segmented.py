@@ -7,6 +7,17 @@ orderable group keys, find segment boundaries, then segmented reductions
 with num_segments = capacity (static). Group outputs land compacted at
 segment-id positions, so the result batch needs no extra compaction pass.
 
+A KEYLESS aggregate has one segment, and a scatter-add of every row
+into slot 0 is the worst thing to ask of XLA:TPU (the serialized update
+loop below: 77 ns a row, 4.6 s of every TPC-H Q6 over 30M rows). Inside
+`one_segment()` — entered by TpuHashAggregateExec when its plan has no
+grouping key — the six primitives (seg_count, seg_sum, seg_sum_count,
+seg_multi_sum, seg_min, seg_max) are masked whole-array reductions in
+the buffer's OWN type (f64 sums accumulate in f64, integer sums and
+counts in i64), with the scalar placed at position 0 of the same [cap]
+result. The f32-chunk / f64-carry stance further down belongs to the
+binned MXU path only.
+
 Reference: GpuAggregateExec.scala:175-400 (AggHelper pre-process ->
 groupby -> merge).
 """
@@ -56,6 +67,43 @@ def unsorted_gids():
         _SORTED_GIDS.reset(tok)
 
 
+# Trace-time flag: every gid is 0 (a keyless aggregate), so a segmented
+# reduction is a dense one. Static, chosen by the plan's shape; callers
+# outside the context (grouped aggregates, window operators,
+# partitioning, collectives) lower exactly as before.
+_ONE_SEGMENT = contextvars.ContextVar("srtpu_one_segment", default=False)
+
+#: trace-time counter of one-segment (dense) reductions, the twin of
+#: mm_traced_sweeps: tests assert that the keyless path engaged
+dense_traced_reductions = 0
+
+
+@contextmanager
+def one_segment():
+    """Declare that every row belongs to segment 0 (no grouping key):
+    the seg_* primitives ignore `gid` and reduce densely."""
+    tok = _ONE_SEGMENT.set(True)
+    try:
+        yield
+    finally:
+        _ONE_SEGMENT.reset(tok)
+
+
+def _dense(reduce, values: jnp.ndarray, valid: jnp.ndarray, ident,
+           cap: int) -> jnp.ndarray:
+    """reduce(where(valid, values, ident)) over the row axis at position
+    0 of a [cap, ...] result; the other positions hold what the scatter
+    left in segments no row reached (`ident`). Built with a select, not
+    `.at[0].set`, which is itself a scatter."""
+    global dense_traced_reductions
+    dense_traced_reductions += 1
+    ident = jnp.asarray(ident, values.dtype)
+    row = valid.reshape(valid.shape + (1,) * (values.ndim - 1))
+    r = reduce(jnp.where(row, values, ident), axis=0)
+    first = jnp.arange(cap, dtype=jnp.int32) == 0
+    return jnp.where(first.reshape((cap,) + (1,) * r.ndim), r, ident)
+
+
 # ---- MXU segmented reductions (the binned path's hot kernels) ----
 #
 # XLA:TPU lowers scatter-add (jax.ops.segment_sum) to a serialized
@@ -77,9 +125,10 @@ def unsorted_gids():
 #     upload narrowing), a chunk of C rows sums to < V*C; choosing C
 #     with V*C <= 2^24 keeps every chunk partial exact in f32, and the
 #     i64 carry is exact. Unbounded i64 sums fall back to scatter.
-#   - float sums: f32 chunk partials with an f64 carry — within the
-#     engine's documented v5e stance (f64 arithmetic at f32 precision,
-#     docs/compatibility.md).
+#   - float sums: f32 chunk partials with an f64 carry — ~1e-5
+#     relative at worst, the stance docs/compatibility.md states for
+#     THIS path only (v5e's f64 is emulated to ~1e-13 elsewhere; a
+#     keyless aggregate's dense reduce, above, keeps that).
 # min/max have no outer-product form and keep the scatter path (their
 # cost only matters if a plan min/maxes a huge un-sorted batch).
 
@@ -128,6 +177,8 @@ def force_matmul_path():
 
 
 def _mm_bins() -> Optional[int]:
+    if _ONE_SEGMENT.get():
+        return None  # one segment: dense reduce, never the f32 chunks
     b = _MM_BINS.get()
     lim = _MM_LIMITS.get()
     if b is None or b > (lim[0] if lim else MM_MAX_BINS):
@@ -347,6 +398,8 @@ def group_by(batch: ColumnBatch, key_idxs: Sequence[int],
 # gids produces silently wrong results on TPU.
 
 def seg_count(valid: jnp.ndarray, gid: jnp.ndarray, cap: int) -> jnp.ndarray:
+    if _ONE_SEGMENT.get():
+        return _dense(jnp.sum, valid.astype(jnp.int64), valid, 0, cap)
     b = _mm_bins()
     if b is not None and b <= cap:
         return _pad_bins(_mm_seg_count(valid, gid, b), cap)
@@ -357,6 +410,8 @@ def seg_count(valid: jnp.ndarray, gid: jnp.ndarray, cap: int) -> jnp.ndarray:
 
 def seg_sum(values: jnp.ndarray, valid: jnp.ndarray, gid: jnp.ndarray,
             cap: int, vbound=None) -> jnp.ndarray:
+    if _ONE_SEGMENT.get():
+        return _dense(jnp.sum, values, valid, 0, cap)
     b = _mm_bins()
     if b is not None and b <= cap and values.ndim == 1:
         r = _mm_seg_sum(values, valid, gid, b, vbound)
@@ -423,6 +478,8 @@ def seg_min(values: jnp.ndarray, valid: jnp.ndarray, gid: jnp.ndarray,
         ident = jnp.array(jnp.inf, dtype=values.dtype)
     else:
         ident = jnp.array(jnp.iinfo(values.dtype).max, dtype=values.dtype)
+    if _ONE_SEGMENT.get():
+        return _dense(jnp.min, values, valid, ident, cap)
     return jax.ops.segment_min(jnp.where(valid, values, ident), gid,
                                num_segments=cap,
                                indices_are_sorted=_SORTED_GIDS.get())
@@ -434,6 +491,8 @@ def seg_max(values: jnp.ndarray, valid: jnp.ndarray, gid: jnp.ndarray,
         ident = jnp.array(-jnp.inf, dtype=values.dtype)
     else:
         ident = jnp.array(jnp.iinfo(values.dtype).min, dtype=values.dtype)
+    if _ONE_SEGMENT.get():
+        return _dense(jnp.max, values, valid, ident, cap)
     return jax.ops.segment_max(jnp.where(valid, values, ident), gid,
                                num_segments=cap,
                                indices_are_sorted=_SORTED_GIDS.get())

@@ -311,7 +311,7 @@ def _plan_key(node: PhysicalPlan) -> tuple:
         own = node.condition.key()
     elif isinstance(node, ops.TpuHashAggregateExec):
         own = (node.mode, aliases_key(node.grouping),
-               aliases_key(node.aggs))
+               aliases_key(node.aggs)) + node.lowering_key()
     elif isinstance(node, ops.TpuSortExec):
         own = orders_key(node.orders)
     elif isinstance(node, ops.TpuRangeShuffleExchangeExec):

@@ -149,6 +149,30 @@ def test_matmul_bounded_int_sum(one_chip, as_tpu):
     assert segmented.mm_traced_sweeps > sweeps, "matmul path not taken"
 
 
+@pytest.mark.parametrize("np_dtype", [jnp.float64, jnp.int64],
+                         ids=["f64", "i64"])
+def test_keyless_dense_reductions(one_chip, as_tpu, np_dtype):
+    """A keyless aggregate's reductions (Q6's sum and its null-tracking
+    count, a min and a max) as whole-array reduces in the buffer's own
+    type: the TPU compiler takes them at a partition's width and the
+    compiled program holds no scatter."""
+    from spark_rapids_tpu.ops import segmented
+
+    def kernel(values, valid, gid):
+        with segmented.one_segment():
+            return (segmented.seg_sum_count(values, valid, gid, ROWS),
+                    segmented.seg_min(values, valid, gid, ROWS),
+                    segmented.seg_max(values, valid, gid, ROWS))
+
+    dense = segmented.dense_traced_reductions
+    c = _compile(kernel, _sds((ROWS,), np_dtype, one_chip),
+                 _sds((ROWS,), jnp.bool_, one_chip),
+                 _sds((ROWS,), jnp.int32, one_chip))
+    assert segmented.dense_traced_reductions == dense + 4
+    assert "scatter" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+
 # ------------------------------------------------------- lookup join
 
 def _sorted_build(one_chip):

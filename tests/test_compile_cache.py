@@ -14,6 +14,7 @@ import subprocess
 import sys
 import textwrap
 import threading
+import time
 
 import numpy as np
 import pyarrow as pa
@@ -26,6 +27,18 @@ from spark_rapids_tpu.runtime import compile_cache as cc
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def _abandoned_attempts_done(timeout: float = 30.0) -> None:
+    """The compile metrics are deltas of a process-wide ledger, and an
+    earlier module's abandoned task attempts (the stage scheduler
+    closes its pool with shutdown(wait=False), so a straggler that
+    lost to its speculative twin runs on) still build programs on
+    their threads: let them finish before counting."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline and any(
+            t.name.startswith("sched-") for t in threading.enumerate()):
+        time.sleep(0.05)
+
+
 @pytest.fixture()
 def cache_session(tmp_path):
     """Session bound to an isolated cache dir; deconfigures after.
@@ -33,6 +46,7 @@ def cache_session(tmp_path):
     identical programs don't turn this test's builds into hits."""
     from spark_rapids_tpu.runtime import jit_cache
 
+    _abandoned_attempts_done()
     jit_cache.clear()
     cc.reset_for_tests()
     s = TpuSparkSession({
@@ -552,6 +566,139 @@ def test_a_warm_artifact_carries_its_programs_name(tmp_path):
         for d, e in served.items():
             if d != old:
                 assert f"jit_{e['name']}" in warm[e["key"]].as_text()
+    finally:
+        s.stop()
+        cc.reset_for_tests()
+        jit_cache.clear()
+
+
+# ------------------------- a keyless aggregate's programs are new keys
+
+# what the parent of the dense keyless lowering called these programs
+# (PERF_LEDGER.jsonl, PR 24): a cache that has seen that commit holds
+# the scatter lowering under these names' keys
+Q6_SCATTER = {"fused_chain_b633ac94", "fused_agg_dbebc581"}
+Q1_NAMES = {"fused_chain_831fc292", "fused_agg_285fe60a",
+            "fused_sort_dd0047ac", "fused_collect1_029f71ff"}
+
+
+@pytest.fixture(scope="module")
+def lineitem_100k(tmp_path_factory):
+    from benchmark import run
+
+    conf = run.load_json(run.HERE, "configs",
+                         "tpch_sf10_lineitem_half.json")
+    gen = run.load_module("datagen", conf["generator"])
+    dirs = gen.generate(conf, 2_147_483_693,
+                        str(tmp_path_factory.mktemp("lineitem")),
+                        rows=100_000)
+    # one task a file, as at the cell's real size: partial aggregates
+    # in the chain, a final merge behind them
+    return dirs, dict(conf["session_conf"], **{
+        "spark.rapids.sql.reader.coalesceSizeBytes": 1})
+
+
+def _dispatched(query, spark, dirs, cached=False):
+    from benchmark import run
+    from spark_rapids_tpu.obs import spans
+
+    tables = {t: spark.read.parquet(d) for t, d in dirs.items()}
+    if cached:
+        tables = {t: df.cache(storage="device")
+                  for t, df in tables.items()}
+    out = run.load_module("queries", query).build(
+        spark, tables).collect_arrow()
+    assert run.not_fused(spark.last_execution) == ""
+    return out, {sp.extra["program"]
+                 for sp in spans.ring.last(1)[0].walk()
+                 if sp.name == "fused.dispatch"}
+
+
+def _without_dense(key):
+    """The structural key the parent gave the same program: the
+    aggregate's entry without its lowering."""
+    if isinstance(key, tuple):
+        return tuple(_without_dense(k) for k in key if k != "dense")
+    return key
+
+
+def test_keyless_programs_get_new_names_and_keyed_keep_theirs(
+        lineitem_100k):
+    dirs, conf = lineitem_100k
+    s = TpuSparkSession(dict(conf, **{
+        "spark.rapids.tpu.compileCache.enabled": False}))
+    try:
+        _, q6 = _dispatched("tpch_q6", s, dirs)
+        _, q1 = _dispatched("tpch_q1", s, dirs, cached=True)
+    finally:
+        s.stop()
+    assert {n.rsplit("_", 1)[0] for n in q6} == {
+        "fused_chain", "fused_agg", "fused_collect1"}
+    assert not q6 & Q6_SCATTER
+    assert q1 == Q1_NAMES
+
+
+def test_an_entry_under_the_parents_key_is_not_served(
+        lineitem_100k, tmp_path):
+    """A cache directory that has seen the parent holds Q6's scatter
+    programs under the parent's keys. Re-file this run's artifacts
+    under exactly those keys (their names come out as the ledger's),
+    warm them up, and run Q6 again: none is taken."""
+    import ast
+    import shutil
+
+    from spark_rapids_tpu.exec.fused import program_name
+    from spark_rapids_tpu.runtime import jit_cache
+
+    dirs, conf = lineitem_100k
+    conf = dict(conf, **{
+        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "c"),
+        "spark.rapids.tpu.compileCache.warmup.enabled": False,
+        "spark.rapids.tpu.compileCache.artifact.minCompileSecs": 0.0})
+    jit_cache.clear()
+    cc.reset_for_tests()
+    s = TpuSparkSession(conf)
+    try:
+        first, _ = _dispatched("tpch_q6", s, dirs)
+        cc.flush()
+        adir = os.path.join(cc.cache_dir(), "artifacts")
+        refiled, old_keys = set(), set()
+        for digest, e in cc.read_index().items():
+            key = ast.literal_eval(e["key"])
+            if e["tag"] != "fused" or "dense" not in e["key"]:
+                continue
+            assert e["artifact"], e
+            old = _without_dense(key)
+            old_repr, old_digest = repr(old), cc.key_digest(old)
+            name = program_name(old[1], old[2])
+            refiled.add(name)
+            old_keys.add(old_repr)
+            shutil.move(os.path.join(adir, digest + ".bin"),
+                        os.path.join(adir, old_digest + ".bin"))
+            os.remove(os.path.join(adir, digest + ".key"))
+            with open(os.path.join(adir, old_digest + ".key"), "w") as f:
+                f.write(old_repr)
+            os.remove(cc._index_path(digest))
+            with open(cc._index_path(old_digest), "w") as f:
+                json.dump(dict(e, key=old_repr, name=name), f)
+        assert refiled == Q6_SCATTER
+    finally:
+        s.stop()
+        cc.reset_for_tests()
+        jit_cache.clear()
+    s = TpuSparkSession(conf)
+    try:
+        cc._warmup_run(top_k=64)
+        with cc._warm_lock:
+            assert old_keys <= set(cc._warm)
+        others = cc.warm_count() - len(old_keys)  # collect1: same key
+        again, names = _dispatched("tpch_q6", s, dirs)
+        assert not names & Q6_SCATTER
+        assert s.last_execution["compile"]["warmHits"] == others
+        assert s.last_execution["compile"]["programsCompiled"] >= 2
+        with cc._warm_lock:  # offered, and left alone
+            assert set(cc._warm) == old_keys
+        assert again.to_pylist() == first.to_pylist()
     finally:
         s.stop()
         cc.reset_for_tests()
