@@ -1,8 +1,9 @@
 """The harness end to end off the chip: the command refuses any
 platform but a TPU; through the rehearsal's hooks each cell runs to a
 result line of the contract's shape; with the timed path broken
-underneath, `correct` comes out false; the float32 control comes out
-not correct."""
+underneath, `correct` comes out false; each cell's control, the
+reference in the precision below the one its configuration states,
+comes out not correct, through the harness and through control.py."""
 
 import json
 import os
@@ -96,6 +97,19 @@ def test_traced_run_reports_the_per_layer_metrics(tmp_path, monkeypatch):
     assert res["correct"] is True
 
 
+def test_the_roofline_counts_the_cells_own_rows():
+    """`fused.hbm_roofline` takes Q6's bytes from the CELL's own
+    configuration, 28 B a row (three doubles and a date32): over the
+    half table 0.84 GB a query, 1.03 ms at the peak of peaks.json."""
+    loaded = run.load_cell("tpch_q6_scan_uncached")
+    peaks = run.load_json(run.HERE, "peaks.json")["device_kind"]["TPU v5 lite"]
+    ctx = {"cell": loaded, "config": loaded["config"], "peaks": peaks,
+           "window": {"names": ["tpch_q6"] * 100},
+           "trace": {"busy_s": 100 * 0.0254}}
+    share = run.load_module("layer_metrics", "fused.hbm_roofline").read(ctx)
+    assert share == pytest.approx(100 * 28 * 29_999_795 / 819e9 / 0.0254)
+
+
 # --- faults planted under the harness: `correct` has to read false ---
 
 def collect_with(monkeypatch, alter):
@@ -159,18 +173,54 @@ def test_a_query_off_the_fused_engine_is_failed(monkeypatch):
         "rows_wrong"]["value"] + len(calls) - 2 - res["failed"]
 
 
-@pytest.mark.parametrize("cell, rows", [
-    ("tpch_q1_resident", 1_000_000), ("tpch_q6_scan_uncached", 1_000_000)])
-def test_the_bfloat16_control_is_not_correct(cell, rows):
-    """The reference with values and products rounded to bfloat16, in
-    the program's place: refused by `sum_rel_err`, at a size a test
-    run holds (the chip's readings at the cells' own size are in
-    PERF.md). The float32 reference is no control: it reads below what
-    the engine reads on a v5e, whose f64 arithmetic is f32."""
+CONTROL = {"tpch_q1_resident": "bfloat16", "tpch_q6_scan_uncached": "float32"}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_control_is_not_correct(cell, rows=1_000_000):
+    """The reference in the cell's control precision (`control` in
+    limits/<cell>.json), in the program's place: refused by
+    `sum_rel_err`, at a size a test run holds (the chip's readings at
+    the cells' own size are in PERF.md). Q6's keyless sum runs in
+    emulated f64, so float32 is its control and has to fail, and
+    bfloat16 with it. Q1's binned MXU group-by sums f32 chunk partials,
+    as its configuration states: there the float32 reference reads
+    like the program and passes, and bfloat16 is the control."""
     res = run.run_cell(cell, SEED, 0.2, False, rows=rows,
                        any_platform=True, controls=("bfloat16", "float32"))
     assert res["correct"] is True
     limit = res["compared"]["sum_rel_err"]["limit"]
+    control = run.load_cell(cell)["limits"]["control"]
+    assert control == CONTROL[cell]
+    assert res["controls"][control]["sum_rel_err"] > 3 * limit
     assert res["controls"]["bfloat16"]["sum_rel_err"] > 3 * limit
-    assert res["controls"]["float32"]["sum_rel_err"] < limit
+    passes = res["controls"]["float32"]["sum_rel_err"] <= limit
+    assert passes == (control == "bfloat16")
     assert res["controls"]["float32"]["rows_wrong"] == 0
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_py_reads_the_cells_control_and_sees_it_refused(
+        cell, monkeypatch, capsys):
+    """benchmark/control.py, the chip's reader of the two readings, at a
+    size a test run holds: one line a seed, then the summary, which
+    names the control that limits/<cell>.json names and says that
+    every seed's control was refused while the program was correct."""
+    from benchmark import control
+
+    def small(name, seed, seconds, trace, **kw):
+        return run.run_cell(name, seed, seconds, trace, rows=200_000,
+                            any_platform=True, **kw)
+
+    monkeypatch.setattr(control, "run_cell", small)
+    assert control.main(["--workload", cell, "--seeds", "2",
+                         "--seconds", "0.2"]) == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    seeds, summary = lines[:-1], lines[-1]
+    assert len(seeds) == 2 and seeds[0]["seed"] != seeds[1]["seed"]
+    assert all(row["correct"] for row in seeds)
+    assert summary["control"] == CONTROL[cell]
+    assert summary["control_refused"] == [True, True]
+    assert summary["program_sum_rel_err_max"] <= summary["limit"]
+    assert summary["control_sum_rel_err_min"] > 3 * summary["limit"]
