@@ -1,0 +1,15 @@
+"""Share of the HBM roofline of a query with a join: the least time
+the chip could take to read each column the query reads, once
+(`device_bytes` of the query's file over the peak of peaks.json), over
+the device's busy time. The bound is bytes: a lookup join does a few
+comparisons per key. Read as `fused.hbm_roofline` is."""
+
+
+def read(ctx):
+    trace, peaks = ctx["trace"], ctx["peaks"]
+    if not trace or not trace["busy_s"] or not peaks:
+        return None
+    queries = ctx["cell"]["queries"]
+    need = sum(queries[q].device_bytes(ctx["config"])
+               for q in ctx["window"]["names"])
+    return 100.0 * need / peaks["hbm_bytes_per_s"] / trace["busy_s"]

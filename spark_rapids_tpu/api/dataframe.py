@@ -669,7 +669,7 @@ class DataFrame:
         from spark_rapids_tpu.runtime import admission
 
         rec = {"engine": None, "fallbacks": [], "compile": None,
-               "degradations": [], "scheduler": None}
+               "degradations": [], "scheduler": None, "join": None}
         self._last_exec = rec
         self.session.last_execution = rec
         # admission front door (runtime/admission.py): the OUTERMOST
@@ -949,12 +949,14 @@ class DataFrame:
                         f"{breaker.threshold} consecutive fused "
                         f"failures for this program key")
             else:
-                ex = FusedSingleChipExecutor(conf)
+                ex = FusedSingleChipExecutor(
+                    conf, wide_joins=self.session.fused_wide_joins)
                 try:
                     out = ex.execute(phys)
                     if ex.last_compile_metrics is not None:
                         rec["_fused_variants"] = \
                             ex.last_compile_metrics["variantCount"]
+                    rec["join"] = ex.last_join_metrics
                     breaker.record_success(fkey)
                     return ran("fused", out)
                 except FusedCompileError as e:

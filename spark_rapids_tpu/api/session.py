@@ -213,6 +213,10 @@ class TpuSparkSession:
         # why faster engines fell back — see DataFrame.collect_arrow)
         self.query_metrics = MetricsRegistry()
         self.last_execution = None
+        #: plan keys (`_plan_key`) of the lookup joins that lost their
+        #: survivor bet in this session: the fused engine lowers those
+        #: at full width from then on (exec/fused.py SurvivorOverflow)
+        self.fused_wide_joins = set()
         self._init_runtime()
         # the session OWNS the observability wiring (obs/): event bus,
         # span builder, event history, and the conf-gated event-log

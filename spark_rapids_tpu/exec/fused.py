@@ -97,14 +97,34 @@ class PushdownOverflow(Exception):
     original plan's own capacities are unaffected)."""
 
 
+class SurvivorOverflow(Exception):
+    """A lookup join's survivor bet failed: more rows passed the
+    pending filter than the static capacity the join brought them to
+    (`survivor_capacity`). Internal to the fused retry loop: `joins`
+    holds the plan keys of the joins that lost, the re-run lowers those
+    at full width, and the executor's `wide_joins` remembers them."""
+
+    def __init__(self, joins):
+        super().__init__("filter survivors exceed the lookup join's "
+                         "capacity; re-running it at full width")
+        self.joins = joins
+
+
 def _check_host_flags(host: np.ndarray, n_ovf: int,
-                      n_uniq: int = 0, n_push: int = 0) -> None:
-    """host = [capacity | uniqueness | pushdown | ansi 3-vectors].
-    Capacity overflow wins (a retried run re-checks everything on the
-    full data), then the lookup-uniqueness and pushdown re-lowering
-    retries, then ANSI raises per error class."""
+                      n_uniq: int = 0, n_push: int = 0,
+                      survivor_joins: tuple = ()) -> None:
+    """host = [capacity | uniqueness | pushdown | survivors | ansi
+    3-vectors]. A lost survivor bet wins: its run dropped rows, so
+    every other flag is re-checked by the re-run on the full data.
+    Then capacity overflow, then the lookup-uniqueness and pushdown
+    re-lowering retries, then ANSI raises per error class."""
     from spark_rapids_tpu.expr.ansicheck import raise_host
 
+    flagged = n_ovf + n_uniq + n_push
+    lost = host[flagged:flagged + len(survivor_joins)]
+    if bool(np.any(lost)):
+        raise SurvivorOverflow(
+            {k for k, f in zip(survivor_joins, lost) if f})
     if bool(np.any(host[:n_ovf])):
         raise TpuSplitAndRetryOOM(
             "fused program capacity overflow; recompiling larger")
@@ -115,7 +135,7 @@ def _check_host_flags(host: np.ndarray, n_ovf: int,
         raise PushdownOverflow(
             "probe join-key cardinality exceeds group capacity; "
             "re-running without agg pushdown")
-    rest = host[n_ovf + n_uniq + n_push:]
+    rest = host[flagged + len(survivor_joins):]
     if rest.size:
         a = rest.reshape(-1, 3).any(axis=0)
         raise_host(bool(a[0]), bool(a[1]), bool(a[2]))
@@ -259,6 +279,24 @@ def shrink_traced(batch: ColumnBatch, cap2: int):
     return ColumnBatch(batch.schema, cols, jnp.minimum(nr, cap2)), ovf
 
 
+#: A lookup join under a pending filter searches the filter's
+#: survivors, brought to the front of this share of the batch: a bet
+#: like `group_cap`, whose loss re-runs that join at full width. 1/64
+#: holds a filter that keeps 1% with room; 1/8 would leave the search
+#: at an eighth of the full width's cost, most of a query.
+_SURVIVOR_SHARE = 64
+_SURVIVOR_ALIGN = 1024
+
+
+def survivor_capacity(n: int) -> Optional[int]:
+    """The static capacity a batch of capacity `n` brings its filter's
+    survivors to before a lookup join, or None where the batch is too
+    small for the bet to pay."""
+    cap = -(-max(n // _SURVIVOR_SHARE, 1) // _SURVIVOR_ALIGN) \
+        * _SURVIVOR_ALIGN
+    return cap if cap * 4 <= n else None
+
+
 @functools.lru_cache(maxsize=4096)
 def program_name(key_tag: str, nodes_key) -> str:
     """`fused_<kind>_<8 hex digits>`: what a fused program is called in
@@ -284,10 +322,15 @@ class FusedSingleChipExecutor:
     the default (single) device."""
 
     def __init__(self, conf=None, expansion: Optional[int] = None,
-                 group_cap: Optional[int] = None):
+                 group_cap: Optional[int] = None,
+                 wide_joins: Optional[set] = None):
         from spark_rapids_tpu.config import rapids_conf as rc
 
         self.conf = conf
+        #: plan keys of the lookup joins that lost their survivor bet
+        #: (SurvivorOverflow): never placed again by whoever owns the
+        #: set, which is the session (api/dataframe.py)
+        self._wide_joins = wide_joins if wide_joins is not None else set()
 
         def c(entry):
             return conf.get(entry) if conf is not None else entry.default
@@ -305,6 +348,10 @@ class FusedSingleChipExecutor:
         #: cacheHits (api/dataframe.py folds it into
         #: session.last_execution["compile"])
         self.last_compile_metrics = None
+        #: what the joins of the most recent execute() did, or None
+        #: where the plan has none: session.last_execution["join"]
+        self.last_join_metrics = None
+        self._run_joins: List[dict] = []
 
     # --- source preparation (once; survives expansion retries) ---
 
@@ -511,26 +558,51 @@ class FusedSingleChipExecutor:
         (result, (expansion, group_cap, use_lookup)) at the settings
         that succeeded. Capacity overflow doubles the factors; a lost
         lookup-uniqueness bet only flips joins to the expanded blocking
-        lowering (same factors — nothing else recompiles bigger)."""
+        lowering (same factors — nothing else recompiles bigger); a
+        lost survivor bet lowers that join at full width, for as long
+        as `wide_joins` lives."""
         expansion, group_cap = self._expansion, self._group_cap
         use_lookup = use_pushdown = True
+        reruns: List[str] = []
         while True:
             try:
-                return (self._run(phys, expansion, group_cap,
-                                  as_parts=as_parts,
-                                  use_lookup=use_lookup,
-                                  use_pushdown=use_pushdown),
-                        (expansion, group_cap, use_lookup,
-                         use_pushdown))
+                out = (self._run(phys, expansion, group_cap,
+                                 as_parts=as_parts,
+                                 use_lookup=use_lookup,
+                                 use_pushdown=use_pushdown),
+                       (expansion, group_cap, use_lookup,
+                        use_pushdown))
+                self._record_joins(reruns)
+                return out
+            except SurvivorOverflow as e:
+                self._wide_joins.update(e.joins)
+                reruns.append("survivorOverflow")
             except LookupUniquenessLost:
                 use_lookup = False
+                reruns.append("uniquenessLost")
             except PushdownOverflow:
                 use_pushdown = False
+                reruns.append("pushdownOverflow")
             except TpuSplitAndRetryOOM:
                 if expansion >= self._max_expansion:
                     raise
                 expansion *= 2
                 group_cap *= 4
+                reruns.append("capacityOverflow")
+
+    def _record_joins(self, reruns: List[str]) -> None:
+        """The settled run's joins -> `last_join_metrics`, one `join`
+        event each, and the open `fused.execute` span's `join` field."""
+        from spark_rapids_tpu.obs import events as obs_events
+
+        if not self._run_joins:
+            self.last_join_metrics = None
+            return
+        self.last_join_metrics = {"runs": len(reruns) + 1,
+                                  "rerunReasons": list(reruns),
+                                  "joins": self._run_joins}
+        for j in self._run_joins:
+            obs_events.emit("join", runs=len(reruns) + 1, **j)
 
     def execute(self, phys: PhysicalPlan, as_parts: bool = False):
         from spark_rapids_tpu.config import rapids_conf as rc
@@ -555,10 +627,14 @@ class FusedSingleChipExecutor:
         # the fused engine runs whole stages as single XLA programs, so
         # there are no operator spans: its tree is prepare, one
         # dispatch per program, fetch
-        with obs_events.span("fused.execute", root=type(phys).__name__):
-            return self._scaffold(
+        with obs_events.span("fused.execute",
+                             root=type(phys).__name__) as sp:
+            out = self._scaffold(
                 phys, as_parts,
                 lambda: self._run_with_retry(phys, as_parts)[0])
+            if self.last_join_metrics is not None:
+                sp.set(join=self.last_join_metrics)
+            return out
 
     def _oom_injection_eager_fallback(self, phys: PhysicalPlan):
         """Run the plan on the per-operator eager engine (whose
@@ -681,6 +757,7 @@ class FusedSingleChipExecutor:
         from spark_rapids_tpu.obs import telemetry
         from spark_rapids_tpu.parallel.plan_compiler import (
             _plan_key,
+            concat_in_place,
             concat_traced,
             shard_equi_join,
         )
@@ -689,7 +766,12 @@ class FusedSingleChipExecutor:
         flags: List[jnp.ndarray] = []       # capacity overflow, scalar
         uniq_flags: List[jnp.ndarray] = []  # lookup uniqueness, scalar
         push_flags: List[jnp.ndarray] = []  # pushdown shrink, scalar
+        surv_flags: List[tuple] = []        # (join key, scalar): lost bet
         ansi_flags: List[jnp.ndarray] = []  # (3,) [arith, div0, cast]
+        # what each join of this run did, by its plan key, summed over
+        # the parts; `buildRows` holds device scalars until the fetch
+        joins: Dict[tuple, dict] = {}
+        self._run_joins = []
         ansi_on = self._ansi
         # ANSI checks see pre-join row visibility; the pushdown's
         # pre-aggregate would evaluate agg inputs on probe rows the
@@ -711,7 +793,8 @@ class FusedSingleChipExecutor:
                  _enc.encoding_key(b))
                 for b in batches)
 
-        def run_program(key_tag, nodes_key, fn, inputs, **uses):
+        def run_program(key_tag, nodes_key, fn, inputs, join_fields=None,
+                        **uses):
             # program dispatch = the fused engine's cooperative yield
             # point (the per-attempt check of the stage scheduler,
             # scaled to this engine's unit of work): a cancelled query
@@ -721,13 +804,15 @@ class FusedSingleChipExecutor:
 
             name = program_name(key_tag, nodes_key)
             with obs_events.span("fused.dispatch", program=name) as sp:
+                if join_fields:
+                    sp.set(joins=join_fields)
                 cancellation.check_current()
                 return dispatch(name, sp, key_tag, nodes_key, fn, inputs,
                                 **uses)
 
         def dispatch(name, sp, key_tag, nodes_key, fn, inputs,
                      uses_expansion=False, uses_group_cap=False,
-                     uses_ansi=False):
+                     uses_ansi=False, survivor_joins=()):
             # chaos site device.dispatch: an injected fault here is the
             # fused engine "dying mid-dispatch"; the dispatch ladder
             # (api/dataframe.py) demotes the query to the eager engine
@@ -781,12 +866,15 @@ class FusedSingleChipExecutor:
             with _dm.guard("fused.dispatch", detail=str(key_tag),
                            inject=True):
                 out, fl, *rest = jitted(*inputs)
-            # fl: scalar=[cap] | (3,)=[cap, uniq, push] (chain programs)
+            # fl: scalar=[cap] | [cap, uniq, push] (chain programs), then
+            # one lost-bet flag for each of `survivor_joins`
             fl = jnp.asarray(fl).reshape(-1)
             flags.append(fl[0])
             if fl.shape[0] > 1:
                 uniq_flags.append(fl[1])
                 push_flags.append(fl[2])
+            surv_flags.extend(
+                (k, fl[3 + i]) for i, k in enumerate(survivor_joins))
             if rest:
                 ansi_flags.append(rest[0])
             return out
@@ -803,7 +891,8 @@ class FusedSingleChipExecutor:
                 return None
             return ansicheck.flags_vec(list(exprs), b, live)
 
-        def chain_traced(nodes, batch, builds=(), ansi_live=False):
+        def chain_traced(nodes, batch, builds=(), ansi_live=False,
+                         join_plan=()):
             """Apply a bottom-up list of per-partition operators inside
             one trace; returns (batch, overflow). `builds` holds the
             already-materialized build batch for each lookup join in
@@ -827,6 +916,8 @@ class FusedSingleChipExecutor:
             b = widen_traced(batch)
             mask = None  # pending filter predicate over b's rows
             builds = list(builds)
+            join_plan = list(join_plan)
+            lost = []  # one flag per "lookupSurvivors" join
 
             def materialized(b, mask):
                 return b if mask is None else filterops.compact(b, mask)
@@ -843,24 +934,23 @@ class FusedSingleChipExecutor:
                 prepared BuildTable — sorted ONCE per join by the
                 buildprep program, not once per probe partition."""
                 work_l, lk = nd._prepare_keys(b, nd.left_keys)
-                lo, counts = joinops.probe_ranges(bt, work_l, lk)
+                lo, matched, dup = joinops.probe_unique(bt, work_l, lk)
                 jt = nd.join_type
 
                 def and_mask(m):
                     return m if mask is None else mask & m
 
                 if jt == "left_semi":
-                    return b, and_mask(counts > 0), uniq
+                    return b, and_mask(matched), uniq
                 if jt == "left_anti":
-                    return b, and_mask(counts == 0), uniq
+                    return b, and_mask(~matched), uniq
                 if jt == "existence":
-                    return nd._exists_batch(b, counts > 0), mask, uniq
+                    return nd._exists_batch(b, matched), mask, uniq
                 # inner / left: unique-build single-match gather; a
                 # visible probe row with >1 matches trips the
                 # uniqueness flag and the re-run lowers this join via
                 # the expanded blocking path (same capacity factors)
-                uniq = uniq | jnp.any((counts > 1) & visible(b, mask))
-                matched = counts > 0
+                uniq = uniq | jnp.any(dup & visible(b, mask))
                 safe = jnp.clip(lo, 0, bt.batch.capacity - 1)
                 rcols = [c.gather(safe) for c in bt.batch.columns]
                 rcols = [c.replace(validity=c.validity & matched)
@@ -873,8 +963,25 @@ class FusedSingleChipExecutor:
                     mask = and_mask(matched)
                 return b, mask, uniq
 
+            def survivors(b, mask, cap):
+                """The rows the pending mask lets through, at the front
+                of a batch of capacity `cap`; -> (batch, lost bet)."""
+                ids, total = joinops.front_row_ids(visible(b, mask), cap)
+                return (b.gather(ids, jnp.minimum(total, cap)),
+                        total > cap)
+
             for nd in nodes:
                 if isinstance(nd, J.TpuBroadcastHashJoinExec):
+                    jp = join_plan.pop(0)
+                    # the host's walk (chain_joins) and this trace are
+                    # one decision: a capacity it did not foresee is a
+                    # defect, not a run-time condition
+                    assert jp["probeSlots"] in (None, b.capacity), \
+                        (jp, b.capacity)
+                    if jp["lowering"] == "lookupSurvivors":
+                        b, over = survivors(b, mask, jp["searchedSlots"])
+                        mask = None
+                        lost.append(over)
                     b, mask, uniq = lookup_join(nd, b, mask,
                                                 builds.pop(0), uniq)
                 elif isinstance(nd, ops.TpuFilterExec):
@@ -928,7 +1035,7 @@ class FusedSingleChipExecutor:
                     else:
                         ovf = ovf | o
             out = materialized(b, mask)
-            fl = jnp.stack([ovf, uniq, push])
+            fl = jnp.stack([ovf, uniq, push] + lost)
             if ansi_live:
                 return out, fl, ansi
             return out, fl
@@ -1002,30 +1109,103 @@ class FusedSingleChipExecutor:
                     return True
             return False
 
+        def chain_joins(nodes, keys, capacity):
+            """The host's walk of a chain that `chain_traced` follows:
+            for each lookup join, bottom-up, what it is lowered to and
+            the capacities around it, from the capacity of the chain's
+            input. A join under a pending FILTER (a mask that a match
+            alone left is no bet) searches the filter's survivors at
+            `survivor_capacity`, unless the batch is too small or the
+            join lost that bet before (`wide_joins`); the batch goes
+            on at that capacity. After an aggregate the capacity is the
+            aggregate's own (None here)."""
+            cap, filtered, out = capacity, False, []
+            for nd, key in zip(nodes, keys):
+                if isinstance(nd, J.TpuBroadcastHashJoinExec):
+                    to = (survivor_capacity(cap)
+                          if filtered and cap is not None
+                          and key not in self._wide_joins else None)
+                    out.append({
+                        "lowering": "lookupSurvivors" if to else "lookup",
+                        "joinType": nd.join_type, "probeSlots": cap,
+                        "searchedSlots": to or cap,
+                        "outputCapacity": to or cap})
+                    if to:
+                        cap, filtered = to, False
+                elif isinstance(nd, ops.TpuFilterExec):
+                    filtered = True
+                elif isinstance(nd, ops.TpuExpandExec):
+                    filtered = False
+                    cap = cap and cap * len(nd.projections)
+                elif isinstance(nd, ops.TpuGenerateExec):
+                    filtered = False
+                    cap = cap and next_capacity(expansion * cap)
+                elif not isinstance(nd, (ops.TpuProjectExec,
+                                         ops.TpuCoalesceBatchesExec)):
+                    cap, filtered = None, False  # an aggregate's own
+            return out
+
+        def note_join(key, rec, rows):
+            """Add one program's share of a join to the run's record;
+            `rows`: the device scalars that sum to its build rows."""
+            if key not in joins:
+                joins[key] = dict(rec, buildRows=rows)
+                return
+            was = joins[key]
+            if rec["lowering"] not in was["lowering"].split("+"):
+                was["lowering"] += "+" + rec["lowering"]
+            for k in ("probeSlots", "searchedSlots", "outputCapacity"):
+                was[k] = (None if None in (was[k], rec[k])
+                          else was[k] + rec[k])
+
         def run_chain(nodes, base):
+            keys = [n.chain_key()
+                    if isinstance(n, agg_pushdown.MergeTail)
+                    else _plan_key(n) for n in nodes]
             nodes_key = tuple(
-                n.chain_key()
-                if isinstance(n, agg_pushdown.MergeTail)
-                else _plan_key(n)[:2] for n in nodes)
+                k if isinstance(n, agg_pushdown.MergeTail) else k[:2]
+                for n, k in zip(nodes, keys))
             # lookup-join build sides materialize + sort ONCE, outside
             # the per-partition programs, and ride in as extra inputs
+            join_keys = [k for n, k in zip(nodes, keys)
+                         if isinstance(n, J.TpuBroadcastHashJoinExec)]
             builds = [build_table(n) for n in nodes
                       if isinstance(n, J.TpuBroadcastHashJoinExec)]
             ansi_live = chain_has_ansi(nodes)
+            uses = dict(
+                uses_expansion=any(isinstance(n, ops.TpuGenerateExec)
+                                   for n in nodes),
+                uses_group_cap=any(
+                    isinstance(n, ops.TpuHashAggregateExec)
+                    for n in nodes),
+                uses_ansi=ansi_live)
 
-            def stage_fn(b, *bs, _nodes=nodes, _al=ansi_live):
-                return chain_traced(_nodes, b, bs, ansi_live=_al)
+            def one(b):
+                plan = chain_joins(nodes, keys, b.capacity) \
+                    if builds else []
+                for key, bt, jp in zip(join_keys, builds, plan):
+                    jp["buildSlots"] = bt.batch.capacity
+                    note_join(key, jp, [bt.batch.num_rows])
+                bets = tuple(k for k, jp in zip(join_keys, plan)
+                             if jp["lowering"] == "lookupSurvivors")
 
-            return [run_program(
-                        "chain", nodes_key, stage_fn, [b] + builds,
-                        uses_expansion=any(
-                            isinstance(n, ops.TpuGenerateExec)
-                            for n in nodes),
-                        uses_group_cap=any(
-                            isinstance(n, ops.TpuHashAggregateExec)
-                            for n in nodes),
-                        uses_ansi=ansi_live)
-                    for b in base]
+                def stage_fn(b, *bs, _nodes=nodes, _al=ansi_live,
+                             _plan=plan):
+                    return chain_traced(_nodes, b, bs, ansi_live=_al,
+                                        join_plan=_plan)
+
+                # the lowering is structural: which joins search their
+                # survivors is part of the program's key (a chain with
+                # none keeps the key, and the name, it had)
+                marked = nodes_key + ((
+                    "survivors",
+                    tuple(jp["lowering"] for jp in plan)),) \
+                    if bets else nodes_key
+                return run_program("chain", marked, stage_fn,
+                                   [b] + builds, join_fields=plan,
+                                   survivor_joins=bets, **uses)
+
+            return [one(b) for b in base]
 
         def build_table(jn: PhysicalPlan):
             """Prepared (sorted) BuildTable for one lookup join — ONE
@@ -1034,8 +1214,11 @@ class FusedSingleChipExecutor:
             parts = emit_parts(jn.children[1])
 
             def bp_fn(*ps):
-                cb = concat_traced(concat_inputs(list(ps)))
-                return jn._build_table(cb), jnp.zeros((), bool)
+                # the parts end to end, uncompacted: the build side's
+                # sort sends every dead row last anyway
+                cb, live = concat_in_place(concat_inputs(list(ps)))
+                return (jn._build_table(cb, live=live),
+                        jnp.zeros((), bool))
 
             return run_program("buildprep", _plan_key(jn)[:2], bp_fn,
                                parts)
@@ -1136,15 +1319,25 @@ class FusedSingleChipExecutor:
                 rparts = emit_parts(node.children[1])
                 nl = len(lparts)
 
+                probe_slots = sum(p.capacity for p in lparts)
+                build_slots = sum(p.capacity for p in rparts)
+                out_cap = next_capacity(
+                    expansion * max(probe_slots, build_slots))
+                rec = {"lowering": "expand", "joinType": node.join_type,
+                       "probeSlots": probe_slots,
+                       "searchedSlots": probe_slots,
+                       "outputCapacity": out_cap,
+                       "buildSlots": build_slots}
+                key = _plan_key(node)
+                note_join(key, rec, [p.num_rows for p in rparts])
+
                 def join_fn(*ps):
                     lb = concat_traced(concat_inputs(list(ps[:nl])))
                     rb = concat_traced(concat_inputs(list(ps[nl:])))
-                    out_cap = next_capacity(
-                        expansion * max(lb.capacity, rb.capacity))
                     return shard_equi_join(node, lb, rb, out_cap)
 
-                return run_program("join", _plan_key(node)[:2], join_fn,
-                                   lparts + rparts,
+                return run_program("join", key[:2], join_fn,
+                                   lparts + rparts, join_fields=[rec],
                                    uses_expansion=True)
             raise FusedCompileError(type(node).__name__)
 
@@ -1153,30 +1346,41 @@ class FusedSingleChipExecutor:
                    or [jnp.zeros((1,), bool)])
             uq = [f.reshape((1,)) for f in uniq_flags]
             pf = [f.reshape((1,)) for f in push_flags]
-            return (jnp.concatenate(ovf + uq + pf + ansi_flags),
-                    len(ovf), len(uq), len(pf))
+            sf = [f.reshape((1,)) for _, f in surv_flags]
+            return (jnp.concatenate(ovf + uq + pf + sf + ansi_flags),
+                    (len(ovf), len(uq), len(pf),
+                     tuple(k for k, _ in surv_flags)))
 
         def assembled_flags(sp):
             """all_flags_arr() inside the `fetch` span `sp`: a dozen
             tiny device operations enqueued from the host, timed apart
-            (`flagsNs`) from the wait that follows."""
+            (`flagsNs`) from the wait that follows. The build sides'
+            row counts ride the same fetch."""
             t0 = time.monotonic_ns()
-            out = all_flags_arr()
+            arr, ns = all_flags_arr()
             sp.set(flagsNs=time.monotonic_ns() - t0)
-            return out
+            return (arr, [j["buildRows"] for j in joins.values()]), ns
+
+        def settle(host, ns):
+            """The fetched (flags, build rows): raise what the flags
+            say, else complete the run's join record."""
+            host_flags, host_rows = host
+            _check_host_flags(np.asarray(host_flags), *ns)
+            for rec, rows in zip(joins.values(), host_rows):
+                rec["buildRows"] = sum(int(r) for r in rows)
+            self._run_joins = list(joins.values())
 
         parts = emit_parts(phys)
         if as_parts:
             if defer_flags:
                 # benchmark path: caller syncs flags itself
-                arr, n_ovf, n_uniq, n_push = all_flags_arr()
-                return parts, arr, (n_ovf, n_uniq, n_push)
+                arr, ns = all_flags_arr()
+                return parts, arr, ns
             # one host sync for overflow + ANSI; parts stay on device
             with obs_events.span("fetch", rows=0) as sp:
-                arr, n_ovf, n_uniq, n_push = assembled_flags(sp)
-                _check_host_flags(telemetry.ledgered_get(
-                    arr, "fused.flags"), n_ovf, n_uniq, n_push)
-                sp.set(bytes=arr.nbytes)
+                extra, ns = assembled_flags(sp)
+                settle(telemetry.ledgered_get(extra, "fused.flags"), ns)
+                sp.set(bytes=extra[0].nbytes)
             return parts
         if len(parts) > 1:
             def collect_fn(*ps):
@@ -1193,7 +1397,7 @@ class FusedSingleChipExecutor:
         # `fetch` blocks on the device: its length is the device time
         # the dispatches left outstanding plus the transfer's own
         with obs_events.span("fetch") as sp:
-            flags_arr, n_ovf, n_uniq, n_push = assembled_flags(sp)
+            extra, ns = assembled_flags(sp)
             nbytes = result.device_size_bytes()
             if nbytes <= self._fetch_fused_bytes:
                 # small result: ONE round trip for rows+flags+data (the
@@ -1202,14 +1406,11 @@ class FusedSingleChipExecutor:
                     device_to_arrow_fused,
                 )
 
-                table, host_flags = device_to_arrow_fused(result,
-                                                          flags_arr)
-                _check_host_flags(np.asarray(host_flags), n_ovf, n_uniq,
-                                  n_push)
+                table, host = device_to_arrow_fused(result, extra)
+                settle(host, ns)
             else:
                 # one host sync for all flags before fetching results
-                _check_host_flags(telemetry.ledgered_get(
-                    flags_arr, "fused.flags"), n_ovf, n_uniq, n_push)
+                settle(telemetry.ledgered_get(extra, "fused.flags"), ns)
                 table = device_to_arrow(result)  # cut to its rows first
                 nbytes = table.nbytes
             sp.set(bytes=nbytes, rows=table.num_rows)

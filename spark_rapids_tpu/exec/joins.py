@@ -381,13 +381,13 @@ class _DeviceJoinBase(PhysicalPlan):
                            [c.truncate(cap2) for c in reduced.columns],
                            n)
 
-    def _build_table(self, right: ColumnBatch,
-                     keys=None) -> joinops.BuildTable:
+    def _build_table(self, right: ColumnBatch, keys=None,
+                     live=None) -> joinops.BuildTable:
         rsch = self.children[1].schema
         work_r, rk = self._prepare_keys(right,
                                         keys if keys is not None
                                         else self.right_keys)
-        bt = joinops.build_side(work_r, rk)
+        bt = joinops.build_side(work_r, rk, live)
         if len(bt.batch.columns) != len(right.columns):
             # strip temp key columns from the (sorted) build batch
             bt = joinops.BuildTable(

@@ -142,6 +142,7 @@ class Literal(Expression):
         super().__init__()
         if dtype is None:
             dtype = _infer_literal_type(value)
+            value = _temporal_value(value)
         self.value = value
         self._dtype = dtype
 
@@ -208,7 +209,13 @@ def _infer_literal_type(v: Any) -> DataType:
         return double
     if isinstance(v, str):
         return string
+    import datetime
     import decimal
+
+    if isinstance(v, datetime.datetime):
+        return TimestampType()
+    if isinstance(v, datetime.date):
+        return DateType()
 
     if isinstance(v, decimal.Decimal):
         sign, digits, exp = v.as_tuple()
@@ -225,6 +232,24 @@ def _infer_literal_type(v: Any) -> DataType:
             et = LongType()  # match the common array<bigint> columns
         return ArrayType(et)
     raise TypeError(f"cannot infer literal type for {v!r}")
+
+
+def _temporal_value(v: Any) -> Any:
+    """A date or datetime as its physical encoding, which is what a
+    typed `Literal(days, DateType())` holds: days since the epoch, or
+    microseconds since the epoch in UTC (a naive datetime is UTC)."""
+    import datetime
+
+    if isinstance(v, datetime.datetime):
+        if v.tzinfo is None:
+            v = v.replace(tzinfo=datetime.timezone.utc)
+        delta = v - datetime.datetime(1970, 1, 1,
+                                      tzinfo=datetime.timezone.utc)
+        return (delta.days * 86_400 + delta.seconds) * 1_000_000 \
+            + delta.microseconds
+    if isinstance(v, datetime.date):
+        return (v - datetime.date(1970, 1, 1)).days
+    return v
 
 
 class Alias(Expression):

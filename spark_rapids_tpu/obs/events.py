@@ -57,6 +57,8 @@ EVENT_TYPES: Dict[str, str] = {
     "compile": "kind (miss|hit|warm|quarantine|warmRebuild|"
                "exportFailed), seconds, error",
     "degrade": "kind, from, to, reason",
+    "join": "lowering, joinType, buildRows, buildSlots, probeSlots, "
+            "searchedSlots, outputCapacity, runs",
     "chaos": "site",
     "admission.queued": "queryId, depth, running",
     "admission.admitted": "queryId, waitMs",

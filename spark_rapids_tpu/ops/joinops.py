@@ -22,7 +22,7 @@ data-dependent output sizes (SURVEY.md section 7 hard part #1/#2).
 
 from __future__ import annotations
 
-from typing import List, NamedTuple, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
@@ -56,14 +56,51 @@ def _join_keys(batch: ColumnBatch, key_idxs: Sequence[int],
     return vals, all_valid
 
 
-def build_side(batch: ColumnBatch, key_idxs: Sequence[int]) -> BuildTable:
+_ABOVE_32 = 2 ** 31 - 1  # a plain int: nothing here may touch the backend
+
+
+def _fits_32_bits(batch: ColumnBatch, key_idxs: Sequence[int]) -> bool:
+    """One plain integer key column whose stamped value range (the
+    narrowed upload's, exec/fused.py) leaves `_ABOVE_32` free: its
+    sort needs one 32-bit operand where the general one takes two of
+    64 bits, which the TPU's compiler takes minutes over and the chip
+    sorts, gathers and searches at a third of the speed."""
+    if len(key_idxs) != 1:
+        return False
+    col = batch.columns[key_idxs[0]]
+    if (col.vrange is None or col.encoding is not None
+            or col.data.ndim != 1
+            or not jnp.issubdtype(col.data.dtype, jnp.integer)):
+        return False
+    lo, hi = col.vrange
+    return -(2 ** 31) <= lo and hi < _ABOVE_32
+
+
+def build_side(batch: ColumnBatch, key_idxs: Sequence[int],
+               live: Optional[jnp.ndarray] = None) -> BuildTable:
+    """`live` marks the batch's rows where they are not at its front
+    (plan_compiler.concat_in_place): the sort brings them there."""
     cap = batch.capacity
-    live = batch.live_mask()
+    rows = batch.num_rows
+    if live is None:
+        live = batch.live_mask()
+    else:
+        rows = jnp.sum(live).astype(jnp.int32)
     vals, all_valid = _join_keys(batch, key_idxs, live)
-    # Sort null-keyed / dead rows to the end: leading rank 0 valid, 1 not.
-    rank = jnp.where(all_valid, 0, 1).astype(jnp.int64)
-    perm = sort_permutation([rank] + vals, cap)
-    sorted_batch = batch.gather(perm, batch.num_rows)
+    if _fits_32_bits(batch, key_idxs):
+        # rank and key in ONE 32-bit sort operand: a valid key lies in
+        # its column's stamped range, null-keyed and dead rows take the
+        # value above every one of them. The search compares these keys
+        # with the probe side's 64-bit ones as they are (promotion).
+        vals = [jnp.where(all_valid, vals[0].astype(jnp.int32),
+                          jnp.int32(_ABOVE_32))]
+        perm = sort_permutation(vals, cap)
+    else:
+        # Sort null-keyed / dead rows to the end: leading rank 0 valid,
+        # 1 not.
+        rank = jnp.where(all_valid, 0, 1).astype(jnp.int64)
+        perm = sort_permutation([rank] + vals, cap)
+    sorted_batch = batch.gather(perm, rows)
     sorted_keys = [jnp.take(v, perm) for v in vals]
     valid_bound = jnp.sum(all_valid).astype(jnp.int32)
     return BuildTable(sorted_batch, sorted_keys, valid_bound)
@@ -124,6 +161,52 @@ def probe_ranges(build: BuildTable, probe: ColumnBatch,
                         build.batch.capacity, upper=True)
     counts = jnp.where(all_valid, hi - lo, 0).astype(jnp.int32)
     return lo, counts
+
+
+def _keys_equal_at(build_keys: List[jnp.ndarray], idx: jnp.ndarray,
+                   probe_keys: List[jnp.ndarray]) -> jnp.ndarray:
+    eq = jnp.ones(idx.shape, dtype=bool)
+    for bk, pk in zip(build_keys, probe_keys):
+        eq = eq & (jnp.take(bk, idx) == pk)
+    return eq
+
+
+def probe_unique(build: BuildTable, probe: ColumnBatch,
+                 key_idxs: Sequence[int]
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Per-probe-row (lo, matched, dup) for a lookup join, which needs
+    one match and the fact of a second, not the count: ONE lower-bound
+    search, then the build key at `lo` (equal, inside the live bound:
+    matched) and at `lo + 1` (equal as well: the build keys are not
+    unique for this row). Two gathers in place of `probe_ranges`'
+    second search."""
+    live = probe.live_mask()
+    vals, all_valid = _join_keys(probe, key_idxs, live)
+    cap = build.batch.capacity
+    bound = build.valid_bound.astype(jnp.int32)
+    lo = _binary_search(build.keys, vals, build.valid_bound, cap,
+                        upper=False)
+
+    def equal_at(idx):
+        return (idx < bound) & _keys_equal_at(
+            build.keys, jnp.clip(idx, 0, cap - 1), vals)
+
+    matched = all_valid & equal_at(lo)
+    return lo, matched, matched & equal_at(lo + 1)
+
+
+def front_row_ids(keep: jnp.ndarray, capacity: int
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Row ids of the first `capacity` rows where `keep`, in order, and
+    how many rows `keep` holds in all (more than `capacity`: they did
+    not fit). No full-width scatter or gather: a prefix sum of the mask
+    and a search of 1..capacity in it, `capacity` x log2(n) steps, the
+    shape `expand_gather_maps` uses. Ids past the total are clamped
+    garbage."""
+    csum = jnp.cumsum(keep.astype(jnp.int32))
+    j = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+    ids = jnp.searchsorted(csum, j, side="left").astype(jnp.int32)
+    return jnp.clip(ids, 0, keep.shape[0] - 1), csum[-1]
 
 
 def expand_gather_maps(lo: jnp.ndarray, counts: jnp.ndarray,

@@ -96,6 +96,37 @@ def concat_traced(batches: List[ColumnBatch]) -> ColumnBatch:
     columnar.batch.concat_batches, which syncs row counts to the host)."""
     if len(batches) == 1:
         return batches[0]
+    interim, live = _concat_columns(batches)
+    perm, total = filterops.compact_perm(live, interim.capacity)
+    return interim.gather(perm, total)
+
+
+def _merged_vrange(parts):
+    """The envelope of the parts' stamped value ranges where each plain
+    column has one: the rows are the parts' own, so the bound holds.
+    (`concat_traced` drops it, as it always has: its callers' programs
+    are keyed on treedefs that never held one.)"""
+    if any(p.vrange is None or p.encoding is not None for p in parts):
+        return None
+    return (min(p.vrange[0] for p in parts),
+            max(p.vrange[1] for p in parts))
+
+
+def concat_in_place(batches: List[ColumnBatch]
+                    ) -> Tuple[ColumnBatch, jnp.ndarray]:
+    """The batches end to end, each part's dead rows left where they
+    are; -> (batch, live mask). For a consumer that takes a mask and
+    moves the rows itself (a join's build side: its sort sends dead
+    rows last), which `concat_traced`'s compaction would only cost a
+    scatter and a gather of every column. Plain columns keep the
+    envelope of their parts' value ranges."""
+    if len(batches) == 1:
+        return batches[0], batches[0].live_mask()
+    return _concat_columns(batches, keep_vrange=True)
+
+
+def _concat_columns(batches: List[ColumnBatch], keep_vrange: bool = False
+                    ) -> Tuple[ColumnBatch, jnp.ndarray]:
     schema = batches[0].schema
     caps = [b.capacity for b in batches]
     total_cap = sum(caps)
@@ -142,7 +173,7 @@ def concat_traced(batches: List[ColumnBatch]) -> ColumnBatch:
         vr = parts[0].vrange if (
             parts[0].encoding is not None
             and all(p.vrange == parts[0].vrange for p in parts)) \
-            else None
+            else (_merged_vrange(parts) if keep_vrange else None)
         return DeviceColumn(dtype, data, val, lens, ev, mv, vrange=vr,
                             elem_lengths=el,
                             encoding=parts[0].encoding)
@@ -151,9 +182,7 @@ def concat_traced(batches: List[ColumnBatch]) -> ColumnBatch:
     for ci, field in enumerate(schema.fields):
         cols.append(cat_col([b.columns[ci] for b in batches],
                             field.dataType))
-    interim = ColumnBatch(schema, cols, total_cap)
-    perm, total = filterops.compact_perm(live, total_cap)
-    return interim.gather(perm, total)
+    return ColumnBatch(schema, cols, total_cap), live
 
 
 def shard_equi_join(node: J._DeviceJoinBase, left: ColumnBatch,

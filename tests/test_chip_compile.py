@@ -211,6 +211,67 @@ def test_lookup_join_probe_and_gather(one_chip, as_tpu):
     _compile(kernel, _sorted_build(one_chip), _probe(one_chip))
 
 
+Q12_PART = 7_864_320      # slots of one of SF10 lineitem's 8 parts
+Q12_BUILD = 15_728_640    # slots of SF10 orders, its 8 parts end to end
+
+
+def test_survivor_lookup_join_at_q12_width(one_chip, as_tpu):
+    """The lookup join under a filter, at tpch_q12_join_resident's own
+    width (exec/fused.py `survivors` + `lookup_join`): the mask's
+    survivors brought to 1/64 of a part's slots by `front_row_ids`,
+    then ONE search of the 15.7M-slot build table, whose keys the
+    build side narrowed to 32 bits, by the 64-bit probe keys. Small:
+    nothing here is as wide as the part."""
+    from spark_rapids_tpu.exec.fused import survivor_capacity
+    from spark_rapids_tpu.ops import joinops
+
+    cap = survivor_capacity(Q12_PART)
+    assert cap == 122_880
+    build = joinops.BuildTable(
+        _batch([_col(long, jnp.int64, Q12_BUILD, one_chip),
+                _col(long, jnp.int64, Q12_BUILD, one_chip)],
+               ["o_orderkey", "o_code"], one_chip),
+        [_sds((Q12_BUILD,), jnp.int32, one_chip)],
+        _sds((), jnp.int32, one_chip))
+    probe = _batch([_col(long, jnp.int64, Q12_PART, one_chip)],
+                   ["l_orderkey"], one_chip)
+
+    def kernel(bt, probe, keep):
+        ids, total = joinops.front_row_ids(keep & probe.live_mask(), cap)
+        front = probe.gather(ids, jnp.minimum(total, cap))
+        lo, matched, dup = joinops.probe_unique(bt, front, [0])
+        got = bt.batch.columns[1].gather(
+            jnp.clip(lo, 0, bt.batch.capacity - 1))
+        return got.data, got.validity & matched, jnp.any(dup), total > cap
+
+    c = _compile(kernel, build, probe,
+                 _sds((Q12_PART,), jnp.bool_, one_chip))
+    assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
+
+
+def test_build_side_sort_is_one_32_bit_operand_at_q12_width(one_chip,
+                                                            as_tpu):
+    """`joinops.build_side` over SF10 `orders` as buildprep sees it —
+    the key's stamped range fits 32 bits, rows live where `live` says:
+    ONE sort operand beside the row ids. (The general path's two
+    64-bit operands take the compiler two minutes: not compiled here.)"""
+    from spark_rapids_tpu.ops import joinops
+
+    key = _col(long, jnp.int64, Q12_BUILD, one_chip, vrange=(0, 2 ** 26 - 1))
+    batch = _batch([key, _col(long, jnp.int64, Q12_BUILD, one_chip)],
+                   ["o_orderkey", "o_code"], one_chip)
+    assert joinops._fits_32_bits(batch, [0])
+
+    def kernel(batch, live):
+        bt = joinops.build_side(batch, [0], live)
+        return bt.keys[0], bt.batch.columns[1].data, bt.valid_bound
+
+    c = _compile(kernel, batch, _sds((Q12_BUILD,), jnp.bool_, one_chip))
+    sorts = [ln for ln in c.as_text().splitlines() if " sort(" in ln]
+    assert len(sorts) == 1 and "s64" not in sorts[0].split(" sort(")[0]
+    assert c.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
 def test_expanded_join_gather_maps(one_chip, as_tpu):
     """The dup-key join's blocking lowering: (lo, counts) expanded to
     probe/build gather maps at a static output capacity (2 matches per
