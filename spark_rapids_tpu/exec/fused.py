@@ -297,6 +297,18 @@ def survivor_capacity(n: int) -> Optional[int]:
     return cap if cap * 4 <= n else None
 
 
+#: lookup joins that read no build column: row-preserving whatever the
+#: build keys hold, and nothing of the build side's payload is moved
+_NO_BUILD_COLUMN = ("left_semi", "left_anti", "existence")
+
+
+def build_gather(join_type: str) -> str:
+    """What a lookup join moves of its build side, for the join's
+    record and the program keys: "matched", the columns read at the
+    rows a probe matched (`perm[lo]`), or "none"."""
+    return "none" if join_type in _NO_BUILD_COLUMN else "matched"
+
+
 @functools.lru_cache(maxsize=4096)
 def program_name(key_tag: str, nodes_key) -> str:
     """`fused_<kind>_<8 hex digits>`: what a fused program is called in
@@ -745,7 +757,7 @@ class FusedSingleChipExecutor:
             return False
         if not self._lookup_conf:
             return False
-        if node.join_type in ("left_semi", "left_anti", "existence"):
+        if node.join_type in _NO_BUILD_COLUMN:
             return True
         return node.join_type in ("inner", "left") and use_lookup
 
@@ -867,7 +879,8 @@ class FusedSingleChipExecutor:
                            inject=True):
                 out, fl, *rest = jitted(*inputs)
             # fl: scalar=[cap] | [cap, uniq, push] (chain programs), then
-            # one lost-bet flag for each of `survivor_joins`
+            # one lost-bet flag for each of `survivor_joins`, then what
+            # nothing reads (joinops.rows_at)
             fl = jnp.asarray(fl).reshape(-1)
             flags.append(fl[0])
             if fl.shape[0] > 1:
@@ -918,6 +931,7 @@ class FusedSingleChipExecutor:
             builds = list(builds)
             join_plan = list(join_plan)
             lost = []  # one flag per "lookupSurvivors" join
+            unread = []  # joinops.rows_at: an output's place, no flag
 
             def materialized(b, mask):
                 return b if mask is None else filterops.compact(b, mask)
@@ -931,8 +945,9 @@ class FusedSingleChipExecutor:
                 probe rows keep their positions; match/no-match lands
                 in the pending mask (inner/semi/anti), the exists
                 column, or right-column validity (left). `bt` is the
-                prepared BuildTable — sorted ONCE per join by the
-                buildprep program, not once per probe partition."""
+                build side as the buildprep program indexed it, ONCE
+                per join and not once per probe partition: the batch
+                lies as it lay and is read at `perm[lo]`."""
                 work_l, lk = nd._prepare_keys(b, nd.left_keys)
                 lo, matched, dup = joinops.probe_unique(bt, work_l, lk)
                 jt = nd.join_type
@@ -951,8 +966,10 @@ class FusedSingleChipExecutor:
                 # uniqueness flag and the re-run lowers this join via
                 # the expanded blocking path (same capacity factors)
                 uniq = uniq | jnp.any(dup & visible(b, mask))
-                safe = jnp.clip(lo, 0, bt.batch.capacity - 1)
-                rcols = [c.gather(safe) for c in bt.batch.columns]
+                rows, plain_read = joinops.rows_at(
+                    bt, jnp.clip(lo, 0, bt.capacity - 1))
+                unread.append(plain_read)
+                rcols = [c.gather(rows) for c in bt.batch.columns]
                 rcols = [c.replace(validity=c.validity & matched)
                          for c in rcols]
                 # nd.schema carries the planner's nullability (left
@@ -1035,7 +1052,7 @@ class FusedSingleChipExecutor:
                     else:
                         ovf = ovf | o
             out = materialized(b, mask)
-            fl = jnp.stack([ovf, uniq, push] + lost)
+            fl = jnp.stack([ovf, uniq, push] + lost + unread)
             if ansi_live:
                 return out, fl, ansi
             return out, fl
@@ -1127,7 +1144,9 @@ class FusedSingleChipExecutor:
                           and key not in self._wide_joins else None)
                     out.append({
                         "lowering": "lookupSurvivors" if to else "lookup",
-                        "joinType": nd.join_type, "probeSlots": cap,
+                        "joinType": nd.join_type,
+                        "buildGather": build_gather(nd.join_type),
+                        "probeSlots": cap,
                         "searchedSlots": to or cap,
                         "outputCapacity": to or cap})
                     if to:
@@ -1165,8 +1184,8 @@ class FusedSingleChipExecutor:
             nodes_key = tuple(
                 k if isinstance(n, agg_pushdown.MergeTail) else k[:2]
                 for n, k in zip(nodes, keys))
-            # lookup-join build sides materialize + sort ONCE, outside
-            # the per-partition programs, and ride in as extra inputs
+            # lookup-join build sides are indexed ONCE, outside the
+            # per-partition programs, and ride in as extra inputs
             join_keys = [k for n, k in zip(nodes, keys)
                          if isinstance(n, J.TpuBroadcastHashJoinExec)]
             builds = [build_table(n) for n in nodes
@@ -1184,8 +1203,8 @@ class FusedSingleChipExecutor:
                 plan = chain_joins(nodes, keys, b.capacity) \
                     if builds else []
                 for key, bt, jp in zip(join_keys, builds, plan):
-                    jp["buildSlots"] = bt.batch.capacity
-                    note_join(key, jp, [bt.batch.num_rows])
+                    jp["buildSlots"] = bt.capacity
+                    note_join(key, jp, [bt.num_rows])
                 bets = tuple(k for k, jp in zip(join_keys, plan)
                              if jp["lowering"] == "lookupSurvivors")
 
@@ -1195,12 +1214,16 @@ class FusedSingleChipExecutor:
                                         join_plan=_plan)
 
                 # the lowering is structural: which joins search their
-                # survivors is part of the program's key (a chain with
-                # none keeps the key, and the name, it had)
-                marked = nodes_key + ((
-                    "survivors",
-                    tuple(jp["lowering"] for jp in plan)),) \
-                    if bets else nodes_key
+                # survivors, and what each reads of a build side left
+                # as it lay, is part of the program's key (a chain with
+                # no join keeps the key, and the name, it had)
+                marked = nodes_key
+                if bets:
+                    marked += (("survivors",
+                                tuple(jp["lowering"] for jp in plan)),)
+                if plan:
+                    marked += (("buildGather",
+                                tuple(jp["buildGather"] for jp in plan)),)
                 return run_program("chain", marked, stage_fn,
                                    [b] + builds, join_fields=plan,
                                    survivor_joins=bets, **uses)
@@ -1208,20 +1231,26 @@ class FusedSingleChipExecutor:
             return [one(b) for b in base]
 
         def build_table(jn: PhysicalPlan):
-            """Prepared (sorted) BuildTable for one lookup join — ONE
-            buildprep program per join per run, shared by every
-            per-partition chain program as an extra pytree input."""
+            """The build side of one lookup join, indexed where it lies
+            (joinops.BuildIndex) — ONE buildprep program per join per
+            run, shared by every per-partition chain program as an
+            extra pytree input."""
             parts = emit_parts(jn.children[1])
+            gather = build_gather(jn.join_type)
 
             def bp_fn(*ps):
                 # the parts end to end, uncompacted: the build side's
                 # sort sends every dead row last anyway
                 cb, live = concat_in_place(concat_inputs(list(ps)))
-                return (jn._build_table(cb, live=live),
+                return (jn._build_index(cb, live, gather == "matched"),
                         jnp.zeros((), bool))
 
-            return run_program("buildprep", _plan_key(jn)[:2], bp_fn,
-                               parts)
+            # in the key, as in the chain's: no cache may hand a chain
+            # that reads `perm[lo]` the sorted batch this program made
+            # before it made an index
+            return run_program(
+                "buildprep", _plan_key(jn)[:2] + (("buildGather", gather),),
+                bp_fn, parts)
 
         def concat_inputs(parts):
             return [widen_traced(p) for p in parts]
@@ -1327,7 +1356,9 @@ class FusedSingleChipExecutor:
                        "probeSlots": probe_slots,
                        "searchedSlots": probe_slots,
                        "outputCapacity": out_cap,
-                       "buildSlots": build_slots}
+                       "buildSlots": build_slots,
+                       # shard_equi_join sorts the whole build side
+                       "buildGather": "sorted"}
                 key = _plan_key(node)
                 note_join(key, rec, [p.num_rows for p in rparts])
 

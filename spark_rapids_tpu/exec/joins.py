@@ -381,13 +381,13 @@ class _DeviceJoinBase(PhysicalPlan):
                            [c.truncate(cap2) for c in reduced.columns],
                            n)
 
-    def _build_table(self, right: ColumnBatch, keys=None,
-                     live=None) -> joinops.BuildTable:
+    def _build_table(self, right: ColumnBatch,
+                     keys=None) -> joinops.BuildTable:
         rsch = self.children[1].schema
         work_r, rk = self._prepare_keys(right,
                                         keys if keys is not None
                                         else self.right_keys)
-        bt = joinops.build_side(work_r, rk, live)
+        bt = joinops.build_side(work_r, rk)
         if len(bt.batch.columns) != len(right.columns):
             # strip temp key columns from the (sorted) build batch
             bt = joinops.BuildTable(
@@ -396,6 +396,17 @@ class _DeviceJoinBase(PhysicalPlan):
                             bt.batch.num_rows),
                 bt.keys, bt.valid_bound)
         return bt
+
+    def _build_index(self, right: ColumnBatch, live,
+                     reads_columns: bool) -> joinops.BuildIndex:
+        """The build side indexed where it lies, for a lookup join that
+        reads its columns at the rows a probe matched, or (semi, anti,
+        existence: `reads_columns` false) reads none."""
+        work_r, rk = self._prepare_keys(right, self.right_keys)
+        idx = joinops.build_index(work_r, rk, live)
+        if not reads_columns:
+            return idx._replace(batch=None, perm=None)
+        return idx._replace(batch=right)
 
 
 class TpuShuffledHashJoinExec(_DeviceJoinBase):

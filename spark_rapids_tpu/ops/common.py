@@ -29,7 +29,7 @@ CPU backend.
 
 from __future__ import annotations
 
-from typing import List
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -172,11 +172,19 @@ def rows_equal_adjacent(keys: List[jnp.ndarray]) -> jnp.ndarray:
     return eq
 
 
+def sorted_with_permutation(key_arrays: List[jnp.ndarray], capacity: int
+                            ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
+    """Stable multi-key sort; -> (the keys, sorted; the gather
+    permutation). The sorted keys are the sort's own outputs: a caller
+    of `sort_permutation` that wants them gathers them a second time."""
+    iota = jnp.arange(capacity, dtype=jnp.int32)
+    out = lax.sort(tuple(key_arrays) + (iota,), num_keys=len(key_arrays),
+                   is_stable=True)
+    return list(out[:-1]), out[-1]
+
+
 def sort_permutation(key_arrays: List[jnp.ndarray],
                      capacity: int) -> jnp.ndarray:
     """Stable multi-key sort; returns the gather permutation (cuDF
     `Table.sortOrder` analog)."""
-    iota = jnp.arange(capacity, dtype=jnp.int32)
-    out = lax.sort(tuple(key_arrays) + (iota,), num_keys=len(key_arrays),
-                   is_stable=True)
-    return out[-1]
+    return sorted_with_permutation(key_arrays, capacity)[1]

@@ -22,7 +22,7 @@ data-dependent output sizes (SURVEY.md section 7 hard part #1/#2).
 
 from __future__ import annotations
 
-from typing import List, NamedTuple, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax.numpy as jnp
 
@@ -30,7 +30,7 @@ from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.ops.common import (
     equality_keys,
     normalize_floating,
-    sort_permutation,
+    sorted_with_permutation,
 )
 
 
@@ -40,6 +40,25 @@ class BuildTable(NamedTuple):
     batch: ColumnBatch             # sorted by join keys, null-keyed rows last
     keys: List[jnp.ndarray]        # sorted orderable keys (excl. null rank)
     valid_bound: jnp.ndarray       # scalar int32: rows with non-null keys
+
+
+class BuildIndex(NamedTuple):
+    """Build side indexed for probing and left as it lies: a probe
+    that found sorted position `lo` reads the batch's row `perm[lo]`,
+    so only the rows a probe matched are ever moved (the fused
+    engine's lookup join, exec/fused.py `lookup_join`)."""
+
+    batch: Optional[ColumnBatch]   # UNSORTED, live rows where `live` left
+    #                                them; None: the join reads no column
+    keys: List[jnp.ndarray]        # sorted orderable keys (excl. null rank)
+    perm: Optional[jnp.ndarray]    # int32 [capacity]: sorted position ->
+    #                                row of `batch`; None with the batch
+    valid_bound: jnp.ndarray       # scalar int32: rows with non-null keys
+    num_rows: jnp.ndarray          # scalar int32: live rows
+
+    @property
+    def capacity(self) -> int:
+        return self.keys[0].shape[0]
 
 
 def _join_keys(batch: ColumnBatch, key_idxs: Sequence[int],
@@ -76,10 +95,12 @@ def _fits_32_bits(batch: ColumnBatch, key_idxs: Sequence[int]) -> bool:
     return -(2 ** 31) <= lo and hi < _ABOVE_32
 
 
-def build_side(batch: ColumnBatch, key_idxs: Sequence[int],
-               live: Optional[jnp.ndarray] = None) -> BuildTable:
-    """`live` marks the batch's rows where they are not at its front
-    (plan_compiler.concat_in_place): the sort brings them there."""
+def build_index(batch: ColumnBatch, key_idxs: Sequence[int],
+                live: Optional[jnp.ndarray] = None) -> BuildIndex:
+    """Everything of `build_side` up to and including the sort; the
+    batch stays as it is. `live` marks the batch's rows where they are
+    not at its front (plan_compiler.concat_in_place): the sort sends
+    the others last."""
     cap = batch.capacity
     rows = batch.num_rows
     if live is None:
@@ -94,16 +115,23 @@ def build_side(batch: ColumnBatch, key_idxs: Sequence[int],
         # with the probe side's 64-bit ones as they are (promotion).
         vals = [jnp.where(all_valid, vals[0].astype(jnp.int32),
                           jnp.int32(_ABOVE_32))]
-        perm = sort_permutation(vals, cap)
+        sorted_keys, perm = sorted_with_permutation(vals, cap)
     else:
         # Sort null-keyed / dead rows to the end: leading rank 0 valid,
         # 1 not.
         rank = jnp.where(all_valid, 0, 1).astype(jnp.int64)
-        perm = sort_permutation([rank] + vals, cap)
-    sorted_batch = batch.gather(perm, rows)
-    sorted_keys = [jnp.take(v, perm) for v in vals]
+        ranked, perm = sorted_with_permutation([rank] + vals, cap)
+        sorted_keys = ranked[1:]
     valid_bound = jnp.sum(all_valid).astype(jnp.int32)
-    return BuildTable(sorted_batch, sorted_keys, valid_bound)
+    return BuildIndex(batch, sorted_keys, perm, valid_bound, rows)
+
+
+def build_side(batch: ColumnBatch, key_idxs: Sequence[int],
+               live: Optional[jnp.ndarray] = None) -> BuildTable:
+    """The index, and the whole batch moved by its permutation."""
+    idx = build_index(batch, key_idxs, live)
+    return BuildTable(batch.gather(idx.perm, idx.num_rows), idx.keys,
+                      idx.valid_bound)
 
 
 def _tuple_cmp_at(build_keys: List[jnp.ndarray], mid: jnp.ndarray,
@@ -171,7 +199,7 @@ def _keys_equal_at(build_keys: List[jnp.ndarray], idx: jnp.ndarray,
     return eq
 
 
-def probe_unique(build: BuildTable, probe: ColumnBatch,
+def probe_unique(build: Union[BuildTable, BuildIndex], probe: ColumnBatch,
                  key_idxs: Sequence[int]
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Per-probe-row (lo, matched, dup) for a lookup join, which needs
@@ -182,7 +210,7 @@ def probe_unique(build: BuildTable, probe: ColumnBatch,
     second search."""
     live = probe.live_mask()
     vals, all_valid = _join_keys(probe, key_idxs, live)
-    cap = build.batch.capacity
+    cap = build.keys[0].shape[0]
     bound = build.valid_bound.astype(jnp.int32)
     lo = _binary_search(build.keys, vals, build.valid_bound, cap,
                         upper=False)
@@ -193,6 +221,23 @@ def probe_unique(build: BuildTable, probe: ColumnBatch,
 
     matched = all_valid & equal_at(lo)
     return lo, matched, matched & equal_at(lo + 1)
+
+
+def rows_at(build: BuildIndex, pos: jnp.ndarray
+            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """-> (the row of `build.batch` at each sorted position `pos`; a
+    scalar that means nothing, which the caller returns from its
+    program in a place of its own, where nothing reads it).
+
+    The scalar is a second, plain read of the permutation. XLA:TPU
+    (libtpu 0.0.34) prefetches ONE entry parameter into fast memory for
+    the whole program, and takes one whose every use is a gather: left
+    alone it takes the permutation, for the few rows read here, and the
+    sorted keys that the search loop reads at every step stay in HBM —
+    the search of Q12 then takes 21 ns a slot and step where it takes
+    7.1 (PERF.md, PR 30). tests/test_chip_compile.py holds the compiler
+    to it at Q12's width."""
+    return jnp.take(build.perm, pos), build.perm[0] < 0
 
 
 def front_row_ids(keep: jnp.ndarray, capacity: int

@@ -398,7 +398,7 @@ def _register_export_serialization() -> None:
     import jax.export as jex
 
     from spark_rapids_tpu.columnar.batch import ColumnBatch, DeviceColumn
-    from spark_rapids_tpu.ops.joinops import BuildTable
+    from spark_rapids_tpu.ops.joinops import BuildIndex, BuildTable
 
     for node in (DeviceColumn, ColumnBatch):
         try:
@@ -409,11 +409,12 @@ def _register_export_serialization() -> None:
                 deserialize_auxdata=pickle.loads)
         except ValueError:
             pass  # already registered (session re-init)
-    try:
-        jex.register_namedtuple_serialization(
-            BuildTable, serialized_name="srtpu.BuildTable")
-    except ValueError:
-        pass
+    for node in (BuildTable, BuildIndex):
+        try:
+            jex.register_namedtuple_serialization(
+                node, serialized_name=f"srtpu.{node.__name__}")
+        except ValueError:
+            pass
     _export_serialization_ready = True
 
 

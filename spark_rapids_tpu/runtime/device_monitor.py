@@ -521,7 +521,8 @@ def check_stale(epoch: Optional[int], what: str) -> None:
 
 def check_batch(batch) -> None:
     """Stale-epoch check over a ColumnBatch's columns (dispatch-input
-    gate; BuildTable wrappers are unwrapped like encoding_key does).
+    gate; BuildTable / BuildIndex wrappers are unwrapped like
+    encoding_key does).
     Columns built inside traces re-stamp at the current epoch, so only
     genuinely pre-recovery uploads trip this."""
     cols = getattr(batch, "columns", None)
