@@ -39,8 +39,8 @@ command (`python -m spark_rapids_tpu.tools.multichip_bench`). The
 replica fleet (`--fleet`) is refused on platform `tpu`: the supervisor
 gives its children no device, so they would fight for the chip.
 
-Per-query compile metrics (programs compiled / cache hits / warmup
-hits / compile seconds / distinct variants) ride along from
+Per-query compile metrics (programs compiled / cache hits / compile
+seconds / jax disk-cache hits / distinct variants) ride along from
 session.last_execution. A duplicate-key dimension join variant
 exercises the expanded blocking path (the lookup-join uniqueness bet
 deliberately lost) so the expansion machinery has a perf number too.
@@ -509,10 +509,6 @@ def cold_probe():
 
     t0 = time.perf_counter()
     spark = TpuSparkSession(_session_conf())
-    # the warmup thread races the scan I/O in production; the probe
-    # joins it so the measurement is deterministic about what it
-    # includes (warmup compile time counts toward cold start)
-    compile_cache.warmup_join(300)
     base = spark.read.parquet(data.fact_dir).cache(storage="device")
     dim = spark.read.parquet(data.dim_dir).cache(storage="device")
     out = engine_query(base, dim).collect_arrow()
@@ -522,7 +518,6 @@ def cold_probe():
         "rows": out.num_rows,
         "engine": spark.last_execution["engine"],
         "compile": spark.last_execution["compile"],
-        "warm_rebuilds": compile_cache.stats.snapshot()["warmRebuilds"],
         "compile_cache_dir": compile_cache.cache_dir(),
         "platform": dev.platform,
         "device_kind": dev.device_kind,
@@ -761,12 +756,10 @@ def main():
     except Exception as e:  # never lose the main report
         print(f"# dupjoin variant unavailable: {e!r}", flush=True)
 
-    # leave index + artifacts on disk for the second invocation
-    # (`bench.py --cold-probe`), which measures the warm-cache start
+    # jax's disk cache now holds every program for the second
+    # invocation (`bench.py --cold-probe`), which measures the
+    # warm-cache start
     from spark_rapids_tpu.obs import telemetry as _tel
-    from spark_rapids_tpu.runtime import compile_cache
-
-    compile_cache.flush()
 
     roofline = dev_gbps * 1e9 / peak
 

@@ -126,7 +126,7 @@ baseline_eager, _ = run_all(BASE_EAGER)
 # straggler probability kept low (each injected straggler stalls an
 # attempt ~0.2s before speculation's duplicate wins).
 SITES = ["io.read:p=0.3", "shuffle.fetch:p=0.3",
-         "shuffle.deserialize:p=0.2", "compile.cache_load:every=2",
+         "shuffle.deserialize:p=0.2",
          "spill.disk:p=0.3", "device.dispatch:once",
          "worker.crash:p=0.2", "task.straggler:p=0.1",
          "shuffle.lost_output:once"]

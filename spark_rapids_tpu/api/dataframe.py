@@ -787,10 +787,11 @@ class DataFrame:
 
         # Compile observability (the tentpole's watch-forever channel):
         # the process compile ledger is snapshotted around the query and
-        # the delta — programs compiled, structural cache hits, warmup
-        # hits, compile seconds — lands in last_execution["compile"]
-        # and the session metrics, with the fused engine's distinct
-        # program-variant count folded in when it ran. The stage
+        # the delta — programs compiled, structural cache hits, compile
+        # seconds, jax's disk hits and misses — lands in
+        # last_execution["compile"] and the session metrics, with the
+        # fused engine's distinct program-variant count folded in when
+        # it ran. The stage
         # scheduler's ledger (tasks launched/retried/speculated,
         # recomputed partitions, evicted workers) rides the same
         # snapshot-delta channel into last_execution["scheduler"].
@@ -815,11 +816,8 @@ class DataFrame:
             qm.metric("compile.programsCompiled").add(
                 comp["programsCompiled"])
             qm.metric("compile.cacheHits").add(comp["cacheHits"])
-            qm.metric("compile.warmHits").add(comp["warmHits"])
             qm.metric("compile.timeMs").add(
                 int(comp["compileSeconds"] * 1000))
-            qm.metric("compile.artifactsQuarantined").add(
-                comp.get("artifactsQuarantined", 0))
             sch = _sched.stats.delta(sched_before,
                                      _sched.stats.snapshot())
             rec["scheduler"] = sch

@@ -352,8 +352,9 @@ class TpuSparkSession:
     @property
     def compile_cache_stats(self):
         """Process compile ledger (runtime/compile_cache.py): programs
-        compiled / structural cache hits / warmup hits / compile
-        seconds. Per-query deltas live in last_execution['compile']."""
+        compiled / structural cache hits / compile seconds / jax's
+        disk-cache hits and misses. Per-query deltas live in
+        last_execution['compile']."""
         from spark_rapids_tpu.runtime.compile_cache import stats
 
         return stats.snapshot()
@@ -365,10 +366,10 @@ class TpuSparkSession:
         fetch/checksum recoveries + orphaned/discarded blocks,
         stage-scheduler recoveries (retries, speculation, recomputed
         partitions, evicted workers), degradation-ladder demotions +
-        circuit-breaker state, quarantined compile artifacts, and
-        semaphore timeouts. A view over the unified registry
-        (obs/registry.py); keys are a stable contract. bench.py folds
-        this into its JSON so BENCH_* tracks robustness overhead."""
+        circuit-breaker state, and semaphore timeouts. A view over the
+        unified registry (obs/registry.py); keys are a stable contract.
+        bench.py folds this into its JSON so BENCH_* tracks robustness
+        overhead."""
         from spark_rapids_tpu.obs import registry as obs_registry
 
         return obs_registry.robustness_snapshot()
@@ -439,15 +440,6 @@ class TpuSparkSession:
             pass
         try:
             self.cache_manager.clear()
-        except Exception:
-            pass
-        try:
-            # drain pending compile-cache index/artifact writes so a
-            # follow-on process (or the warm-cache bench probe) sees
-            # everything this session compiled
-            from spark_rapids_tpu.runtime import compile_cache
-
-            compile_cache.flush()
         except Exception:
             pass
         try:

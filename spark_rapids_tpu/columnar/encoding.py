@@ -411,7 +411,7 @@ def align_encodings(cols):
 def encoding_key(obj) -> tuple:
     """Per-column dictionary identities of a ColumnBatch (or a
     BuildTable / BuildIndex wrapping one) — the fused engine folds this
-    into its program keys so persistent/AOT artifacts never serve a program
+    into its program keys so the program cache never serves a program
     whose baked host probes belong to a different dictionary."""
     cols = getattr(obj, "columns", None)
     if cols is None:

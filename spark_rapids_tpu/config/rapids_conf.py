@@ -533,37 +533,22 @@ FUSED_EXEC = conf(
 COMPILE_CACHE_ENABLED = conf(
     "spark.rapids.tpu.compileCache.enabled", True,
     "Persist compiled XLA programs across processes "
-    "(runtime/compile_cache.py): jax's persistent compilation cache "
-    "plus the engine's structural key->artifact index (invalidated "
-    "on any jax/jaxlib/engine version change; backends keep their "
-    "entries side by side). A fresh process re-tracing the same query "
-    "then loads serialized executables instead of recompiling.",
+    "(runtime/compile_cache.py): jax's persistent compilation cache, "
+    "whose keys carry the program's HLO, the jaxlib build and the "
+    "target device (versions and backends keep their entries side by "
+    "side). A fresh process re-tracing the same query then loads "
+    "serialized executables instead of recompiling.",
     bool)
 COMPILE_CACHE_DIR = conf(
     "spark.rapids.tpu.compileCache.dir", "",
     "Directory for the persistent compilation cache. Precedence: the "
     "JAX_COMPILATION_CACHE_DIR environment variable (jax's cache "
-    "lives exactly there, the engine's index in its srtpu/ "
-    "sub-directory), then this entry, then the fixed "
-    ".srtpu_compile_cache/ inside the checkout. Safe to share between "
-    "concurrent sessions and backends: all writes are atomic-rename "
-    "and entries are content-addressed.", str)
-COMPILE_CACHE_WARMUP = conf(
-    "spark.rapids.tpu.compileCache.warmup.enabled", True,
-    "Background-compile the top-K most-used fused programs recorded by "
-    "prior runs (their jax.export artifacts) at session start, "
-    "overlapping the first scan's decode/upload I/O; warmed programs "
-    "serve without even re-tracing.", bool)
-COMPILE_CACHE_WARMUP_TOP_K = conf(
-    "spark.rapids.tpu.compileCache.warmup.topK", 32,
-    "How many prior-run program artifacts the async warmup compiles, "
-    "most-used first.", int, checker=lambda v: 0 <= v <= (1 << 12))
-COMPILE_CACHE_ARTIFACT_MIN_S = conf(
-    "spark.rapids.tpu.compileCache.artifact.minCompileSecs", 0.5,
-    "Only fused programs whose first compile took at least this long "
-    "get a serialized warmup artifact (exporting re-traces the program "
-    "in the background; cheap programs reload fast enough from the "
-    "XLA disk cache alone).", float)
+    "lives exactly there, the engine's own files in its srtpu/ "
+    "sub-directory), then this entry (jax's cache in its xla/ "
+    "sub-directory), then the fixed .srtpu_compile_cache/ inside the "
+    "checkout. Safe to share between concurrent sessions and "
+    "backends: jax writes each entry by atomic rename under a key of "
+    "its content.", str)
 FUSED_SHAPE_BUCKETS = conf(
     "spark.rapids.sql.fusedExec.shapeBucketing", True,
     "Bucket scan-upload capacities to 1/8-power-of-two steps so files "
@@ -605,8 +590,8 @@ CHAOS_ENABLED = conf(
     "spark.rapids.tpu.chaos.enabled", False,
     "Arm the deterministic fault-injection registry "
     "(runtime/faults.py): injection sites across every failure domain "
-    "(io.read, shuffle.fetch, shuffle.deserialize, compile.cache_load, "
-    "spill.disk, device.dispatch) raise seeded faults that the "
+    "(io.read, shuffle.fetch, shuffle.deserialize, spill.disk, "
+    "device.dispatch) raise seeded faults that the "
     "engine's recovery machinery — backoff retries, quarantine, the "
     "degradation ladder — must absorb. ci/chaos_check.sh asserts "
     "results are identical to a clean run.", bool)

@@ -797,8 +797,8 @@ class FusedSingleChipExecutor:
 
             # dictionary identities ride the key: trace-time host
             # probes (predicate code rewrites, remap tables) bake
-            # dictionary CONTENT into a program, so a persistent/AOT
-            # artifact must never serve a different dictionary
+            # dictionary CONTENT into a program, so a cached program
+            # must never serve a different dictionary
             return tuple(
                 (tuple((tuple(leaf.shape), str(leaf.dtype))
                        for leaf in jax.tree_util.tree_leaves(b)),
@@ -860,15 +860,11 @@ class FusedSingleChipExecutor:
                 if hit:
                     m["cacheHits"] += 1
                     cc.stats.on_hit()
-                    # keep the disk index's usage ranking honest:
-                    # cross-query reuse counts toward warmup's top-K
-                    cc.record_use(key + jc._env_token(), "fused")
                 else:
                     m["programsRequested"] += 1
             sp.set(cacheHit=hit)
-            # the XLA module is `jit_<name>` whoever builds it: this
-            # process, or the warm-up thread from a disk artifact
-            # (runtime/compile_cache.py gives the artifact this name)
+            # the XLA module is `jit_<name>`: what the device trace
+            # calls this program
             fn.__name__ = fn.__qualname__ = name
             jitted = cached_jit(key, lambda: fn)
             # fatal-classification + chaos site device.fatal: a dead

@@ -54,8 +54,7 @@ EVENT_TYPES: Dict[str, str] = {
     "telemetry.summary":
         "bytesMoved, bytesMovedTotal, hbmPeakBytes, rooflineFrac, "
         "linkFrac, bytesPerOutputRow, wallMs",
-    "compile": "kind (miss|hit|warm|quarantine|warmRebuild|"
-               "exportFailed), seconds, error",
+    "compile": "kind (miss|hit), seconds",
     "degrade": "kind, from, to, reason",
     "join": "lowering, joinType, buildRows, buildSlots, probeSlots, "
             "searchedSlots, outputCapacity, runs",

@@ -20,8 +20,8 @@ def robustness_snapshot() -> dict:
     injections per site, backoff retries per domain, shuffle
     fetch/checksum recoveries + orphaned/discarded blocks,
     stage-scheduler recoveries, degradation-ladder demotions +
-    circuit-breaker state, quarantined compile artifacts, and
-    semaphore timeouts. Key layout is pinned by existing tests."""
+    circuit-breaker state, and semaphore timeouts. Key layout is pinned
+    by existing tests."""
     from spark_rapids_tpu.runtime import admission as _adm
     from spark_rapids_tpu.runtime import backoff, degrade, faults
     from spark_rapids_tpu.runtime import device_monitor as _dm
@@ -29,7 +29,6 @@ def robustness_snapshot() -> dict:
     from spark_rapids_tpu.runtime import sanitizer as _san
     from spark_rapids_tpu.runtime import scheduler as _sched
     from spark_rapids_tpu.runtime import semaphore as sem
-    from spark_rapids_tpu.runtime.compile_cache import stats
     from spark_rapids_tpu.shuffle.manager import get_shuffle_manager
 
     mgr = get_shuffle_manager()
@@ -53,8 +52,6 @@ def robustness_snapshot() -> dict:
             "deviceLostBuffers":
                 0 if cat is None
                 else cat.metrics.get("device_lost_buffers", 0)},
-        "artifactsQuarantined":
-            stats.snapshot()["artifactsQuarantined"],
         "semaphoreTimeouts": sem.get().timeouts,
     }
 

@@ -124,8 +124,7 @@ def profile_data(source: Source, top_n: int = 10) -> dict:
     shuffle = {"bytesWritten": 0, "bytesFetched": 0, "writes": 0,
                "fetches": 0, "retries": 0}
     spill = {"toHostBytes": 0, "toDiskBytes": 0, "unspillBytes": 0}
-    compile_c = {"miss": 0, "hit": 0, "warm": 0, "quarantine": 0,
-                 "warmRebuild": 0, "exportFailed": 0}
+    compile_c = {"miss": 0, "hit": 0}
     recovery = {"attempts": 0, "retried": 0, "speculated": 0,
                 "discarded": 0, "lost": 0, "failed": 0,
                 "degradations": 0, "chaosInjections": 0}
@@ -191,7 +190,7 @@ def profile_data(source: Source, top_n: int = 10) -> dict:
             sanitizer["lastCycle"] = ev.get("cycle")
         elif et == "sanitizer.inversion":
             sanitizer["inversions"] += 1
-    served = compile_c["hit"] + compile_c["warm"]
+    served = compile_c["hit"]
     requests = served + compile_c["miss"]
     return {
         "queryId": events[-1]["queryId"] if events else None,
@@ -238,7 +237,7 @@ def profile(source: Source, top_n: int = 10) -> str:
     ratio = ("n/a" if c["cacheServedRatio"] is None
              else f"{100.0 * c['cacheServedRatio']:.0f}%")
     lines.append(f"compile: {c['miss']} compiled, {c['hit']} cache "
-                 f"hit(s), {c['warm']} warm, cache-served {ratio}")
+                 f"hit(s), cache-served {ratio}")
     r = d["recovery"]
     lines.append(f"recovery: {r['attempts']} attempt(s), "
                  f"{r['retried']} retried, {r['speculated']} "

@@ -90,7 +90,7 @@ class TpuExecutorPlugin:
 
         self._validate_device()
         # chaos registry FIRST: every later init step is itself a
-        # consumer of an injection site (compile.cache_load, io.read)
+        # consumer of an injection site (io.read, spill.disk)
         faults.configure(conf)
         degrade.configure(conf)
         # device-loss monitor before anything that can touch the
@@ -106,7 +106,7 @@ class TpuExecutorPlugin:
         sanitizer.configure(conf)
         filecache.configure(conf)  # FileCache.init (Plugin.scala:545)
         # persistent compilation layer BEFORE any program compiles, so
-        # the whole session (incl. warmup) rides the disk cache
+        # the whole session rides the disk cache
         compile_cache.configure(conf)
         memory.initialize_memory(conf, force=True)
         semaphore.initialize(
@@ -148,9 +148,8 @@ class TpuExecutorPlugin:
         return fatal
 
     def shutdown(self):
-        from spark_rapids_tpu.runtime import compile_cache, memory
+        from spark_rapids_tpu.runtime import memory
 
-        compile_cache.flush()  # drain pending index/artifact writes
         memory.shutdown_memory()
 
 

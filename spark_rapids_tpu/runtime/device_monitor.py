@@ -31,8 +31,7 @@ with GPUs", PAPERS.md). This module is the recovery subsystem:
   (host/disk tiers survive and unspill into the new epoch on next
   use; device-only state is recomputed by the lineage scheduler /
   query resubmission), invalidate the encoded-dictionary device cache
-  (columnar/encoding.py) and PR 1's warm AOT executables (re-served
-  lazily from disk artifacts), mark the HBM timeline, then unfence.
+  (columnar/encoding.py), mark the HBM timeline, then unfence.
 - **Resubmission**: the outermost collect (api/dataframe.py) catches
   `DeviceLostError`, waits for the fence to lift (`await_ready`), and
   resubmits once through admission — the retryVictim pattern.
@@ -277,18 +276,18 @@ class DeviceMonitor:
     def _invalidate_device_state(self):
         """Drop every pre-epoch device residue: DEVICE-tier spillables
         (host/disk tiers survive for lazy restore), the encoded
-        dictionary device cache, warm AOT executables, and mark the
-        HBM occupancy timeline."""
+        dictionary device cache, and mark the HBM occupancy timeline.
+        Compiled programs need no hook: the epoch in jit_cache's keys
+        makes every pre-recovery entry a miss."""
         from spark_rapids_tpu.columnar import encoding
         from spark_rapids_tpu.obs import telemetry
-        from spark_rapids_tpu.runtime import compile_cache, memory
+        from spark_rapids_tpu.runtime import memory
 
         restorable = dropped = 0
         catalog = memory._catalog
         if catalog is not None:
             restorable, dropped = catalog.on_device_lost()
         encoding.invalidate_device_cache()
-        compile_cache.invalidate_warm()
         telemetry.hbm_epoch_marker(_EPOCH)
         return restorable, dropped
 

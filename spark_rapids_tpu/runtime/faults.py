@@ -10,8 +10,6 @@ locations where the real world fails —
     io.read             file open/read in io/readers.py + io/avro.py
     shuffle.fetch       shuffle block file reads (shuffle/manager.py)
     shuffle.deserialize wire-format decode (shuffle/serde.py)
-    compile.cache_load  persistent-cache artifact loads
-                        (runtime/compile_cache.py)
     spill.disk          disk-tier spill writes/reads (runtime/memory.py)
     device.dispatch     fused/eager program dispatch (exec/fused.py,
                         api/dataframe.py) — the site that exercises the
@@ -105,7 +103,7 @@ locations where the real world fails —
                         under backoff, billed to the query retry budget
 
 and every site's CONSUMER survives the injected fault: backoff retries
-(runtime/backoff.py), quarantine-and-recompile, or engine demotion.
+(runtime/backoff.py), lineage recomputation, or engine demotion.
 CI re-runs a query subset with seeded injection at each site and
 asserts results are identical to the clean run (ci/chaos_check.sh).
 
@@ -135,7 +133,6 @@ KNOWN_SITES = (
     "io.read",
     "shuffle.fetch",
     "shuffle.deserialize",
-    "compile.cache_load",
     "spill.disk",
     "device.dispatch",
     "worker.crash",
@@ -199,7 +196,7 @@ class SitePolicy:
 
 
 def parse_sites(spec: str, default_p: float) -> Dict[str, SitePolicy]:
-    """'io.read:p=0.1;shuffle.fetch:every=3;compile.cache_load:once'
+    """'io.read:p=0.1;shuffle.fetch:every=3;spill.disk:once'
     -> {site: SitePolicy}. A bare site name takes the default
     probability. Unknown site names are allowed (future PRs declare new
     sites without touching the parser)."""

@@ -33,11 +33,9 @@ _segmented_mod = None
 def _env_token() -> Tuple:
     """Trace-environment facts that change what a structurally identical
     program computes: the backend (kernels branch on it, e.g. the MXU
-    segmented reductions) and the test-only forced-matmul flag.
-    Deliberately EPOCH-FREE: this token rides into the persistent
-    compile-cache keys, and disk artifacts survive a device-loss
-    recovery (they reload into the rebuilt client) as well as process
-    restarts that reset the epoch to 1."""
+    segmented reductions) and the test-only forced-matmul flag. Part of
+    every in-process key; the persistent cache (jax's) is keyed on the
+    traced HLO and needs no token of ours."""
     global _segmented_mod
     if _segmented_mod is None:  # lazy: segmented imports columnar.batch
         from spark_rapids_tpu.ops import segmented
@@ -50,12 +48,12 @@ _device_monitor_mod = None
 
 
 def _mem_key(full: Tuple) -> Tuple:
-    """In-memory cache key: the persistent key PLUS the device epoch
-    (runtime/device_monitor.py). Executables jitted against a backend
-    that device-loss recovery tore down must never be re-dispatched —
-    the epoch bump makes every pre-recovery entry a miss, and programs
-    re-intern lazily against the fresh client (via the epoch-free disk
-    artifacts when one exists)."""
+    """In-memory cache key: structure + trace environment PLUS the
+    device epoch (runtime/device_monitor.py). Executables jitted against
+    a backend that device-loss recovery tore down must never be
+    re-dispatched — the epoch bump makes every pre-recovery entry a
+    miss, and programs re-intern lazily against the fresh client (their
+    rebuild loads from jax's disk cache, whose keys know no epoch)."""
     global _device_monitor_mod
     if _device_monitor_mod is None:  # lazy: avoids an import cycle
         from spark_rapids_tpu.runtime import device_monitor
@@ -72,12 +70,10 @@ def cached_jit(key: Tuple, build: Callable[[], Callable],
     lazily on first call, so a construction-time snapshot could label a
     trace with an environment it was not traced under.
 
-    Entries route through the persistent compilation layer
-    (runtime/compile_cache.py): a fresh build's first dispatch is timed
-    and recorded (and, for fused whole-stage programs, exported to a
-    disk artifact), and a key the background warmup already AOT-compiled
-    is served without building — the cross-process analog of this
-    module's in-process structural reuse."""
+    This is the one in-process program cache; across processes a
+    fresh build's compile is served by jax's persistent cache
+    (runtime/compile_cache.py). A build's first dispatch is timed under
+    the `compile` span and counted in the compile ledger."""
 
     def dispatch(*args, **kwargs):
         mem = _mem_key(key + _env_token())
@@ -88,63 +84,44 @@ def cached_jit(key: Tuple, build: Callable[[], Callable],
             with _lock:
                 fn = _cache.get(mem)
                 if fn is None:
-                    fn = _make_entry(mem[:-1], key, build, jit_kwargs)
+                    fn = _make_entry(key, build, jit_kwargs)
                     _cache[mem] = fn
         return fn(*args, **kwargs)
 
     return dispatch
 
 
-def _make_entry(full: Tuple, key: Tuple, build: Callable[[], Callable],
+def _make_entry(key: Tuple, build: Callable[[], Callable],
                 jit_kwargs) -> Callable:
-    """One cache entry: either a warmup-served AOT executable (with a
-    build-on-mismatch fallback) or a jax.jit whose first dispatch is
-    timed for the compile ledger. Must be called under _lock."""
+    """One cache entry: a jax.jit whose first dispatch is timed for the
+    compile ledger. Must be called under _lock."""
     from spark_rapids_tpu.runtime import compile_cache as cc
 
     tag = key[0] if key and isinstance(key[0], str) else "?"
-    warm = cc.take_warm(full) if not jit_kwargs else None
-    state = {"jitted": None, "timed": warm is not None}
+    jitted = None
     entry_lock = threading.Lock()
 
     def entry(*args, **kwargs):
-        if warm is not None and state["jitted"] is None:
-            try:
-                return warm(*args, **kwargs)
-            except Exception as e:
-                # aval/env drift between the recording and this
-                # process: rebuild live, never fail the query — but
-                # count it (and time the rebuild like any compile), so
-                # a warm layer that never serves shows
-                cc.stats.on_warm_rebuild(f"{type(e).__name__}: {e}"[:200])
-                state["timed"] = False
-        fn = state["jitted"]
-        if fn is not None and state["timed"]:
-            return fn(*args, **kwargs)
+        nonlocal jitted
+        if jitted is not None:
+            return jitted(*args, **kwargs)
         with entry_lock:
-            if state["jitted"] is None:
-                state["jitted"] = jax.jit(build(), **jit_kwargs)
-            if not state["timed"]:
-                state["timed"] = True
+            if jitted is None:
+                jitted = jax.jit(build(), **jit_kwargs)
                 from spark_rapids_tpu.obs import events as obs_events
 
                 t0 = time.perf_counter()
                 with obs_events.span("compile", kind=tag) as sp:
-                    out = state["jitted"](*args, **kwargs)
+                    out = jitted(*args, **kwargs)
                     # async dispatch returns once tracing+compilation
                     # are done (execution overlaps) — the cold-start
                     # quantity
                     seconds = time.perf_counter() - t0
                     sp.set(seconds=round(seconds, 6))
-                cc.record_build(
-                    full, tag, seconds, state["jitted"],
-                    args if not (kwargs or jit_kwargs) else None)
+                cc.stats.on_compile(seconds)
                 return out
-        return state["jitted"](*args, **kwargs)
+        return jitted(*args, **kwargs)
 
-    if warm is not None:
-        cc.stats.on_warm_hit()
-        cc.record_use(full, tag)
     return entry
 
 

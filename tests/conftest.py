@@ -20,7 +20,7 @@ if "host_platform_device_count" not in flags:
 # Isolate the persistent compile cache per test run: the layer stays
 # ENABLED (cross-module recompiles load from disk after the per-module
 # jit_cache clear below), but state never leaks between runs — tests
-# asserting compile counts must not see a previous run's artifacts.
+# asserting XLA compile counts must not see a previous run's entries.
 # Explicit per-test dirs (test_compile_cache.py) still win: env-derived
 # conf values are defaults, not overrides. JAX_COMPILATION_CACHE_DIR
 # outranks both (runtime/compile_cache.py resolve_dirs) and jax reads

@@ -47,12 +47,6 @@ def main() -> None:
         "spark.rapids.tpu.multihost.processId": pid,
         "spark.sql.shuffle.partitions": 4,
         "spark.sql.autoBroadcastJoinThreshold": -1,
-        # the pytest worker's cache directory comes down the
-        # environment with whatever artifacts its earlier modules
-        # left; a warm-up thread still compiling one of them when this
-        # short process exits aborts it (rc -6, "terminate called"):
-        # the product's fault (PERF.md section 7), not this test's
-        "spark.rapids.tpu.compileCache.warmup.enabled": False,
     })
     assert jax.process_count() == nproc, jax.process_count()
     spark.conf.set("spark.rapids.tpu.mesh",

@@ -4,8 +4,8 @@ The reference proves its reliability story with forced-fault tests
 (the *RetrySuite strategy); this suite does the same for every failure
 domain the deterministic injection registry (runtime/faults.py)
 covers: shuffle checksums + fetch backoff, file-read backoff,
-compile-cache quarantine, semaphore timeouts, disk-spill errors, and
-the fused -> eager -> CPU degradation ladder with its circuit breaker.
+semaphore timeouts, disk-spill errors, and the fused -> eager -> CPU
+degradation ladder with its circuit breaker.
 """
 
 import os
@@ -53,6 +53,29 @@ def test_policy_parsing_and_validation():
         faults.parse_sites("io.read:p=1.5", 0.1)
     with pytest.raises(ValueError):
         faults.parse_sites("io.read:sometimes", 0.1)
+
+
+def test_every_known_site_is_injected_somewhere():
+    """A site outlives what it guarded when only the registry and the
+    conf's description still name it (`compile.cache_load` did, until
+    the artifact load it guarded went)."""
+    import spark_rapids_tpu
+
+    pkg = os.path.dirname(spark_rapids_tpu.__file__)
+    skip = {os.path.join(pkg, "runtime", "faults.py"),
+            os.path.join(pkg, "config", "rapids_conf.py")}
+    text = ""
+    for d, _, names in os.walk(pkg):
+        for n in names:
+            path = os.path.join(d, n)
+            if n.endswith(".py") and path not in skip:
+                with open(path) as f:
+                    text += f.read()
+    unused = [s for s in faults.KNOWN_SITES
+              if f'"{s}"' not in text and f"'{s}'" not in text]
+    assert not unused
+    assert "compile.cache_load" not in faults.KNOWN_SITES
+    assert len(faults.KNOWN_SITES) == 23
 
 
 def test_registry_determinism_per_site():
@@ -254,39 +277,6 @@ def test_reader_missing_file_fails_fast(tmp_path):
     with pytest.raises(FileNotFoundError):
         list(readers.read_parquet_task(
             [str(tmp_path / "nope.parquet")], None, 128))
-
-
-# ------------------------------------- compile-cache artifact domain
-
-def test_corrupt_artifact_quarantined_as_cache_miss(tmp_path,
-                                                    monkeypatch):
-    from spark_rapids_tpu.runtime import compile_cache as cc
-
-    monkeypatch.setattr(cc, "_configured_dir", str(tmp_path))
-    os.makedirs(tmp_path / "artifacts")
-    digest = "d" * 32
-    (tmp_path / "artifacts" / f"{digest}.key").write_text("('k',)")
-    (tmp_path / "artifacts" / f"{digest}.bin").write_bytes(
-        b"\x00truncated-garbage")
-    before = cc.stats.snapshot()["artifactsQuarantined"]
-    assert cc._load_artifact(digest, "('k',)") is None  # miss, no raise
-    assert cc.stats.snapshot()["artifactsQuarantined"] == before + 1
-    names = os.listdir(tmp_path / "artifacts")
-    assert f"{digest}.bin.quarantine" in names
-    assert f"{digest}.bin" not in names
-    # quarantined entry does not resurrect: a second load is a plain
-    # miss (FileNotFoundError path), not another quarantine
-    assert cc._load_artifact(digest, "('k',)") is None
-    assert cc.stats.snapshot()["artifactsQuarantined"] == before + 1
-
-
-def test_injected_cache_load_fault_is_cache_miss(tmp_path, monkeypatch):
-    from spark_rapids_tpu.runtime import compile_cache as cc
-
-    monkeypatch.setattr(cc, "_configured_dir", str(tmp_path))
-    os.makedirs(tmp_path / "artifacts")
-    _arm("compile.cache_load:once")
-    assert cc._load_artifact("e" * 32, "('x',)") is None
 
 
 # -------------------------------------------------- semaphore domain

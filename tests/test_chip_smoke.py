@@ -59,9 +59,7 @@ def test_phases_on_cpu_match_pyarrow(data, capsys):
     for q in queries:
         assert q["engine"] == "fused" and q["correct"] is True, q
         comp = q["compile"]
-        assert comp["artifactsQuarantined"] == 0, q
-        assert comp["programsCompiled"] + comp["cacheHits"] \
-            + comp["warmHits"] > 0, q
+        assert comp["programsCompiled"] + comp["cacheHits"] > 0, q
     # the uncached query really uploaded the fact columns it reads
     assert queries[0]["bytesMoved"]["h2d"] >= ROWS * (8 + 1 + 2)
     served = [ln for ln in lines if ln["phase"] == "served"]

@@ -1,11 +1,12 @@
 """Persistent cross-process compilation layer
-(runtime/compile_cache.py) + fused variant dedup (exec/fused.py
-run_program canonical keys): the round-5 cold-start killer.
+(runtime/compile_cache.py: jax's disk cache and nothing beside it) +
+fused variant dedup (exec/fused.py run_program canonical keys): the
+round-5 cold-start killer.
 
-Covers the acceptance surface: cross-process executable reuse, warmup
-serving, version-skew invalidation, digest-collision safety, concurrent
-writers, per-query compile metrics, and the canonical-key dedup that
-stops expansion retries / re-lowerings / the ANSI channel from
+Covers the acceptance surface: cross-process executable reuse, a
+changed lowering under an unchanged key, no thread and no file of the
+engine's own, per-query compile metrics, and the canonical-key dedup
+that stops expansion retries / re-lowerings / the ANSI channel from
 recompiling the whole pipeline."""
 
 import json
@@ -51,7 +52,6 @@ def cache_session(tmp_path):
     cc.reset_for_tests()
     s = TpuSparkSession({
         "spark.rapids.tpu.compileCache.dir": str(tmp_path / "cache"),
-        "spark.rapids.tpu.compileCache.warmup.enabled": False,
     })
     yield s
     s.stop()
@@ -147,10 +147,7 @@ def test_ansi_flag_without_checks_shares_programs(tmp_path):
     jit_cache.clear()
     cc.reset_for_tests()
     cache = str(tmp_path / "cache")
-    base_conf = {
-        "spark.rapids.tpu.compileCache.dir": cache,
-        "spark.rapids.tpu.compileCache.warmup.enabled": False,
-    }
+    base_conf = {"spark.rapids.tpu.compileCache.dir": cache}
     t = pa.table({"k": pa.array(np.arange(512) % 7, type=pa.int64()),
                   "v": pa.array(np.arange(512, dtype=np.float64))})
 
@@ -194,25 +191,17 @@ def test_shape_bucketing_shares_programs_across_similar_sizes():
         assert n <= cap <= int(n * 1.126) + (1 << 16), (n, cap)
 
 
-# ------------------------------------------- cross-process + warmup
+# ---------------------------------------------------- cross-process
 
 _PROC_SCRIPT = textwrap.dedent("""
-    import json, sys, time
+    import json, sys
     import jax; jax.config.update("jax_platforms", "cpu")
     import numpy as np, pyarrow as pa
     from spark_rapids_tpu.api.session import TpuSparkSession
     from spark_rapids_tpu.api import functions as F
-    from spark_rapids_tpu.runtime import compile_cache as cc
 
-    cache_dir, warm = sys.argv[1], sys.argv[2] == "warm"
-    s = TpuSparkSession({
-        "spark.rapids.tpu.compileCache.dir": cache_dir,
-        "spark.rapids.tpu.compileCache.warmup.enabled": warm,
-        # tiny test programs must still export warmup artifacts
-        "spark.rapids.tpu.compileCache.artifact.minCompileSecs": 0.0,
-    })
-    if warm:
-        cc.warmup_join(120)
+    cache_dir, mode = sys.argv[1], sys.argv[2]
+    s = TpuSparkSession({"spark.rapids.tpu.compileCache.dir": cache_dir})
     t = pa.table({"k": pa.array(np.arange(2000) % 11,
                                 type=pa.int64()),
                   "v": pa.array(np.arange(2000, dtype=np.float64))})
@@ -220,122 +209,163 @@ _PROC_SCRIPT = textwrap.dedent("""
            .groupBy("k").agg(F.sum("v").alias("s"))
            .collect_arrow())
     total = sum(out.column("s").to_pylist())
-    cc.flush()
     print(json.dumps({"engine": s.last_execution["engine"],
                       "compile": s.last_execution["compile"],
-                      "total": total}))
+                      "total": total}), flush=True)
+    if mode == "stop":
+        s.stop()
+    # mode "leave": the interpreter exits with the session open
+""")
+
+# one structural key, two lowerings: what a perf PR on a lowering does
+# to a directory that has seen its parent
+_STALE_SCRIPT = textwrap.dedent("""
+    import sys
+    import jax; jax.config.update("jax_platforms", "cpu")
+    import jax.numpy as jnp
+    from spark_rapids_tpu.api.session import TpuSparkSession
+    from spark_rapids_tpu.runtime import jit_cache
+
+    cache_dir, lowering = sys.argv[1], sys.argv[2]
+    s = TpuSparkSession({"spark.rapids.tpu.compileCache.dir": cache_dir})
+
+    def program(x):
+        return x + 1 if lowering == "parent" else x * 2
+
+    program.__name__ = program.__qualname__ = "fused_chain_00000000"
+    fn = jit_cache.cached_jit(("fused", "chain", "one-key"),
+                              lambda: program)
+    print("answer", int(fn(jnp.arange(8, dtype=jnp.int32))[3]), flush=True)
     s.stop()
 """)
 
 
-def _run_proc(cache_dir: str, mode: str) -> dict:
+def _run_script(script: str, *argv: str) -> str:
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=REPO + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     r = subprocess.run(
-        [sys.executable, "-c", _PROC_SCRIPT, cache_dir, mode],
+        [sys.executable, "-c", script, *argv],
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
-    assert r.returncode == 0, r.stderr[-2000:]
-    line = [l for l in r.stdout.splitlines() if l.startswith("{")][-1]
-    return json.loads(line)
+    assert r.returncode == 0, (r.returncode, r.stderr[-2000:])
+    return r.stdout
 
 
-@pytest.mark.slow
-def test_cross_process_warm_start(tmp_path):
-    """The tentpole end-to-end: process 1 compiles cold and persists;
-    process 2 (fresh interpreter, warmup on) serves every fused
-    program from artifacts — zero XLA compile seconds — and produces
-    identical results."""
-    cache = str(tmp_path / "xproc")
-    cold = _run_proc(cache, "cold")
+def _run_proc(cache_dir: str, mode: str) -> dict:
+    out = _run_script(_PROC_SCRIPT, cache_dir, mode)
+    return json.loads(
+        [ln for ln in out.splitlines() if ln.startswith("{")][-1])
+
+
+@pytest.fixture(scope="module")
+def filled(tmp_path_factory):
+    """(a directory one process has compiled the query into, what that
+    process printed)."""
+    cache = str(tmp_path_factory.mktemp("xproc"))
+    cold = _run_proc(cache, "stop")
     assert cold["engine"] == "fused"
     assert cold["compile"]["programsCompiled"] > 0
-    assert cold["compile"]["warmHits"] == 0
+    assert cold["compile"]["xlaCacheMisses"] > 0
+    return cache, cold
 
-    warm = _run_proc(cache, "warm")
+
+def test_second_process_loads_every_program_from_the_directory(filled):
+    """Process 2 (fresh interpreter) traces the same programs and jax's
+    cache serves every executable: no XLA compile, the same answer."""
+    cache, cold = filled
+    warm = _run_proc(cache, "stop")
     assert warm["engine"] == "fused"
-    assert warm["total"] == cold["total"]  # warm executables correct
-    assert warm["compile"]["programsCompiled"] == 0, warm
-    assert warm["compile"]["warmHits"] == \
-        cold["compile"]["programsCompiled"]
-    assert warm["compile"]["compileSeconds"] == 0.0
+    assert warm["total"] == cold["total"]
+    comp = warm["compile"]
+    assert comp["programsCompiled"] == cold["compile"]["programsCompiled"]
+    assert comp["xlaCacheMisses"] == 0, comp
+    assert comp["xlaCacheHits"] > 0, comp
 
 
-@pytest.mark.slow
-def test_version_skew_invalidates_artifacts(tmp_path):
-    """Stale-artifact invalidation: a VERSION stamp mismatch (jax or
-    plugin upgrade) wipes index + artifacts + XLA entries before any
-    program loads."""
-    cache = str(tmp_path / "skew")
-    _run_proc(cache, "cold")
-    assert os.listdir(os.path.join(cache, "index"))
-    # simulate a plugin upgrade
-    stamp = os.path.join(cache, "VERSION.json")
-    tok = json.load(open(stamp))
-    tok["plugin"] = tok["plugin"] + ".post-upgrade"
-    with open(stamp, "w") as f:
-        json.dump(tok, f)
-    again = _run_proc(cache, "warm")
-    # nothing served stale: the run recompiled from scratch
-    assert again["compile"]["warmHits"] == 0
-    assert again["compile"]["programsCompiled"] > 0
+def test_a_process_that_exits_at_once_over_a_filled_directory_returns_0(
+        filled):
+    """One query over a directory another process filled, then the end
+    of the interpreter with the session open: nothing of the engine's
+    is still compiling (the warm-up thread aborted such a process, rc
+    -6); `_run_script` asserts the return code."""
+    cache, cold = filled
+    assert _run_proc(cache, "leave")["total"] == cold["total"]
 
 
-# ------------------------------------------------- index unit layer
+def test_a_changed_lowering_under_an_unchanged_key_is_not_served_stale(
+        tmp_path):
+    """jax keys its entries on the HLO, so the engine's key names a
+    program and does not have to vouch for its lowering: the parent's
+    program under the same structural key, in the same directory, is
+    never handed to the change."""
+    cache = str(tmp_path / "c")
+    assert _run_script(_STALE_SCRIPT, cache, "parent").split()[-2:] == [
+        "answer", "4"]
+    assert _run_script(_STALE_SCRIPT, cache, "change").split()[-2:] == [
+        "answer", "6"]
 
-def test_collision_mismatch_ignores_artifact(tmp_path):
-    cc.reset_for_tests()
-    s = TpuSparkSession({
-        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "c"),
-        "spark.rapids.tpu.compileCache.warmup.enabled": False,
-    })
+
+def _files_under(root):
+    return {os.path.join(d, f): (st.st_ino, st.st_mtime_ns, st.st_size)
+            for d, _, fs in os.walk(root) for f in fs
+            for st in [os.stat(os.path.join(d, f))]}
+
+
+def test_the_third_run_of_a_query_writes_nothing_under_the_cache_root(
+        cache_session):
+    """The hot path creates, renames and touches no file: jax writes an
+    entry inside the compile that made it, and the engine writes none."""
+    s = cache_session
+    assert os.listdir(cc.cache_dir()) == ["xla"]
+    q = _mini_q5(s)
+    q.collect_arrow()
+    q.collect_arrow()
+    assert os.listdir(os.path.join(cc.cache_dir(), "xla"))
+    before = _files_under(cc.cache_dir())
+    q.collect_arrow()  # every program is resident
+    assert s.last_execution["compile"]["programsCompiled"] == 0
+    assert _files_under(cc.cache_dir()) == before
+
+
+def test_the_compile_record_has_exactly_these_fields(cache_session):
+    _mini_q5(cache_session).collect_arrow()
+    assert set(cache_session.last_execution["compile"]) == {
+        "programsCompiled", "cacheHits", "compileSeconds",
+        "xlaCacheHits", "xlaCacheMisses", "variantCount"}
+    assert set(cache_session.compile_cache_stats) == {
+        "programsCompiled", "cacheHits", "compileSeconds",
+        "xlaCacheHits", "xlaCacheMisses"}
+
+
+def test_default_session_has_no_compile_cache_thread():
+    """The default conf, not a test's: whatever the session starts, no
+    thread of the compile cache is among it."""
+    s = TpuSparkSession()
     try:
-        adir = os.path.join(cc.cache_dir(), "artifacts")
-        # a digest whose .key sidecar names a DIFFERENT structural key
-        with open(os.path.join(adir, "deadbeef.key"), "wb") as f:
-            f.write(b"('some', 'other', 'key')")
-        with open(os.path.join(adir, "deadbeef.bin"), "wb") as f:
-            f.write(b"garbage")
-        assert cc._load_artifact("deadbeef", "('the', 'real', 'key')") \
-            is None
+        assert cc.enabled()
+        assert not [t.name for t in threading.enumerate()
+                    if t.name.startswith("srtpu-compile-cache")]
     finally:
         s.stop()
-        cc.reset_for_tests()
 
 
-def test_concurrent_index_writers_never_tear(tmp_path):
-    cc.reset_for_tests()
-    s = TpuSparkSession({
-        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "c"),
-        "spark.rapids.tpu.compileCache.warmup.enabled": False,
-    })
+REMOVED_KEYS = ["spark.rapids.tpu.compileCache.warmup.enabled",
+                "spark.rapids.tpu.compileCache.warmup.topK",
+                "spark.rapids.tpu.compileCache.artifact.minCompileSecs"]
+
+
+@pytest.mark.parametrize("key", REMOVED_KEYS)
+def test_a_removed_option_is_an_unknown_key_like_any_other(key):
+    from spark_rapids_tpu.config import rapids_conf as rc
+
+    assert key not in {e.key for e in rc.conf_entries()}
+    with open(os.path.join(REPO, "docs", "configs.md")) as f:
+        assert key not in f.read()
+    s = TpuSparkSession({key: "1"})
     try:
-        digest = cc.key_digest(("t", "concurrent"))
-        errs = []
-
-        def hammer(i):
-            try:
-                for _ in range(30):
-                    cc._record_index(digest, repr(("t", "concurrent")),
-                                     "fused", 0.01, False)
-            except Exception as e:  # pragma: no cover
-                errs.append(e)
-
-        threads = [threading.Thread(target=hammer, args=(i,))
-                   for i in range(6)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert not errs
-        # the entry parses (atomic-rename discipline: no torn JSON);
-        # counts are best-effort last-writer-wins, only >= 1 guaranteed
-        idx = cc.read_index()
-        assert idx[digest]["tag"] == "fused"
-        assert idx[digest]["count"] >= 1
+        assert key in s.rapids_conf.unknown_keys
     finally:
         s.stop()
-        cc.reset_for_tests()
 
 
 def test_disabled_conf_writes_nothing(tmp_path):
@@ -367,7 +397,7 @@ def test_dir_precedence_env_then_conf_then_fixed(tmp_path, monkeypatch):
 
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
     # the variable wins, jax keeps the directory it read from it (None =
-    # this module sets no path), our layers take a sub-directory
+    # this module sets no path), the engine's root is a sub-directory
     assert cc.resolve_dirs(conf) == (str(tmp_path / "env" / "srtpu"),
                                      None)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
@@ -386,7 +416,7 @@ def test_dir_precedence_env_then_conf_then_fixed(tmp_path, monkeypatch):
 
 def test_env_dir_is_left_to_jax(tmp_path, monkeypatch):
     """With the variable set, configure() points jax nowhere else and
-    keeps index + artifacts under <dir>/srtpu."""
+    keeps the engine's root at <dir>/srtpu, empty."""
     import jax
 
     env_dir = str(tmp_path / "outside")
@@ -397,94 +427,13 @@ def test_env_dir_is_left_to_jax(tmp_path, monkeypatch):
         cc.configure()
         assert jax.config.jax_compilation_cache_dir == before
         assert cc.cache_dir() == os.path.join(env_dir, "srtpu")
-        assert sorted(os.listdir(cc.cache_dir())) == [
-            "VERSION.json", "artifacts", "index"]
+        assert os.listdir(cc.cache_dir()) == []
     finally:
         cc.reset_for_tests()
-
-
-def test_backend_change_wipes_nothing(tmp_path):
-    """A CPU rehearsal and a chip run share one directory: the stamp
-    names versions, not the backend, and a version mismatch clears the
-    engine's index + artifacts but never jax's own entries."""
-    root = str(tmp_path / "shared")
-    for sub in ("index", "artifacts", "xla"):
-        os.makedirs(os.path.join(root, sub))
-        open(os.path.join(root, sub, "entry"), "w").close()
-    assert "backend" not in cc.version_token()
-    with open(os.path.join(root, "VERSION.json"), "w") as f:
-        json.dump(cc.version_token(), f)
-    cc._check_version_stamp(root)  # same versions: nothing touched
-    assert all(os.path.exists(os.path.join(root, sub, "entry"))
-               for sub in ("index", "artifacts", "xla"))
-    with open(os.path.join(root, "VERSION.json"), "w") as f:
-        json.dump({**cc.version_token(), "jax": "0.0.1"}, f)
-    cc._check_version_stamp(root)
-    assert not os.path.exists(os.path.join(root, "index", "entry"))
-    assert not os.path.exists(os.path.join(root, "artifacts", "entry"))
-    assert os.path.exists(os.path.join(root, "xla", "entry"))
-
-
-def test_warmup_skips_other_backends_artifacts(tmp_path):
-    """An artifact another backend exported would fail to compile here
-    and be quarantined for both: warmup reads only this backend's."""
-    import jax
-
-    cc.reset_for_tests()
-    s = TpuSparkSession({
-        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "c"),
-        "spark.rapids.tpu.compileCache.warmup.enabled": False,
-    })
-    try:
-        key = repr(("fused", "other-backend"))
-        digest = cc.key_digest(("fused", "other-backend"))
-        cc._record_index(digest, key, "fused", 1.0, True)
-        entry = cc.read_index()[digest]
-        assert entry["backend"] == jax.default_backend()
-        entry["backend"] = "tpu"
-        with open(cc._index_path(digest), "w") as f:
-            json.dump(entry, f)
-        adir = os.path.join(cc.cache_dir(), "artifacts")
-        with open(os.path.join(adir, digest + ".key"), "w") as f:
-            f.write(key)
-        with open(os.path.join(adir, digest + ".bin"), "wb") as f:
-            f.write(b"an export for another platform")
-        before = cc.stats.snapshot()["artifactsQuarantined"]
-        cc._warmup_run(top_k=8)
-        assert cc.warm_count() == 0
-        assert cc.stats.snapshot()["artifactsQuarantined"] == before
-        assert os.path.exists(os.path.join(adir, digest + ".bin"))
-    finally:
-        s.stop()
-        cc.reset_for_tests()
-
-
-def test_warm_executable_that_fails_is_rebuilt_and_counted():
-    """runtime/jit_cache.py keeps a query alive when a warm artifact
-    does not run here (rebuilds live) — and now says so."""
-    import jax.numpy as jnp
-
-    from spark_rapids_tpu.runtime import jit_cache
-
-    key = ("fused", "warm-rebuild-test")
-
-    def broken(*_a, **_k):
-        raise TypeError("aval drift")
-
-    with cc._warm_lock:
-        cc._warm[repr(key + jit_cache._env_token())] = broken
-    before = cc.stats.snapshot()
-    fn = jit_cache.cached_jit(key, lambda: (lambda x: x + 1))
-    assert int(fn(jnp.int32(41))) == 42
-    assert int(fn(jnp.int32(1))) == 2  # second call: no warm retry
-    after = cc.stats.snapshot()
-    assert after["warmRebuilds"] == before["warmRebuilds"] + 1
-    assert after["warmHits"] == before["warmHits"] + 1
-    assert after["programsCompiled"] == before["programsCompiled"] + 1
 
 
 def test_xla_disk_cache_hits_are_counted(cache_session):
-    """Layer 1 reports itself: a structurally identical program built
+    """jax's cache reports itself: a structurally identical program built
     again in this process after the in-memory caches are dropped is an
     XLA disk HIT, not a compile."""
     import jax
@@ -500,76 +449,6 @@ def test_xla_disk_cache_hits_are_counted(cache_session):
     again = cache_session.last_execution["compile"]
     assert again["programsCompiled"] == first["programsCompiled"]
     assert again["xlaCacheHits"] >= first["programsCompiled"]
-
-
-def test_export_failure_is_counted_not_silent(cache_session, tmp_path):
-    """A fused program jax.export cannot serialize stays index-only —
-    and the ledger says so. Programs over dictionary-encoded columns
-    are such programs today (DeviceDictionary has no export
-    serialization): every program of the bench's q5 and dup-key join."""
-    import pyarrow.parquet as pq
-
-    import spark_rapids_tpu.config.rapids_conf as rc
-
-    pq.write_table(pa.table({
-        "store": pa.array(np.arange(50), type=pa.int64()),
-        "region": pa.array([f"r{i % 4}" for i in range(50)]),
-    }), str(tmp_path / "dim.parquet"), use_dictionary=["region"])
-    dim = cache_session.read.parquet(str(tmp_path / "dim.parquet"))
-    cc._artifact_min_s = 0.0
-    try:
-        before = cc.stats.snapshot()["artifactExportFailures"]
-        dim.groupBy("region").agg(F.count("*").alias("n")).collect_arrow()
-        cc.flush()
-        failed = cc.stats.snapshot()["artifactExportFailures"] - before
-        index = cc.read_index()
-        without = [e for e in index.values()
-                   if e["tag"] == "fused" and not e["artifact"]]
-        assert failed == len(without) > 0, (failed, index)
-    finally:
-        cc._artifact_min_s = rc.COMPILE_CACHE_ARTIFACT_MIN_S.default
-
-
-def test_a_warm_artifact_carries_its_programs_name(tmp_path):
-    """A fused program is one XLA module name in the device trace
-    whoever built it: the index records the traced function's name,
-    and warm-up compiles the loaded artifact under it (an entry from
-    before there were names is left to be built live once more)."""
-    from spark_rapids_tpu.runtime import jit_cache
-
-    jit_cache.clear()
-    cc.reset_for_tests()
-    s = TpuSparkSession({
-        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "c"),
-        "spark.rapids.tpu.compileCache.warmup.enabled": False,
-        "spark.rapids.tpu.compileCache.artifact.minCompileSecs": 0.0,
-    })
-    try:
-        _mini_q5(s).collect_arrow()
-        assert s.last_execution["engine"] == "fused"
-        cc.flush()
-        served = {d: e for d, e in cc.read_index().items()
-                  if e.get("artifact")}
-        assert served
-        for e in served.values():
-            assert e["tag"] == "fused" and e["name"].startswith("fused_")
-        old = sorted(served)[0]  # one entry forgets its name
-        entry = dict(served[old])
-        del entry["name"]
-        with open(cc._index_path(old), "w") as f:
-            json.dump(entry, f)
-        cc._warmup_run(top_k=64)
-        assert cc.warm_count() == len(served) - 1
-        with cc._warm_lock:
-            warm = dict(cc._warm)
-        assert served[old]["key"] not in warm
-        for d, e in served.items():
-            if d != old:
-                assert f"jit_{e['name']}" in warm[e["key"]].as_text()
-    finally:
-        s.stop()
-        cc.reset_for_tests()
-        jit_cache.clear()
 
 
 # ------------------------- a keyless aggregate's programs are new keys
@@ -614,14 +493,6 @@ def _dispatched(query, spark, dirs, cached=False):
                  if sp.name == "fused.dispatch"}
 
 
-def _without_dense(key):
-    """The structural key the parent gave the same program: the
-    aggregate's entry without its lowering."""
-    if isinstance(key, tuple):
-        return tuple(_without_dense(k) for k in key if k != "dense")
-    return key
-
-
 def test_keyless_programs_get_new_names_and_keyed_keep_theirs(
         lineitem_100k):
     dirs, conf = lineitem_100k
@@ -638,68 +509,53 @@ def test_keyless_programs_get_new_names_and_keyed_keep_theirs(
     assert q1 == Q1_NAMES
 
 
-def test_an_entry_under_the_parents_key_is_not_served(
-        lineitem_100k, tmp_path):
-    """A cache directory that has seen the parent holds Q6's scatter
-    programs under the parent's keys. Re-file this run's artifacts
-    under exactly those keys (their names come out as the ledger's),
-    warm them up, and run Q6 again: none is taken."""
-    import ast
-    import shutil
+# ------------- every cell's programs come back from the disk, by name
 
-    from spark_rapids_tpu.exec.fused import program_name
+@pytest.mark.parametrize("name", ["tpch_q1_resident",
+                                  "tpch_q6_scan_uncached",
+                                  "tpch_q12_join_resident"],
+                         ids=["tpch_q1", "tpch_q6", "tpch_q12"])
+def test_a_second_session_builds_a_cells_programs_from_the_disk_cache(
+        name, tmp_path):
+    """The one builder left, on each cell's query as `benchmark/run.py`
+    builds it: after the in-process caches are dropped, a new session
+    over the same directory traces every program again under the name
+    it had, and XLA compiles none of them."""
+    import jax
+
+    from benchmark import run
     from spark_rapids_tpu.runtime import jit_cache
 
-    dirs, conf = lineitem_100k
-    conf = dict(conf, **{
-        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "c"),
-        "spark.rapids.tpu.compileCache.warmup.enabled": False,
-        "spark.rapids.tpu.compileCache.artifact.minCompileSecs": 0.0})
-    jit_cache.clear()
-    cc.reset_for_tests()
-    s = TpuSparkSession(conf)
-    try:
-        first, _ = _dispatched("tpch_q6", s, dirs)
-        cc.flush()
-        adir = os.path.join(cc.cache_dir(), "artifacts")
-        refiled, old_keys = set(), set()
-        for digest, e in cc.read_index().items():
-            key = ast.literal_eval(e["key"])
-            if e["tag"] != "fused" or "dense" not in e["key"]:
-                continue
-            assert e["artifact"], e
-            old = _without_dense(key)
-            old_repr, old_digest = repr(old), cc.key_digest(old)
-            name = program_name(old[1], old[2])
-            refiled.add(name)
-            old_keys.add(old_repr)
-            shutil.move(os.path.join(adir, digest + ".bin"),
-                        os.path.join(adir, old_digest + ".bin"))
-            os.remove(os.path.join(adir, digest + ".key"))
-            with open(os.path.join(adir, old_digest + ".key"), "w") as f:
-                f.write(old_repr)
-            os.remove(cc._index_path(digest))
-            with open(cc._index_path(old_digest), "w") as f:
-                json.dump(dict(e, key=old_repr, name=name), f)
-        assert refiled == Q6_SCATTER
-    finally:
-        s.stop()
-        cc.reset_for_tests()
+    cell = run.load_cell(name)
+    gen = run.load_module("datagen", cell["config"]["generator"])
+    dirs = gen.generate(cell["config"], 2_147_483_693,
+                        str(tmp_path / "data"), rows=40_000)
+    conf = dict(cell["config"]["session_conf"], **{
+        "spark.rapids.sql.reader.coalesceSizeBytes": 1,
+        "spark.rapids.tpu.compileCache.dir": str(tmp_path / "cache")})
+    (query,) = cell["traffic"]["queries"]
+    cached = "device" in cell["traffic"]["tables"].values()
+
+    def one_session():
+        _abandoned_attempts_done()
         jit_cache.clear()
-    s = TpuSparkSession(conf)
-    try:
-        cc._warmup_run(top_k=64)
-        with cc._warm_lock:
-            assert old_keys <= set(cc._warm)
-        others = cc.warm_count() - len(old_keys)  # collect1: same key
-        again, names = _dispatched("tpch_q6", s, dirs)
-        assert not names & Q6_SCATTER
-        assert s.last_execution["compile"]["warmHits"] == others
-        assert s.last_execution["compile"]["programsCompiled"] >= 2
-        with cc._warm_lock:  # offered, and left alone
-            assert set(cc._warm) == old_keys
-        assert again.to_pylist() == first.to_pylist()
-    finally:
-        s.stop()
+        jax.clear_caches()
         cc.reset_for_tests()
-        jit_cache.clear()
+        s = TpuSparkSession(conf)
+        try:
+            before = cc.stats.snapshot()
+            out, names = _dispatched(query, s, dirs, cached=cached)
+            # the device cache's fill compiles too: the whole session
+            return out, names, cc.stats.delta(before, cc.stats.snapshot())
+        finally:
+            s.stop()
+            cc.reset_for_tests()
+
+    first, names, cold = one_session()
+    assert cold["programsCompiled"] > 0 and cold["xlaCacheMisses"] > 0
+    again, names_again, warm = one_session()
+    assert names_again == names and len(names) >= 3
+    assert warm["programsCompiled"] == cold["programsCompiled"]
+    assert warm["xlaCacheMisses"] == 0, warm
+    assert warm["xlaCacheHits"] >= warm["programsCompiled"]
+    assert again.to_pylist() == first.to_pylist()

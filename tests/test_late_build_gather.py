@@ -3,13 +3,11 @@ matched (exec/fused.py `lookup_join`, `build_table`; ops/joinops.py
 `build_index`, `BuildIndex`, `rows_at`): "matched", and "none" where
 the join reads no build column, equal a plain Python join and what
 `build_side`'s sorted batch gives, whatever the shapes; the program
-keys carry the mark; the index passes through jax.export as the
-chain's build input."""
+keys carry the mark."""
 
 import collections
 import os
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
@@ -242,8 +240,9 @@ def keyed(monkeypatch):
 @pytest.mark.parametrize("how", ["inner", "left_semi"])
 def test_program_keys_carry_what_the_join_reads(spark, keyed, tmp_path, how):
     """The parent's `buildprep` made a sorted batch under the key
-    without the mark: an artifact cached under that key, or under the
-    chain's, must not be offered to these programs."""
+    without the mark: the mark names these programs apart from it
+    (`fused_buildprep_<digest>`, `fused_chain_<digest>` in the trace
+    and the ledger's breakdown)."""
     probe, halves = tables(0)
     query(spark, probe, halves, how, at=tmp_path).collect_arrow()
     gather = the_join(spark)["buildGather"]
@@ -325,48 +324,3 @@ def test_build_index_leaves_the_batch_and_returns_the_sorts_own_keys(
                               np.asarray(up_front.data)[hit])
         assert np.array_equal(np.asarray(late.validity)[hit],
                               np.asarray(up_front.validity)[hit])
-
-
-@pytest.mark.parametrize("reads", [True, False],
-                         ids=["matched", "none"])
-def test_build_index_passes_through_export_as_input_and_output(reads):
-    """What buildprep returns and the chains take (runtime/
-    compile_cache.py `_register_export_serialization`)."""
-    import jax.export as jex
-
-    from spark_rapids_tpu.runtime import compile_cache as cc
-
-    cc._register_export_serialization()
-    probe, halves = tables(2 ** 33)
-    # plain payload: a dictionary has no export serialization yet
-    # (tests/test_compile_cache.py: counted, index-only)
-    build = arrow_to_device(pa.concat_tables(halves).select(["bk", "bv"]))
-    pb = arrow_to_device(probe.select(["k"]))
-
-    def prep(batch):
-        idx = joinops.build_index(batch, [0])
-        return idx if reads else idx._replace(batch=None, perm=None)
-
-    def chain(bt, probe):
-        lo, matched, _ = joinops.probe_unique(bt, probe, [0])
-        if bt.batch is None:
-            return matched
-        rows = jnp.take(bt.perm, jnp.clip(lo, 0, bt.capacity - 1))
-        return bt.batch.columns[1].gather(rows).data, matched
-
-    def round_trip(fn, *args):
-        avals = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-        blob = jex.export(jax.jit(fn))(*avals).serialize()
-        return jex.deserialize(blob).call(*args)
-
-    idx = round_trip(prep, build)
-    assert isinstance(idx, joinops.BuildIndex)
-    assert (idx.batch is None, idx.perm is None) == (not reads, not reads)
-    want = jax.jit(chain)(prep(build), pb)
-    got = round_trip(chain, idx, pb)
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-    matched = np.asarray(got if not reads else got[1])
-    assert 0 < matched.sum() < PROBE_ROWS

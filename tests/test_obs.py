@@ -318,8 +318,7 @@ def test_robustness_metrics_keys_unchanged():
         rm = s.robustness_metrics
         assert set(rm) == {"chaos", "retries", "shuffle", "scheduler",
                            "degrade", "admission", "sanitizer",
-                           "device", "spill",
-                           "artifactsQuarantined", "semaphoreTimeouts"}
+                           "device", "spill", "semaphoreTimeouts"}
         assert "queriesAdmitted" in rm["admission"]
         assert {"epoch", "fences", "recoveries"} <= set(rm["device"])
         assert "orphanedFilesSwept" in rm["spill"]
