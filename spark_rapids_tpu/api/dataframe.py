@@ -669,7 +669,8 @@ class DataFrame:
         from spark_rapids_tpu.runtime import admission
 
         rec = {"engine": None, "fallbacks": [], "compile": None,
-               "degradations": [], "scheduler": None, "join": None}
+               "degradations": [], "scheduler": None, "join": None,
+               "agg": None}
         self._last_exec = rec
         self.session.last_execution = rec
         # admission front door (runtime/admission.py): the OUTERMOST
@@ -955,6 +956,7 @@ class DataFrame:
                         rec["_fused_variants"] = \
                             ex.last_compile_metrics["variantCount"]
                     rec["join"] = ex.last_join_metrics
+                    rec["agg"] = ex.last_agg_metrics
                     breaker.record_success(fkey)
                     return ran("fused", out)
                 except FusedCompileError as e:

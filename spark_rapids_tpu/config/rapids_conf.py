@@ -168,7 +168,9 @@ AGG_MATMUL_CHUNK_ROWS = conf(
     "Rows per matmul-reduction chunk (the lax.scan step). Smaller "
     "chunks tighten f32 accumulation error and int-exactness bounds "
     "at more scan iterations. Must stay below 2^24: per-chunk counts "
-    "accumulate exactly in f32 only up to that.", int,
+    "accumulate exactly in f32 only up to that. A sweep that holds an "
+    "integer sum in 8-bit limbs uses at most 65793 rows a chunk "
+    "(255 x chunk < 2^24), whatever this says.", int,
     checker=lambda v: 1024 <= v < (1 << 24))
 SKEW_JOIN_ENABLED = conf(
     "spark.sql.adaptive.skewJoin.enabled", True,
@@ -200,7 +202,9 @@ AGG_MATMUL_ENABLED = conf(
     "spark.rapids.sql.agg.matmulSegments.enabled", True,
     "Lower binned group-by reductions to one-hot matmuls on the MXU "
     "instead of scatter-adds (XLA:TPU serializes scatters; measured "
-    "~25x on v5e). Counts and vrange-bounded integer sums stay exact; "
+    "~25x on v5e). Counts and integer sums stay exact (one weight "
+    "vector under a tight vrange, one per byte of the column's width "
+    "otherwise); "
     "float sums accumulate f32 chunk partials into an f64 carry "
     "(within the documented v5e f64-at-f32-precision stance).", bool)
 FILECACHE_ENABLED = conf(

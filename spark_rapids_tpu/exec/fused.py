@@ -39,6 +39,7 @@ out-of-core engine, which remains the path for HBM-exceeding inputs.
 
 from __future__ import annotations
 
+import collections
 import functools
 import hashlib
 import os
@@ -364,6 +365,11 @@ class FusedSingleChipExecutor:
         #: where the plan has none: session.last_execution["join"]
         self.last_join_metrics = None
         self._run_joins: List[dict] = []
+        #: how the settled run's partial aggregates lowered their sums,
+        #: summed over its programs ({"limbs": 16, ...}), or None where
+        #: it summed nothing: session.last_execution["agg"]
+        self.last_agg_metrics = None
+        self._run_agg = collections.Counter()
 
     # --- source preparation (once; survives expansion retries) ---
 
@@ -585,6 +591,7 @@ class FusedSingleChipExecutor:
                        (expansion, group_cap, use_lookup,
                         use_pushdown))
                 self._record_joins(reruns)
+                self.last_agg_metrics = dict(self._run_agg) or None
                 return out
             except SurvivorOverflow as e:
                 self._wide_joins.update(e.joins)
@@ -646,6 +653,8 @@ class FusedSingleChipExecutor:
                 lambda: self._run_with_retry(phys, as_parts)[0])
             if self.last_join_metrics is not None:
                 sp.set(join=self.last_join_metrics)
+            if self.last_agg_metrics is not None:
+                sp.set(agg=self.last_agg_metrics)
             return out
 
     def _oom_injection_eager_fallback(self, phys: PhysicalPlan):
@@ -784,6 +793,7 @@ class FusedSingleChipExecutor:
         # the parts; `buildRows` holds device scalars until the fetch
         joins: Dict[tuple, dict] = {}
         self._run_joins = []
+        self._run_agg = collections.Counter()
         ansi_on = self._ansi
         # ANSI checks see pre-join row visibility; the pushdown's
         # pre-aggregate would evaluate agg inputs on probe rows the
@@ -874,6 +884,12 @@ class FusedSingleChipExecutor:
             with _dm.guard("fused.dispatch", detail=str(key_tag),
                            inject=True):
                 out, fl, *rest = jitted(*inputs)
+            # how the program's partial aggregate lowered its sums was
+            # decided when it was traced, and is kept with it
+            agg = jc.sum_lowerings(key)
+            if agg:
+                sp.set(agg=agg)
+                self._run_agg.update(agg)
             # fl: scalar=[cap] | [cap, uniq, push] (chain programs), then
             # one lost-bet flag for each of `survivor_joins`, then what
             # nothing reads (joinops.rows_at)

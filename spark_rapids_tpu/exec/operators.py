@@ -1026,11 +1026,13 @@ class TpuHashAggregateExec(PhysicalPlan):
         canonical OLAP shape) plus the bin-occupancy count as ONE
         matmul sweep: each extra weight vector rides the same one-hot
         tiles (segmented._mm_pass_multi), so the whole partial costs
-        barely more than a single reduction. Returns
+        barely more than a single reduction. A sum owns one weight
+        vector or, an integer with no tight static bound, one per byte
+        of its column's width (segmented._mm_sum_plan). Returns
         (occupancy_counts, buffer_cols) or None when the shape doesn't
-        qualify (other aggregate functions, decimal128 sums, unbounded
-        int sums, or no matmul backend) — the generic per-function
-        update loop then runs instead."""
+        qualify (other aggregate functions, decimal128 sums, or no
+        matmul backend) — the generic per-function update loop then
+        runs instead."""
         from spark_rapids_tpu.expr.aggregates import Average, Count, Sum
         from spark_rapids_tpu.ops import decimal128 as d128
 
@@ -1047,7 +1049,7 @@ class TpuHashAggregateExec(PhysicalPlan):
         accs: List = []
         chunk = segmented.mm_chunk()
         guard = False
-        slots = []  # ("sum", w_i, cnt_i, out_t, out_np) | ("count", cnt_i)
+        slots = []  # ("sum", plan, w_i, cnt_i, out_t) | ("count", cnt_i)
         # Dedup count reductions on semantic identity (source column
         # index, or "live" for the bare live mask) — id() of temporary
         # arrays can alias across frees in eager execution.
@@ -1068,20 +1070,19 @@ class TpuHashAggregateExec(PhysicalPlan):
             if isinstance(fn, (Sum, Average)):
                 col = work.columns[ci]
                 valid = col.validity & live
-                out_t = fn.buffer_types()[0]
-                vb = segmented.infer_int_vbound(col)
-                data = col.data.astype(out_t.np_dtype)
-                plan = segmented._mm_sum_plan(data, valid, vb)
+                # the column's own dtype and stamp, before any cast to
+                # the sum type: the plan counts limbs from its width
+                plan = segmented._mm_sum_plan(col.data, valid, col.vrange)
                 if plan is None:
                     return None
-                w, c, acc, g = plan
-                chunk = min(chunk, c)
-                guard = guard or g
+                segmented.note_sum_lowering(plan.kind)
+                chunk = min(chunk, plan.chunk)
+                guard = guard or plan.guard
                 wi = len(weights)
-                weights.append(w)
-                accs.append(acc)
-                slots.append(("sum", wi, add_count(valid, ("col", ci)),
-                              out_t, data.dtype))
+                weights.extend(plan.weights)
+                accs.extend([plan.acc] * len(plan.weights))
+                slots.append(("sum", plan, wi, add_count(valid, ("col", ci)),
+                              fn.buffer_types()[0]))
             else:  # Count
                 if k == 0:
                     slots.append(("count", add_count(live, "live")))
@@ -1099,10 +1100,11 @@ class TpuHashAggregateExec(PhysicalPlan):
         cols: List[DeviceColumn] = []
         for slot in slots:
             if slot[0] == "sum":
-                _, wi, cnt_i, out_t, out_np = slot
+                _, plan, wi, cnt_i, out_t = slot
+                total = plan.combine(outs[wi:wi + len(plan.weights)])
                 cnt = outs[cnt_i]
                 cols.append(DeviceColumn(
-                    out_t, outs[wi].astype(out_np), cnt > 0))
+                    out_t, total.astype(out_t.np_dtype), cnt > 0))
                 cols.append(DeviceColumn(_long, cnt, ones))
             else:
                 cols.append(DeviceColumn(_long, outs[slot[1]], ones))

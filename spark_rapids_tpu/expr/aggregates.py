@@ -104,9 +104,9 @@ class Sum(AggregateFunction):
             sh, sl = d128.seg_sum128(hi, lo, valid, gid, cap)
             return [DeviceColumn(out_t, d128.join(sh, sl), cnt > 0),
                     DeviceColumn(long, cnt, jnp.ones(cnt.shape, bool))]
-        vb = segmented.infer_int_vbound(values)
-        data = values.data.astype(out_t.np_dtype)
-        s, cnt = segmented.seg_sum_count(data, valid, gid, cap, vbound=vb)
+        s, cnt = segmented.seg_sum_count(
+            values.data, valid, gid, cap, vbound=values.vrange,
+            out_dtype=out_t.np_dtype)
         return [DeviceColumn(out_t, s, cnt > 0),
                 DeviceColumn(long, cnt, jnp.ones(cnt.shape, bool))]
 

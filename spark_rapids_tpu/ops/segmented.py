@@ -107,8 +107,10 @@ def _dense(reduce, values: jnp.ndarray, valid: jnp.ndarray, ident,
 # ---- MXU segmented reductions (the binned path's hot kernels) ----
 #
 # XLA:TPU lowers scatter-add (jax.ops.segment_sum) to a serialized
-# update loop — measured ~100 ns/row on v5e, i.e. seconds per 32M-row
-# batch — while one-hot matmuls ride the MXU at >100x that rate. When
+# update loop — 62.6 ns a row on v5e for a 64-bit scatter-add of
+# 122,880 unsorted rows into 8 bins (TPC-H Q12's partial aggregate,
+# PERF.md section 6, PR 32), i.e. seconds per 32M-row batch — while
+# one-hot matmuls ride the MXU at >100x that rate. When
 # the bin count B is statically small (the binned group-by), a
 # segmented sum is an outer-product accumulation:
 #
@@ -124,7 +126,16 @@ def _dense(reduce, values: jnp.ndarray, valid: jnp.ndarray, ident,
 #   - bounded int sums: when |value| <= V (static vrange metadata from
 #     upload narrowing), a chunk of C rows sums to < V*C; choosing C
 #     with V*C <= 2^24 keeps every chunk partial exact in f32, and the
-#     i64 carry is exact. Unbounded i64 sums fall back to scatter.
+#     i64 carry is exact: ONE weight vector.
+#   - every other int sum (no vrange: a CASE, a product, a cast; or a
+#     bound too loose for a chunk of 2048 rows): the value is split by
+#     its OWN width W into W/8 limbs of 8 bits, the low ones unsigned,
+#     the top one signed (an arithmetic shift), each a weight vector of
+#     the same sweep with an i64 carry. A chunk partial is at most
+#     255*C < 2^24 (C capped at _LIMB_CHUNK), so every dot is exact,
+#     and sum_k S_k << 8k in wrapping i64 is the scatter-add's answer
+#     bit for bit, overflow wrap included: an integer sum under
+#     binned_bins never scatters.
 #   - float sums: f32 chunk partials with an f64 carry — ~1e-5
 #     relative at worst, the stance docs/compatibility.md states for
 #     THIS path only (v5e's f64 is emulated to ~1e-13 elsewhere; a
@@ -140,8 +151,34 @@ _MM_FORCE = contextvars.ContextVar("srtpu_mm_force", default=False)
 #: scatter-vs-scatter comparisons pass vacuously)
 mm_traced_sweeps = 0
 
+#: trace-time record of how each partial aggregate's Sum/Average was
+#: lowered: {"bounded" | "limbs" | "float" | "scatter": count}, kept by
+#: whoever traces a program (runtime/jit_cache.py keeps it with the
+#: program, exec/fused.py reports it as `agg`)
+_SUM_LOWERINGS = contextvars.ContextVar("srtpu_sum_lowerings", default=None)
+
+
+@contextmanager
+def noting_sum_lowerings():
+    """Collect the sum lowerings of what is traced inside."""
+    notes: dict = {}
+    tok = _SUM_LOWERINGS.set(notes)
+    try:
+        yield notes
+    finally:
+        _SUM_LOWERINGS.reset(tok)
+
+
+def note_sum_lowering(kind: str) -> None:
+    notes = _SUM_LOWERINGS.get()
+    if notes is not None:
+        notes[kind] = notes.get(kind, 0) + 1
+
+
 MM_MAX_BINS = 1 << 14
 _MM_CHUNK = 1 << 15
+#: most rows of a chunk whose 8-bit limb partials stay exact in f32
+_LIMB_CHUNK = ((1 << 24) - 1) // 255
 _MM_LIMITS = contextvars.ContextVar("srtpu_mm_limits", default=None)
 
 
@@ -192,23 +229,6 @@ def mm_bins_active() -> Optional[int]:
     """Bin count when the matmul reductions will engage (inside a
     binned_bins context on a TPU/forced backend), else None."""
     return _mm_bins()
-
-
-def infer_int_vbound(col) -> Optional[Tuple[int, int]]:
-    """Static |value| bound for a column's matmul sum plan: upload
-    vrange when stamped, else the type width for 8-bit columns (16-bit
-    widths force the chunk below _mm_sum_plan's floor, so computing
-    them is wasted). Must be taken BEFORE any cast to the i64 sum
-    dtype."""
-    vb = getattr(col, "vrange", None)
-    if vb is not None:
-        return vb
-    if (col.data.ndim == 1
-            and jnp.issubdtype(col.data.dtype, jnp.integer)
-            and col.data.dtype.itemsize == 1):
-        info = jnp.iinfo(col.data.dtype)
-        return (int(info.min), int(info.max))
-    return None
 
 
 def _mm_factors(b: int) -> Tuple[int, int]:
@@ -312,37 +332,67 @@ def _mm_seg_count(valid: jnp.ndarray, gid: jnp.ndarray,
                     jnp.int64)
 
 
-def _mm_sum_plan(values: jnp.ndarray, valid: jnp.ndarray, vbound):
-    """-> (weights_f32, chunk, acc_dtype, guard_nonfinite) for a matmul
-    segmented sum of `values`, or None when exactness cannot be
-    arranged (unbounded/loosely-bounded ints -> scatter)."""
+class _SumPlan(NamedTuple):
+    """How one segmented sum rides `_mm_pass_multi`."""
+
+    kind: str        # "float" | "bounded" | "limbs": the `agg` record's
+    weights: list    # pre-masked f32 vectors, one dot column-block each
+    chunk: int       # most rows a chunk may hold
+    acc: object      # the carry's dtype, of every vector
+    guard: bool      # guard_nonfinite
+
+    def combine(self, outs) -> jnp.ndarray:
+        """The vectors' bin sums -> the sum, in `acc`."""
+        total = outs[0]
+        for k, s in enumerate(outs[1:], 1):  # limbs: ring arithmetic
+            total = total + (s << (8 * k))
+        return total
+
+
+def _mm_sum_plan(values: jnp.ndarray, valid: jnp.ndarray,
+                 vbound) -> Optional[_SumPlan]:
+    """The matmul plan of a segmented sum of `values`, which must come
+    in the column's OWN dtype (before any cast to the sum type: an
+    integer's limbs are counted from its width). None for what is
+    neither float nor integer."""
     dt = values.dtype
+    masked = jnp.where(valid, values, 0)
     if jnp.issubdtype(dt, jnp.floating):
-        w = jnp.where(valid, values, 0).astype(jnp.float32)
-        return w, mm_chunk(), jnp.float64, True
-    if jnp.issubdtype(dt, jnp.integer):
-        if vbound is None:
-            return None  # unbounded int: scatter keeps exact wrapping
+        return _SumPlan("float", [masked.astype(jnp.float32)], mm_chunk(),
+                        jnp.float64, True)
+    if not jnp.issubdtype(dt, jnp.integer):
+        return None
+    if vbound is not None:
         v = max(abs(int(vbound[0])), abs(int(vbound[1])), 1)
         chunk = 1
         while chunk * 2 * v <= (1 << 24) and chunk < mm_chunk():
             chunk <<= 1
-        if chunk < 2048:
-            return None  # bound too loose for exact f32 chunks
-        w = jnp.where(valid, values, 0).astype(jnp.float32)
-        return w, chunk, jnp.int64, False
-    return None
+        if chunk >= 2048:  # else: too loose for exact f32 chunks
+            return _SumPlan("bounded", [masked.astype(jnp.float32)],
+                            chunk, jnp.int64, False)
+    top = dt.itemsize - 1
+    limbs = [(masked >> (8 * k)) & 0xFF for k in range(top)]
+    limbs.append(masked >> (8 * top))  # arithmetic: carries the sign
+    return _SumPlan("limbs", [b.astype(jnp.float32) for b in limbs],
+                    min(mm_chunk(), _LIMB_CHUNK), jnp.int64, False)
 
 
-def _mm_seg_sum(values: jnp.ndarray, valid: jnp.ndarray,
-                gid: jnp.ndarray, b: int,
-                vbound) -> Optional[jnp.ndarray]:
-    plan = _mm_sum_plan(values, valid, vbound)
-    if plan is None:
-        return None
-    w, chunk, acc, guard = plan
-    return _mm_pass(w, gid, b, chunk, acc,
-                    guard_nonfinite=guard).astype(values.dtype)
+def _mm_multi_sum(plans: Sequence[_SumPlan], gid: jnp.ndarray, b: int,
+                  counted: Optional[jnp.ndarray] = None):
+    """All of `plans` (and the count of `counted`, where given) in ONE
+    row sweep -> ([sum in its plan's acc], count or None)."""
+    ws = [w for p in plans for w in p.weights]
+    accs = [p.acc for p in plans for _ in p.weights]
+    if counted is not None:
+        ws.append(counted.astype(jnp.float32))
+        accs.append(jnp.int64)
+    outs = _mm_pass_multi(ws, gid, b, min(p.chunk for p in plans), accs,
+                          guard_nonfinite=any(p.guard for p in plans))
+    sums, at = [], 0
+    for p in plans:
+        sums.append(p.combine(outs[at:at + len(p.weights)]))
+        at += len(p.weights)
+    return sums, (outs[-1] if counted is not None else None)
 
 
 def dense_bin_perm(occupied: jnp.ndarray, cap: int) -> jnp.ndarray:
@@ -408,40 +458,49 @@ def seg_count(valid: jnp.ndarray, gid: jnp.ndarray, cap: int) -> jnp.ndarray:
                                indices_are_sorted=_SORTED_GIDS.get())
 
 
-def seg_sum(values: jnp.ndarray, valid: jnp.ndarray, gid: jnp.ndarray,
-            cap: int, vbound=None) -> jnp.ndarray:
-    if _ONE_SEGMENT.get():
-        return _dense(jnp.sum, values, valid, 0, cap)
-    b = _mm_bins()
-    if b is not None and b <= cap and values.ndim == 1:
-        r = _mm_seg_sum(values, valid, gid, b, vbound)
-        if r is not None:
-            return _pad_bins(r, cap)
-    zero = jnp.zeros((), dtype=values.dtype)
-    return jax.ops.segment_sum(jnp.where(valid, values, zero), gid,
-                               num_segments=cap,
-                               indices_are_sorted=_SORTED_GIDS.get())
-
-
-def seg_sum_count(values: jnp.ndarray, valid: jnp.ndarray,
-                  gid: jnp.ndarray, cap: int, vbound=None
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(segmented sum, segmented count) of the same masked rows. On the
-    matmul path both ride ONE row sweep (`_mm_pass_multi`) — the
-    aggregate functions that need sum+count (Sum's null tracking,
-    Average) should call this instead of seg_sum + seg_count."""
+def _seg_sum(values, valid, gid, cap: int, vbound, out_dtype,
+             with_count: bool):
+    """-> (sum, count or None, how the sum was lowered: a `_SumPlan`
+    kind, "scatter", or None for one segment's dense reduce)."""
+    out_dtype = out_dtype or values.dtype
     b = _mm_bins()
     if b is not None and b <= cap and values.ndim == 1:
         plan = _mm_sum_plan(values, valid, vbound)
         if plan is not None:
-            w, chunk, acc, guard = plan
-            s, c = _mm_pass_multi(
-                [w, valid.astype(jnp.float32)], gid, b, chunk,
-                [acc, jnp.int64], guard_nonfinite=guard)
-            return (_pad_bins(s.astype(values.dtype), cap),
-                    _pad_bins(c, cap))
-    return (seg_sum(values, valid, gid, cap, vbound),
-            seg_count(valid, gid, cap))
+            (s,), c = _mm_multi_sum([plan], gid, b,
+                                    valid if with_count else None)
+            return (_pad_bins(s.astype(out_dtype), cap),
+                    _pad_bins(c, cap) if with_count else None, plan.kind)
+    values = values.astype(out_dtype)
+    if _ONE_SEGMENT.get():
+        s, kind = _dense(jnp.sum, values, valid, 0, cap), None
+    else:
+        zero = jnp.zeros((), dtype=values.dtype)
+        s, kind = jax.ops.segment_sum(
+            jnp.where(valid, values, zero), gid, num_segments=cap,
+            indices_are_sorted=_SORTED_GIDS.get()), "scatter"
+    return s, (seg_count(valid, gid, cap) if with_count else None), kind
+
+
+def seg_sum(values: jnp.ndarray, valid: jnp.ndarray, gid: jnp.ndarray,
+            cap: int, vbound=None, out_dtype=None) -> jnp.ndarray:
+    """Segmented sum in `out_dtype` (default: the values' own). Hand an
+    integer column over in its OWN dtype, with its `vrange` as `vbound`
+    and the sum type as `out_dtype`: the matmul plan reads all three."""
+    return _seg_sum(values, valid, gid, cap, vbound, out_dtype, False)[0]
+
+
+def seg_sum_count(values: jnp.ndarray, valid: jnp.ndarray,
+                  gid: jnp.ndarray, cap: int, vbound=None, out_dtype=None
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(segmented sum, segmented count) of the same masked rows, as an
+    aggregate's Sum/Average needs them (the count tracks nulls): on the
+    matmul path both ride ONE row sweep (`_mm_pass_multi`), and how the
+    sum was lowered is noted (`noting_sum_lowerings`)."""
+    s, c, kind = _seg_sum(values, valid, gid, cap, vbound, out_dtype, True)
+    if kind:
+        note_sum_lowering(kind)
+    return s, c
 
 
 def seg_multi_sum(values_list, valid: jnp.ndarray, gid: jnp.ndarray,
@@ -455,19 +514,11 @@ def seg_multi_sum(values_list, valid: jnp.ndarray, gid: jnp.ndarray,
             and all(v.ndim == 1 for v in values_list)):
         plans = [_mm_sum_plan(v, valid, None) for v in values_list]
         if all(p is not None for p in plans):
-            ws = [p[0] for p in plans]
-            accs = [p[2] for p in plans]
-            chunk = min(p[1] for p in plans)
-            guard = any(p[3] for p in plans)
-            if with_count:
-                ws.append(valid.astype(jnp.float32))
-                accs.append(jnp.int64)
-            outs = _mm_pass_multi(ws, gid, b, chunk, accs,
-                                  guard_nonfinite=guard)
+            sums, cnt = _mm_multi_sum(plans, gid, b,
+                                      valid if with_count else None)
             sums = [_pad_bins(o.astype(v.dtype), cap)
-                    for o, v in zip(outs, values_list)]
-            cnt = _pad_bins(outs[-1], cap) if with_count else None
-            return cnt, sums
+                    for o, v in zip(sums, values_list)]
+            return (_pad_bins(cnt, cap) if with_count else None), sums
     cnt = seg_count(valid, gid, cap) if with_count else None
     return cnt, [seg_sum(v, valid, gid, cap) for v in values_list]
 

@@ -30,18 +30,22 @@ _lock = threading.Lock()
 _segmented_mod = None
 
 
+def _segmented():
+    global _segmented_mod
+    if _segmented_mod is None:  # lazy: segmented imports columnar.batch
+        from spark_rapids_tpu.ops import segmented
+
+        _segmented_mod = segmented
+    return _segmented_mod
+
+
 def _env_token() -> Tuple:
     """Trace-environment facts that change what a structurally identical
     program computes: the backend (kernels branch on it, e.g. the MXU
     segmented reductions) and the test-only forced-matmul flag. Part of
     every in-process key; the persistent cache (jax's) is keyed on the
     traced HLO and needs no token of ours."""
-    global _segmented_mod
-    if _segmented_mod is None:  # lazy: segmented imports columnar.batch
-        from spark_rapids_tpu.ops import segmented
-
-        _segmented_mod = segmented
-    return (jax.default_backend(), _segmented_mod._MM_FORCE.get())
+    return (jax.default_backend(), _segmented()._MM_FORCE.get())
 
 
 _device_monitor_mod = None
@@ -111,8 +115,12 @@ def _make_entry(key: Tuple, build: Callable[[], Callable],
                 from spark_rapids_tpu.obs import events as obs_events
 
                 t0 = time.perf_counter()
-                with obs_events.span("compile", kind=tag) as sp:
+                # what is decided while the program is traced and
+                # reported with every later dispatch stays with it
+                with obs_events.span("compile", kind=tag) as sp, \
+                        _segmented().noting_sum_lowerings() as noted:
                     out = jitted(*args, **kwargs)
+                    entry.sum_lowerings = noted
                     # async dispatch returns once tracing+compilation
                     # are done (execution overlaps) — the cold-start
                     # quantity
@@ -143,6 +151,14 @@ def probe(key: Tuple) -> bool:
     environment and device epoch) is already resident — per-query
     compiled-vs-hit accounting without forcing a build."""
     return _mem_key(key + _env_token()) in _cache
+
+
+def sum_lowerings(key: Tuple) -> dict:
+    """How the resident program for `key` lowered its partial
+    aggregate's sums (ops/segmented.py `noting_sum_lowerings`): taken
+    when it was traced, read by every dispatch that hits the cache."""
+    fn = _cache.get(_mem_key(key + _env_token()))
+    return getattr(fn, "sum_lowerings", {})
 
 
 def cache_size() -> int:

@@ -139,21 +139,27 @@ def test_matmul_segmented_sum_count(one_chip, as_tpu, bins):
     assert c.memory_analysis().temp_size_in_bytes < (2 << 30)
 
 
-def test_matmul_bounded_int_sum(one_chip, as_tpu):
-    """Exact int64 sums of a vrange-bounded column (qty in [0, 127])
-    ride the MXU in f32 chunks with an i64 carry."""
+@pytest.mark.parametrize("vbound", [(0, 127), None],
+                         ids=["bounded", "limbs"])
+def test_matmul_int_sum(one_chip, as_tpu, vbound):
+    """Exact int64 sums ride the MXU in f32 chunks with an i64 carry:
+    one weight vector for a vrange-bounded column (qty in [0, 127]),
+    eight 8-bit limbs for an int64 column with no bound, and no
+    scatter either way."""
     from spark_rapids_tpu.ops import segmented
 
     def kernel(values, valid, gid):
         with segmented.unsorted_gids(), segmented.binned_bins(2050):
             return segmented.seg_sum(values, valid, gid, 4096,
-                                     vbound=(0, 127))
+                                     vbound=vbound)
 
     sweeps = segmented.mm_traced_sweeps
-    _compile(kernel, _sds((ROWS,), jnp.int64, one_chip),
-             _sds((ROWS,), jnp.bool_, one_chip),
-             _sds((ROWS,), jnp.int32, one_chip))
+    c = _compile(kernel, _sds((ROWS,), jnp.int64, one_chip),
+                 _sds((ROWS,), jnp.bool_, one_chip),
+                 _sds((ROWS,), jnp.int32, one_chip))
     assert segmented.mm_traced_sweeps > sweeps, "matmul path not taken"
+    assert " scatter(" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < (2 << 30)
 
 
 @pytest.mark.parametrize("np_dtype", [jnp.float64, jnp.int64],
@@ -383,16 +389,10 @@ def q12_chain():
     return seen[-1]
 
 
-def test_q12s_own_chain_searches_keys_held_in_fast_memory(q12_chain,
-                                                          one_chip, as_tpu):
-    """The program the cell runs, lowered again at SF10's widths: its
-    search loop carries the 63 MB of sorted build keys in fast memory
-    (`S(1)`). 330 ms of a 643 ms query hang on that placement, which
-    is the compiler's to make (joinops.rows_at; PERF.md, PR 30): with
-    the keys in HBM the search takes 21 ns a slot and step, not 7.1.
-    Read on jax 0.9.0 / libtpu 0.0.34."""
-    import re
-
+@pytest.fixture(scope="module")
+def q12_chain_at_sf10(q12_chain, one_chip, no_persistent_cache):
+    """The program the cell runs, lowered again at SF10's widths and
+    compiled for the described chip: its text."""
     from spark_rapids_tpu.exec.fused import survivor_capacity
 
     fn, (probe, build) = q12_chain
@@ -411,13 +411,43 @@ def test_q12s_own_chain_searches_keys_held_in_fast_memory(q12_chain,
             lambda a: _sds(tuple(slots if d == small else d
                                  for d in a.shape), a.dtype, one_chip), tree)
 
-    c = _compile(at_sf10, widened(probe, probe.capacity, Q12_PART),
-                 widened(build, build.capacity, Q12_BUILD))
-    text = c.as_text()
-    (search,) = [ln for ln in text.splitlines()
+    with pytest.MonkeyPatch.context() as mp:  # `as_tpu`, module-wide
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        c = _compile(at_sf10, widened(probe, probe.capacity, Q12_PART),
+                     widened(build, build.capacity, Q12_BUILD))
+    assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
+    return c.as_text()
+
+
+def test_q12s_own_chain_searches_keys_held_in_fast_memory(
+        q12_chain_at_sf10):
+    """The cell's own chain program at SF10's widths: its
+    search loop carries the 63 MB of sorted build keys in fast memory
+    (`S(1)`). 330 ms of a 509 ms query hang on that placement, which
+    is the compiler's to make (joinops.rows_at; PERF.md, PR 30): with
+    the keys in HBM the search takes 21 ns a slot and step, not 7.1.
+    Read on jax 0.9.0 / libtpu 0.0.34."""
+    import re
+
+    (search,) = [ln for ln in q12_chain_at_sf10.splitlines()
                  if " while(" in ln and f"s32[{Q12_BUILD}]" in ln]
     assert re.search(rf"s32\[{Q12_BUILD}\]\{{0:T\(1024\)S\(1\)\}}", search)
-    assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
+
+
+def test_q12s_own_chain_scatters_no_row(q12_chain_at_sf10):
+    """Its partial aggregate — two `sum(case when … then 1 else 0
+    end)` into l_shipmode's bins — rides the MXU in 8-bit limbs
+    (ops/segmented.py `_mm_sum_plan`): the two 64-bit scatter-adds
+    over the survivors' 122,880 slots (61.5 ms each a query; PERF.md,
+    PR 32) are gone, and the one scatter left moves no row: it
+    brings the occupied bins to the front (`dense_bin_perm`, 1,024
+    slots of 32 bits)."""
+    scatters = [ln.split(" = ")[1] for ln in q12_chain_at_sf10.splitlines()
+                if " scatter(" in ln]
+    assert len(scatters) == 1 and scatters[0].startswith("s32[1024]")
+    # the sweep: one loop whose carries are the bins, 32,768 rows a step
+    assert [ln for ln in q12_chain_at_sf10.splitlines()
+            if " while(" in ln and "f32[4,32768]" in ln]
 
 
 def test_expanded_join_gather_maps(one_chip, as_tpu):
