@@ -16,15 +16,17 @@ from spark_rapids_tpu.expr.core import Expression
 from spark_rapids_tpu.plan import logical as L
 
 
-def _resolve(expr, schema, session=None) -> Expression:
+def _resolve(expr, schema, session=None, qualifiers=None) -> Expression:
     """Replace UnresolvedColumn markers with BoundReferences; attempt
-    UDF bytecode compilation once argument types are concrete."""
+    UDF bytecode compilation once argument types are concrete.
+    `qualifiers`: the alias each field came in under (DataFrame.alias),
+    or None."""
     if isinstance(expr, UnresolvedColumn):
-        i = _field_index(schema, expr.name)
+        i = _field_index(schema, expr.name, qualifiers)
         f = schema.fields[i]
         return BoundReference(i, f.dataType, f.nullable)
     if isinstance(expr, Expression):
-        new_children = [_resolve(c, schema, session)
+        new_children = [_resolve(c, schema, session, qualifiers)
                         for c in expr.children]
         node = expr.with_children(new_children)
         if getattr(node, "_wants_compile", False):
@@ -120,8 +122,28 @@ def _pin_query_time(plan):
     return L.transform_expressions(plan, lambda e: e.transform(efn))
 
 
-def _field_index(schema, name: str) -> int:
+def _field_index(schema, name: str, qualifiers=None) -> int:
+    """The field `name` means. `qualifiers` holds, per field, the alias
+    it came in under (DataFrame.alias) or None: `alias.field` then
+    names the field of that input, and a bare name that two aliased
+    inputs both hold is ambiguous, as in Spark. Without aliases the
+    first field of that name is meant, as before."""
     lowered = [n.lower() for n in schema.names]
+    if qualifiers is not None and any(qualifiers):
+        alias, dot, field = name.partition(".")
+        if dot and name not in schema.names:
+            hits = [i for i, (q, n) in enumerate(zip(qualifiers, lowered))
+                    if q == alias and n == field.lower()]
+            if len(hits) == 1:
+                return hits[0]
+            if hits:
+                raise ValueError(f"reference {name!r} is ambiguous")
+        hits = [i for i, n in enumerate(lowered) if n == name.lower()]
+        if len({qualifiers[i] for i in hits}) > 1:
+            raise ValueError(
+                f"reference {name!r} is ambiguous: it could be "
+                + ", ".join(f"{qualifiers[i] or '<no alias>'}.{name}"
+                            for i in hits))
     if name in schema.names:
         return schema.names.index(name)
     if name.lower() in lowered:
@@ -136,9 +158,12 @@ def _named(expr: Expression, fallback: str) -> Alias:
 
 
 class DataFrame:
-    def __init__(self, plan: L.LogicalPlan, session):
+    def __init__(self, plan: L.LogicalPlan, session, qualifiers=None):
         self._plan = plan
         self.session = session
+        # per field, the alias it came in under (`alias`), kept through
+        # joins, filters, sorts and limits; None: no field has one
+        self._qualifiers = qualifiers
 
     # --- schema ---
 
@@ -151,12 +176,33 @@ class DataFrame:
         return self._plan.schema.names
 
     def __getitem__(self, name: str) -> Column:
-        i = _field_index(self.schema, name)
+        i = _field_index(self.schema, name, self._qualifiers)
         f = self.schema.fields[i]
         ref = BoundReference(i, f.dataType, f.nullable)
         # provenance for join-condition resolution (df1.a == df2.b)
         ref._origin_plan = self._plan
-        return Column(ref, name)
+        return Column(ref, f.name if self._qualifiers else name)
+
+    def alias(self, name: str) -> "DataFrame":
+        """This frame under a name of its own: `F.col("name.field")`
+        then means its field, also after joins (`from date_dim dt`)."""
+        return DataFrame(self._plan, self.session,
+                         [name] * len(self.schema.fields))
+
+    def _quals(self) -> list:
+        return self._qualifiers or [None] * len(self.schema.fields)
+
+    def _out_name(self, c):
+        """The output name of column `c`: `alias.field` gives `field`."""
+        name = c if isinstance(c, str) else c.name
+        if (self._qualifiers and isinstance(name, str) and "." in name
+                and name not in self.columns):
+            try:
+                return self.columns[_field_index(self.schema, name,
+                                                 self._qualifiers)]
+            except KeyError:
+                pass
+        return name
 
     # --- transformations ---
 
@@ -165,7 +211,8 @@ class DataFrame:
             return _stamp_session(self[c].expr, self.session)
         if isinstance(c, Column):
             return _stamp_session(
-                _resolve(c.expr, self.schema, self.session),
+                _resolve(c.expr, self.schema, self.session,
+                         self._qualifiers),
                 self.session)
         raise TypeError(repr(c))
 
@@ -177,7 +224,7 @@ class DataFrame:
                     exprs.append(Alias(BoundReference(j, f.dataType,
                                                       f.nullable), f.name))
                 continue
-            name = c if isinstance(c, str) else c.name
+            name = self._out_name(c)
             e = self._col_expr(c)
             exprs.append(_named(e, name if isinstance(name, str)
                                 else f"col{i}"))
@@ -283,7 +330,8 @@ class DataFrame:
                 "window functions are not allowed in filter conditions; "
                 "materialize with select/withColumn first (Spark analysis "
                 "rule)")
-        return DataFrame(L.Filter(cond, self._plan), self.session)
+        return DataFrame(L.Filter(cond, self._plan), self.session,
+                         self._qualifiers)
 
     where = filter
 
@@ -342,7 +390,8 @@ class DataFrame:
 
     def crossJoin(self, other: "DataFrame") -> "DataFrame":
         return DataFrame(
-            L.Join(self._plan, other._plan, "cross", [], []), self.session)
+            L.Join(self._plan, other._plan, "cross", [], []), self.session,
+            self._joined_qualifiers(other, "cross"))
 
     def _resolve_combined(self, other: "DataFrame", e) -> Expression:
         """Resolve an expression against [left fields | right fields]:
@@ -353,11 +402,13 @@ class DataFrame:
         def go(node):
             if isinstance(node, UnresolvedColumn):
                 try:
-                    i = _field_index(self.schema, node.name)
+                    i = _field_index(self.schema, node.name,
+                                     self._qualifiers)
                     f = self.schema.fields[i]
                     return BoundReference(i, f.dataType, f.nullable)
                 except KeyError:
-                    i = _field_index(other.schema, node.name)
+                    i = _field_index(other.schema, node.name,
+                                     other._qualifiers)
                     f = other.schema.fields[i]
                     return BoundReference(n_l + i, f.dataType, f.nullable)
             if isinstance(node, BoundReference):
@@ -464,7 +515,8 @@ class DataFrame:
             jt = "cross" if not lk and remainder is None else how
             plan = L.Join(self._plan, other._plan, jt, lk, rk,
                           condition=remainder)
-            return DataFrame(plan, self.session)
+            return DataFrame(plan, self.session,
+                             self._joined_qualifiers(other, jt))
         if isinstance(on, (list, tuple)) and on and isinstance(on[0], str):
             lk = [self[c].expr for c in on]
             rk = [other[c].expr for c in on]
@@ -487,7 +539,17 @@ class DataFrame:
                     df_r = df_r.withColumn(on[i], Column(p))
             rk = [df_r[c].expr for c in on]
         plan = L.Join(df_l._plan, df_r._plan, how, lk, rk)
-        return DataFrame(plan, self.session)
+        return DataFrame(plan, self.session,
+                         self._joined_qualifiers(other, how))
+
+    def _joined_qualifiers(self, other: "DataFrame", how: str):
+        if self._qualifiers is None and other._qualifiers is None:
+            return None
+        if how in ("left_semi", "left_anti"):
+            return self._qualifiers
+        if how == "existence":
+            return self._quals() + [None]
+        return self._quals() + other._quals()
 
     def union(self, other: "DataFrame") -> "DataFrame":
         return DataFrame(L.Union([self._plan, other._plan]), self.session)
@@ -505,7 +567,8 @@ class DataFrame:
             if isinstance(c, SortColumn):
                 orders.append(L.SortOrder(
                     _stamp_session(
-                        _resolve(c.expr, self.schema, self.session),
+                        _resolve(c.expr, self.schema, self.session,
+                                 self._qualifiers),
                         self.session),
                     c.ascending, c.nulls_first))
                 continue
@@ -517,12 +580,13 @@ class DataFrame:
                     "window functions are not allowed in orderBy; "
                     "materialize with select/withColumn first")
         return DataFrame(L.Sort(orders, self._plan, global_sort=True),
-                         self.session)
+                         self.session, self._qualifiers)
 
     sort = orderBy
 
     def limit(self, n: int) -> "DataFrame":
-        return DataFrame(L.Limit(n, self._plan), self.session)
+        return DataFrame(L.Limit(n, self._plan), self.session,
+                         self._qualifiers)
 
     def distinct(self) -> "DataFrame":
         return self.groupBy(*self.columns).agg()
@@ -534,6 +598,8 @@ class DataFrame:
     # --- actions ---
 
     def _physical(self, cpu_oracle: bool = False):
+        """What the optimizer's rules did on the way (plan/optimizer.py
+        `optimize`) is left in `self._optimizer_notes`."""
         from spark_rapids_tpu.config import rapids_conf as rc
         from spark_rapids_tpu.plan.optimizer import optimize
         from spark_rapids_tpu.plan.overrides import plan_query
@@ -554,7 +620,9 @@ class DataFrame:
         # pinning may rebuild nodes, which would break identity matching
         plan = self.session.cache_manager.substitute(self._plan)
         plan = _pin_query_time(plan)
-        return plan_query(optimize(plan), self.session.rapids_conf)
+        self._optimizer_notes = {"pushedThroughJoin": 0}
+        return plan_query(optimize(plan, self._optimizer_notes),
+                          self.session.rapids_conf)
 
     # --- caching ---
     #
@@ -669,8 +737,8 @@ class DataFrame:
         from spark_rapids_tpu.runtime import admission
 
         rec = {"engine": None, "fallbacks": [], "compile": None,
-               "degradations": [], "scheduler": None, "join": None,
-               "agg": None}
+               "degradations": [], "scheduler": None, "plan": None,
+               "join": None, "agg": None, "sort": None}
         self._last_exec = rec
         self.session.last_execution = rec
         # admission front door (runtime/admission.py): the OUTERMOST
@@ -773,8 +841,11 @@ class DataFrame:
             return ran("hostCache", cached, store=False)
 
         with obs_events.span("plan") as sp:
+            self._optimizer_notes = None  # a prebuilt plan leaves none
             phys, meta = self._physical()
-            sp.set(nodes=_count_nodes(phys))
+            rec["plan"] = dict(self._optimizer_notes or {},
+                               nodes=_count_nodes(phys))
+            sp.set(**rec["plan"])
         # structured twin of the NOT_ON_TPU explain: one placement
         # event per plan node, with the verbatim fallback reason —
         # what obs.report.qualification() reads
@@ -957,6 +1028,7 @@ class DataFrame:
                             ex.last_compile_metrics["variantCount"]
                     rec["join"] = ex.last_join_metrics
                     rec["agg"] = ex.last_agg_metrics
+                    rec["sort"] = ex.last_sort_metrics
                     breaker.record_success(fkey)
                     return ran("fused", out)
                 except FusedCompileError as e:
@@ -1246,8 +1318,7 @@ class GroupedData:
         self.mode = mode
         self._user_sets = sets
         self.grouping = [
-            _named(df._col_expr(c), c if isinstance(c, str) else c.name)
-            for c in cols]
+            _named(df._col_expr(c), df._out_name(c)) for c in cols]
         from spark_rapids_tpu.sqltypes import MapType
 
         for g in self.grouping:

@@ -370,6 +370,14 @@ class FusedSingleChipExecutor:
         #: it summed nothing: session.last_execution["agg"]
         self.last_agg_metrics = None
         self._run_agg = collections.Counter()
+        #: how the settled run's programs lowered their sorts and
+        #: group-bys, one record a dispatch and sort ({"program",
+        #: "how": "packed" | "passes", "by", "operands", "keyBits",
+        #: "passes", "slots"}) under "lowerings", with the most key
+        #: operands any of them handed to `lax.sort`, or None where it
+        #: sorted nothing: session.last_execution["sort"]
+        self.last_sort_metrics = None
+        self._run_sorts: List[dict] = []
 
     # --- source preparation (once; survives expansion retries) ---
 
@@ -592,6 +600,11 @@ class FusedSingleChipExecutor:
                         use_pushdown))
                 self._record_joins(reruns)
                 self.last_agg_metrics = dict(self._run_agg) or None
+                self.last_sort_metrics = {
+                    "maxKeyOperands": max(
+                        s["operands"] for s in self._run_sorts),
+                    "lowerings": self._run_sorts,
+                } if self._run_sorts else None
                 return out
             except SurvivorOverflow as e:
                 self._wide_joins.update(e.joins)
@@ -655,6 +668,8 @@ class FusedSingleChipExecutor:
                 sp.set(join=self.last_join_metrics)
             if self.last_agg_metrics is not None:
                 sp.set(agg=self.last_agg_metrics)
+            if self.last_sort_metrics is not None:
+                sp.set(sort=self.last_sort_metrics)
             return out
 
     def _oom_injection_eager_fallback(self, phys: PhysicalPlan):
@@ -792,8 +807,10 @@ class FusedSingleChipExecutor:
         # what each join of this run did, by its plan key, summed over
         # the parts; `buildRows` holds device scalars until the fetch
         joins: Dict[tuple, dict] = {}
+        plan_keys: Dict[int, tuple] = {}
         self._run_joins = []
         self._run_agg = collections.Counter()
+        self._run_sorts = []
         ansi_on = self._ansi
         # ANSI checks see pre-join row visibility; the pushdown's
         # pre-aggregate would evaluate agg inputs on probe rows the
@@ -890,6 +907,12 @@ class FusedSingleChipExecutor:
             if agg:
                 sp.set(agg=agg)
                 self._run_agg.update(agg)
+            # and so was how it lowered its sorts and group-bys
+            sorts = jc.sort_lowerings(key)
+            if sorts:
+                sp.set(sort=sorts)
+                self._run_sorts.extend(dict(s, program=name)
+                                       for s in sorts)
             # fl: scalar=[cap] | [cap, uniq, push] (chain programs), then
             # one lost-bet flag for each of `survivor_joins`, then what
             # nothing reads (joinops.rows_at)
@@ -952,35 +975,62 @@ class FusedSingleChipExecutor:
                 return b.live_mask() if mask is None \
                     else mask & b.live_mask()
 
-            def lookup_join(nd, b, mask, bt, uniq):
+            def lookup_join(nd, b, mask, bt, uniq, bet_to=None):
                 """Row-preserving join-as-gather (see _is_lookup_join):
                 probe rows keep their positions; match/no-match lands
                 in the pending mask (inner/semi/anti), the exists
                 column, or right-column validity (left). `bt` is the
                 build side as the buildprep program indexed it, ONCE
                 per join and not once per probe partition: the batch
-                lies as it lay and is read at `perm[lo]`."""
+                lies as it lay and is read at `perm[lo]`, or at the row
+                its table of positions names (joinops.BuildPositions).
+                `bet_to`: the join's build side sits under a filter,
+                so its MATCHES are brought to the front of a batch of
+                that capacity before any build column is read
+                (chain_joins); -> also whether they did not fit."""
                 work_l, lk = nd._prepare_keys(b, nd.left_keys)
-                lo, matched, dup = joinops.probe_unique(bt, work_l, lk)
+                # `at`: the matching build row itself, or its place in
+                # the sorted index
+                by_position = isinstance(bt, joinops.BuildPositions)
+                at, matched, dup = (
+                    joinops.probe_positions if by_position
+                    else joinops.probe_unique)(bt, work_l, lk)
                 jt = nd.join_type
+                over = None
 
                 def and_mask(m):
                     return m if mask is None else mask & m
 
-                if jt == "left_semi":
-                    return b, and_mask(matched), uniq
-                if jt == "left_anti":
-                    return b, and_mask(~matched), uniq
-                if jt == "existence":
-                    return nd._exists_batch(b, matched), mask, uniq
-                # inner / left: unique-build single-match gather; a
-                # visible probe row with >1 matches trips the
-                # uniqueness flag and the re-run lowers this join via
-                # the expanded blocking path (same capacity factors)
-                uniq = uniq | jnp.any(dup & visible(b, mask))
-                rows, plain_read = joinops.rows_at(
-                    bt, jnp.clip(lo, 0, bt.capacity - 1))
-                unread.append(plain_read)
+                if jt in ("left_semi", "inner"):
+                    # a visible probe row with >1 matches trips the
+                    # uniqueness flag (inner: the re-run lowers this
+                    # join via the expanded blocking path, same
+                    # capacity factors; a semi join does not care)
+                    if jt == "inner":
+                        uniq = uniq | jnp.any(dup & visible(b, mask))
+                    mask = and_mask(matched)
+                    if bet_to is not None:
+                        ids, total = joinops.front_row_ids(
+                            visible(b, mask), bet_to)
+                        b = b.gather(ids, jnp.minimum(total, bet_to))
+                        matched = jnp.arange(bet_to, dtype=jnp.int32) \
+                            < total
+                        at = jnp.take(at, ids)
+                        mask, over = None, total > bet_to
+                    if jt == "left_semi":
+                        return b, mask, uniq, over
+                elif jt == "left_anti":
+                    return b, and_mask(~matched), uniq, over
+                elif jt == "existence":
+                    return nd._exists_batch(b, matched), mask, uniq, over
+                else:  # left: a miss keeps its row, its build columns null
+                    uniq = uniq | jnp.any(dup & visible(b, mask))
+                # inner / left: unique-build single-match gather
+                rows = at
+                if not by_position:
+                    rows, plain_read = joinops.rows_at(
+                        bt, jnp.clip(at, 0, bt.capacity - 1))
+                    unread.append(plain_read)
                 rcols = [c.gather(rows) for c in bt.batch.columns]
                 rcols = [c.replace(validity=c.validity & matched)
                          for c in rcols]
@@ -988,9 +1038,7 @@ class FusedSingleChipExecutor:
                 # joins promote build-side fields to nullable)
                 b = ColumnBatch(nd.schema, list(b.columns) + rcols,
                                 b.num_rows)
-                if jt == "inner":
-                    mask = and_mask(matched)
-                return b, mask, uniq
+                return b, mask, uniq, over
 
             def survivors(b, mask, cap):
                 """The rows the pending mask lets through, at the front
@@ -1007,12 +1055,16 @@ class FusedSingleChipExecutor:
                     # defect, not a run-time condition
                     assert jp["probeSlots"] in (None, b.capacity), \
                         (jp, b.capacity)
-                    if jp["lowering"] == "lookupSurvivors":
+                    if "probeFilter" in jp["bet"]:
                         b, over = survivors(b, mask, jp["searchedSlots"])
                         mask = None
                         lost.append(over)
-                    b, mask, uniq = lookup_join(nd, b, mask,
-                                                builds.pop(0), uniq)
+                    b, mask, uniq, over = lookup_join(
+                        nd, b, mask, builds.pop(0), uniq,
+                        jp["outputCapacity"]
+                        if "buildFilter" in jp["bet"] else None)
+                    if over is not None:
+                        lost.append(over)
                 elif isinstance(nd, ops.TpuFilterExec):
                     av = ansi_vec([nd.condition], b, visible(b, mask))
                     if av is not None:
@@ -1085,11 +1137,13 @@ class FusedSingleChipExecutor:
                 return [b for c in node.children for b in emit_parts(c)]
             if chainable(node):
                 nodes, cur = collect_chain(node)
-                if use_lookup and push_on:
+                base = emit_parts(cur)
+                if use_lookup and push_on \
+                        and not bets_on_matches(nodes, base):
                     rep = rewrite_memo(nodes)
                     if rep is not None:
                         nodes = rep
-                return run_chain(nodes, emit_parts(cur))
+                return run_chain(nodes, base)
             return [emit_blocking(node)]
 
         def chainable(n):
@@ -1104,7 +1158,77 @@ class FusedSingleChipExecutor:
             while chainable(cur) and id(cur) not in src_parts:
                 chain.append(cur)
                 cur = cur.children[0]
-            return list(reversed(chain)), cur
+            return yield_lost_bets(list(reversed(chain))), cur
+
+        def yield_lost_bets(nodes):
+            """The chain with every inner lookup join that LOST its bet
+            on its matches (`chain_joins`) behind the join right after
+            it, where that one still places the bet and reads probe
+            columns only: the join that was seen to keep more than 1/64
+            of its rows yields to one that may keep fewer, and then
+            probes, and bets, over that join's survivors. The columns
+            come out in the plan's order through a projection of bare
+            references, which moves nothing. Inner joins on columns of
+            the same probe side commute."""
+            if not self._wide_joins:
+                return nodes
+            # a bet lost in this run's earlier attempt counts at once
+            memo = ("yield", len(self._wide_joins)) \
+                + tuple(id(n) for n in nodes)
+            if memo in self._rewrite_memo:
+                return self._rewrite_memo[memo]
+            out, i = [], 0
+            while i < len(nodes):
+                a = nodes[i]
+                b = nodes[i + 1] if i + 1 < len(nodes) else None
+                if b is not None and yields_to(a, b):
+                    out.extend(swapped_joins(a, b))
+                    i += 2
+                else:
+                    out.append(a)
+                    i += 1
+            self._rewrite_memo[memo] = out
+            return out
+
+        def yields_to(a, b) -> bool:
+            def plain_inner(n):
+                return (self._is_lookup_join(n, use_lookup)
+                        and n.join_type == "inner")
+
+            if not (plain_inner(a) and plain_inner(b)):
+                return False
+            n_probe = len(a.children[0].schema.fields)
+            return ((plan_key_memo(a), "matches") in self._wide_joins
+                    and (plan_key_memo(b), "matches")
+                    not in self._wide_joins
+                    and b.build_is_filtered()
+                    and all(r < n_probe for k in b.left_keys
+                            for r in k.references()))
+
+        def swapped_joins(a, b):
+            """[b over a's probe side, a over that, the columns back in
+            a-then-b order]."""
+            from spark_rapids_tpu.expr import Alias, BoundReference
+            from spark_rapids_tpu.sqltypes import StructType
+
+            probe = list(a.children[0].schema.fields)
+            a_cols = list(a.schema.fields[len(probe):])
+            b_cols = list(b.schema.fields[len(a.schema.fields):])
+            first = J.TpuBroadcastHashJoinExec(
+                a.children[0], b.children[1], b.join_type, b.left_keys,
+                b.right_keys, StructType(probe + b_cols), b.conf)
+            second = J.TpuBroadcastHashJoinExec(
+                first, a.children[1], a.join_type, a.left_keys,
+                a.right_keys, StructType(probe + b_cols + a_cols), a.conf)
+            n_p, n_a, n_b = len(probe), len(a_cols), len(b_cols)
+            order = (list(range(n_p))
+                     + list(range(n_p + n_b, n_p + n_b + n_a))
+                     + list(range(n_p, n_p + n_b)))
+            back = ops.TpuProjectExec(
+                [Alias(BoundReference(o, f.dataType, f.nullable), f.name)
+                 for o, f in zip(order, b.schema.fields)],
+                second, b.schema, b.conf)
+            return [first, second, back]
 
         def rewrite_memo(nodes):
             """Per-run memo of agg_pushdown.rewrite_chain: the rewrite
@@ -1116,6 +1240,30 @@ class FusedSingleChipExecutor:
                 self._rewrite_memo[key] = \
                     agg_pushdown.rewrite_chain(nodes)
             return self._rewrite_memo[key]
+
+        def chain_keys(nodes):
+            return [n.chain_key()
+                    if isinstance(n, agg_pushdown.MergeTail)
+                    else plan_key_memo(n) for n in nodes]
+
+        def plan_key_memo(n):
+            # `_plan_key` builds a whole-subtree key: once a node a run
+            if id(n) not in plan_keys:
+                plan_keys[id(n)] = _plan_key(n)
+            return plan_keys[id(n)]
+
+        def bets_on_matches(nodes, base) -> bool:
+            """Whether the chain's LAST join, the one the aggregate
+            pushdown would aggregate below, bets on its own matches
+            (`chain_joins`): it is then a selective filter, and an
+            aggregate pushed below it would group the rows it drops.
+            Once that bet is lost the pushdown applies again."""
+            if not any(isinstance(n, J.TpuBroadcastHashJoinExec)
+                       for n in nodes):
+                return False
+            keys = chain_keys(nodes)
+            return any("buildFilter" in chain_joins(
+                nodes, keys, b.capacity)[-1]["bet"] for b in base)
 
         def chain_has_ansi(nodes) -> bool:
             """Hoisted ANSI relevance for one chain: True only when the
@@ -1146,23 +1294,39 @@ class FusedSingleChipExecutor:
             alone left is no bet) searches the filter's survivors at
             `survivor_capacity`, unless the batch is too small or the
             join lost that bet before (`wide_joins`); the batch goes
-            on at that capacity. After an aggregate the capacity is the
-            aggregate's own (None here)."""
+            on at that capacity. An inner or semi join whose BUILD side
+            sits under a filter is itself a filter: it places the same
+            bet on its own matches, brought to the front before any
+            build column is read (`bet`: which of the two a
+            "lookupSurvivors" join placed). After an aggregate the
+            capacity is the aggregate's own (None here)."""
             cap, filtered, out = capacity, False, []
             for nd, key in zip(nodes, keys):
                 if isinstance(nd, J.TpuBroadcastHashJoinExec):
+                    bet, probe_slots = [], cap
                     to = (survivor_capacity(cap)
                           if filtered and cap is not None
                           and key not in self._wide_joins else None)
+                    if to:
+                        bet.append("probeFilter")
+                        cap, filtered = to, False
+                    searched = cap
+                    to = (survivor_capacity(cap)
+                          if cap is not None
+                          and nd.join_type in ("inner", "left_semi")
+                          and (key, "matches") not in self._wide_joins
+                          and nd.build_is_filtered() else None)
+                    if to:
+                        bet.append("buildFilter")
+                        cap, filtered = to, False
                     out.append({
-                        "lowering": "lookupSurvivors" if to else "lookup",
+                        "lowering": "lookupSurvivors" if bet else "lookup",
+                        "bet": "+".join(bet),
                         "joinType": nd.join_type,
                         "buildGather": build_gather(nd.join_type),
-                        "probeSlots": cap,
-                        "searchedSlots": to or cap,
-                        "outputCapacity": to or cap})
-                    if to:
-                        cap, filtered = to, False
+                        "probeSlots": probe_slots,
+                        "searchedSlots": searched,
+                        "outputCapacity": cap})
                 elif isinstance(nd, ops.TpuFilterExec):
                     filtered = True
                 elif isinstance(nd, ops.TpuExpandExec):
@@ -1190,9 +1354,7 @@ class FusedSingleChipExecutor:
                           else was[k] + rec[k])
 
         def run_chain(nodes, base):
-            keys = [n.chain_key()
-                    if isinstance(n, agg_pushdown.MergeTail)
-                    else _plan_key(n) for n in nodes]
+            keys = chain_keys(nodes)
             nodes_key = tuple(
                 k if isinstance(n, agg_pushdown.MergeTail) else k[:2]
                 for n, k in zip(nodes, keys))
@@ -1200,8 +1362,20 @@ class FusedSingleChipExecutor:
             # per-partition programs, and ride in as extra inputs
             join_keys = [k for n, k in zip(nodes, keys)
                          if isinstance(n, J.TpuBroadcastHashJoinExec)]
-            builds = [build_table(n) for n in nodes
-                      if isinstance(n, J.TpuBroadcastHashJoinExec)]
+            join_nodes = [n for n in nodes
+                          if isinstance(n, J.TpuBroadcastHashJoinExec)]
+            # the host's walk of every part's chain comes first: how a
+            # build side is indexed follows from the slots it is
+            # probed from
+            plans = [chain_joins(nodes, keys, b.capacity)
+                     if join_nodes else [] for b in base]
+            built = [
+                build_table(n, sum(plan[i]["searchedSlots"] or 0
+                                   for plan in plans),
+                            sum(b.capacity for b in base))
+                for i, n in enumerate(join_nodes)]
+            builds = [bt for bt, _ in built]
+            build_slots = [slots for _, slots in built]
             ansi_live = chain_has_ansi(nodes)
             uses = dict(
                 uses_expansion=any(isinstance(n, ops.TpuGenerateExec)
@@ -1211,14 +1385,20 @@ class FusedSingleChipExecutor:
                     for n in nodes),
                 uses_ansi=ansi_live)
 
-            def one(b):
-                plan = chain_joins(nodes, keys, b.capacity) \
-                    if builds else []
-                for key, bt, jp in zip(join_keys, builds, plan):
-                    jp["buildSlots"] = bt.capacity
+            def one(b, plan):
+                bets = []
+                for key, bt, slots, jp in zip(join_keys, builds,
+                                              build_slots, plan):
+                    jp["buildSlots"] = slots
+                    by_position = isinstance(bt, joinops.BuildPositions)
+                    jp["probe"] = "position" if by_position else "search"
+                    jp["probeSteps"] = 1 if by_position else max(
+                        1, slots.bit_length())
                     note_join(key, jp, [bt.num_rows])
-                bets = tuple(k for k, jp in zip(join_keys, plan)
-                             if jp["lowering"] == "lookupSurvivors")
+                    if "probeFilter" in jp["bet"]:
+                        bets.append(key)
+                    if "buildFilter" in jp["bet"]:
+                        bets.append((key, "matches"))
 
                 def stage_fn(b, *bs, _nodes=nodes, _al=ansi_live,
                              _plan=plan):
@@ -1226,43 +1406,94 @@ class FusedSingleChipExecutor:
                                         join_plan=_plan)
 
                 # the lowering is structural: which joins search their
-                # survivors, and what each reads of a build side left
-                # as it lay, is part of the program's key (a chain with
-                # no join keeps the key, and the name, it had)
+                # survivors, what each reads of a build side left as it
+                # lay and how it finds the row is part of the program's
+                # key (a chain with no join keeps the key, and the
+                # name, it had; so does one with none of the later
+                # lowerings: a mark is added only where one engaged)
                 marked = nodes_key
                 if bets:
                     marked += (("survivors",
                                 tuple(jp["lowering"] for jp in plan)),)
+                if any("buildFilter" in jp["bet"] for jp in plan):
+                    marked += (("bets", tuple(jp["bet"] for jp in plan)),)
+                if any(jp["probe"] == "position" for jp in plan):
+                    marked += (("probe",
+                                tuple(jp["probe"] for jp in plan)),)
                 if plan:
                     marked += (("buildGather",
                                 tuple(jp["buildGather"] for jp in plan)),)
                 return run_program("chain", marked, stage_fn,
                                    [b] + builds, join_fields=plan,
-                                   survivor_joins=bets, **uses)
+                                   survivor_joins=tuple(bets), **uses)
 
-            return [one(b) for b in base]
+            return [one(b, plan) for b, plan in zip(base, plans)]
 
-        def build_table(jn: PhysicalPlan):
-            """The build side of one lookup join, indexed where it lies
-            (joinops.BuildIndex) — ONE buildprep program per join per
-            run, shared by every per-partition chain program as an
-            extra pytree input."""
-            parts = emit_parts(jn.children[1])
+        def build_table(jn: PhysicalPlan, probed_slots: int,
+                        chain_slots: int):
+            """-> (the build side of one lookup join, indexed where it
+            lies, its slots) — ONE buildprep program per join per run,
+            shared by every per-partition chain program as an extra
+            pytree input. Its one plain integer key is read BY POSITION
+            (joinops.BuildPositions: a table with an entry for every
+            value of the key's stamped range) where writing that table
+            costs no more than the search it saves — its entries are
+            no more than `probed_slots`, the slots the chain programs
+            probe it from, times the steps of a search of the sorted
+            index — and it has no more entries than the chain's input
+            has slots (`chain_slots`: 4 bytes a fact row at most). A
+            sparse key under a selective probe-side filter (TPC-H
+            Q12's `o_orderkey`: 67M values for 983,040 survivors x 24
+            steps) keeps the sorted index and its search
+            (joinops.BuildIndex)."""
+            # filters right above the build side's source are taken
+            # into this program as its mask of live rows: the index
+            # sends the rows they drop last, or leaves them out of its
+            # table, and a program that only compacted them is saved
+            src, filters = jn.children[1], []
+            while (isinstance(src, (ops.TpuFilterExec,
+                                    ops.TpuCoalesceBatchesExec))
+                   and id(src) not in src_parts):
+                if isinstance(src, ops.TpuFilterExec):
+                    filters.insert(0, src)
+                src = src.children[0]
+            if chain_has_ansi(filters):
+                src, filters = jn.children[1], []
+            parts = emit_parts(src)
             gather = build_gather(jn.join_type)
+            ranges = [jn.build_key_range(p) for p in parts]
+            slots = sum(p.capacity for p in parts)
+            steps = max(1, slots.bit_length())
+            by_position = bool(ranges) and None not in ranges and (
+                max(hi for _, hi in ranges) - min(lo for lo, _ in ranges)
+                < min(probed_slots * steps, chain_slots))
 
             def bp_fn(*ps):
+                from spark_rapids_tpu.expr import EvalContext
+
                 # the parts end to end, uncompacted: the build side's
-                # sort sends every dead row last anyway
+                # sort sends every dead row last anyway, and its table
+                # of positions takes no dead row
                 cb, live = concat_in_place(concat_inputs(list(ps)))
-                return (jn._build_index(cb, live, gather == "matched"),
+                for f in filters:
+                    pred = f.condition.eval(EvalContext(cb))
+                    live = live & pred.data & pred.validity
+                index = (jn._build_positions if by_position
+                         else jn._build_index)
+                return (index(cb, live, gather == "matched"),
                         jnp.zeros((), bool))
 
             # in the key, as in the chain's: no cache may hand a chain
             # that reads `perm[lo]` the sorted batch this program made
-            # before it made an index
-            return run_program(
-                "buildprep", _plan_key(jn)[:2] + (("buildGather", gather),),
-                bp_fn, parts)
+            # before it made an index, nor a table of positions
+            marked = plan_key_memo(jn)[:2]
+            if filters:
+                marked += (("buildFilter", tuple(
+                    plan_key_memo(f)[:2] for f in filters)),)
+            if by_position:
+                marked += (("probe", "position"),)
+            marked += (("buildGather", gather),)
+            return run_program("buildprep", marked, bp_fn, parts), slots
 
         def concat_inputs(parts):
             return [widen_traced(p) for p in parts]
@@ -1276,10 +1507,12 @@ class FusedSingleChipExecutor:
                     # per-part chain pre-aggregates + joins + merges
                     # buffers, and the blocking step only merge-finals
                     nodes, cur = collect_chain(node)
+                    base = emit_parts(cur)
                     rep = (rewrite_memo(nodes)
-                           if len(nodes) > 1 else None)
+                           if len(nodes) > 1
+                           and not bets_on_matches(nodes, base) else None)
                     if rep is not None:
-                        parts = run_chain(rep, emit_parts(cur))
+                        parts = run_chain(rep, base)
 
                         def mf_fn(*ps):
                             cb = concat_traced(concat_inputs(list(ps)))

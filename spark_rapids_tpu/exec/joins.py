@@ -408,6 +408,38 @@ class _DeviceJoinBase(PhysicalPlan):
             return idx._replace(batch=None, perm=None)
         return idx._replace(batch=right)
 
+    def _build_positions(self, right: ColumnBatch, live,
+                         reads_columns: bool) -> joinops.BuildPositions:
+        """The build side as a row-or-absent table over its key's
+        stamped range (`build_key_range` is not None), read by
+        position where `_build_index`'s is searched."""
+        work_r, rk = self._prepare_keys(right, self.right_keys)
+        pos = joinops.build_positions(work_r, rk, live)
+        return pos._replace(batch=right if reads_columns else None)
+
+    def build_key_range(self, right: ColumnBatch):
+        """The stamped (lo, hi) of the build side's one plain integer
+        key column, or None."""
+        if not all(isinstance(k, BoundReference) for k in self.right_keys):
+            return None
+        return joinops.key_range(right, [k.ordinal for k in self.right_keys])
+
+    def build_is_filtered(self) -> bool:
+        """Whether the build side sits under a filter: the join then
+        filters its probe side, as a WHERE on a dimension's attributes
+        does once it is pushed below the join."""
+        from spark_rapids_tpu.exec import operators as ops
+
+        node = self.children[1]
+        while True:
+            if isinstance(node, ops.TpuFilterExec):
+                return True
+            if not isinstance(node, (ops.TpuProjectExec,
+                                     ops.TpuCoalesceBatchesExec,
+                                     ops.TpuShuffleExchangeExec)):
+                return False
+            node = node.children[0]
+
 
 class TpuShuffledHashJoinExec(_DeviceJoinBase):
     """Partitioned equi-join; children must be co-partitioned by key

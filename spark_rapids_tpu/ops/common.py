@@ -29,7 +29,9 @@ CPU backend.
 
 from __future__ import annotations
 
-from typing import List, Tuple
+import contextvars
+from contextlib import contextmanager
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -174,17 +176,229 @@ def rows_equal_adjacent(keys: List[jnp.ndarray]) -> jnp.ndarray:
 
 def sorted_with_permutation(key_arrays: List[jnp.ndarray], capacity: int
                             ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
-    """Stable multi-key sort; -> (the keys, sorted; the gather
-    permutation). The sorted keys are the sort's own outputs: a caller
-    of `sort_permutation` that wants them gathers them a second time."""
+    """Stable sort on ONE key array; -> (the key, sorted; the gather
+    permutation). The sorted key is the sort's own output: a caller
+    of `sort_permutation` that wants it gathers it a second time.
+    Several keys never go to one `lax.sort`: `sort_permutation`."""
+    (key,) = key_arrays
     iota = jnp.arange(capacity, dtype=jnp.int32)
-    out = lax.sort(tuple(key_arrays) + (iota,), num_keys=len(key_arrays),
-                   is_stable=True)
-    return list(out[:-1]), out[-1]
+    note_sort("packed", 1, 8 * key.dtype.itemsize, capacity)
+    out = lax.sort((key, iota), num_keys=1, is_stable=True)
+    return [out[0]], out[1]
 
 
 def sort_permutation(key_arrays: List[jnp.ndarray],
                      capacity: int) -> jnp.ndarray:
-    """Stable multi-key sort; returns the gather permutation (cuDF
-    `Table.sortOrder` analog)."""
-    return sorted_with_permutation(key_arrays, capacity)[1]
+    """Stable multi-key sort of already-orderable arrays; returns the
+    gather permutation (cuDF `Table.sortOrder` analog). Several keys
+    go as stable one-key passes from the least significant key. A
+    caller that has COLUMNS packs them first (`key_fields`,
+    `sort_permutation_fields`): far fewer bits, far fewer passes."""
+    if len(key_arrays) == 1:
+        return sorted_with_permutation(key_arrays, capacity)[1]
+    note_sort("passes", 1, sum(8 * k.dtype.itemsize for k in key_arrays),
+              capacity, passes=len(key_arrays))
+    if len({k.dtype for k in key_arrays}) == 1:
+        return _sort_passes(list(key_arrays), capacity)
+    # keys of several dtypes (a float among integers) cannot share one
+    # loop's operand: a pass each, every one in its own dtype's order
+    perm = jnp.arange(capacity, dtype=jnp.int32)
+    for key in reversed(key_arrays):
+        perm = lax.sort((jnp.take(key, perm), perm), num_keys=1,
+                        is_stable=True)[1]
+    return perm
+
+
+def _sort_passes(words: List[jnp.ndarray], capacity: int) -> jnp.ndarray:
+    """The permutation that sorts rows by `words` (most significant
+    first, all of one dtype), as stable sorts on ONE key operand each,
+    from the least significant word. The passes are the trips of one
+    loop, so a program holds one sort whatever the width of its keys:
+    the TPU's compiler takes 15 s and more over every sort of 32Ki
+    slots or more, and minutes over one with several 64-bit key
+    operands (PERF.md, PR 33)."""
+    iota = jnp.arange(capacity, dtype=jnp.int32)
+    if len(words) == 1:
+        return lax.sort((words[0], iota), num_keys=1, is_stable=True)[1]
+    stacked = jnp.stack(words[::-1])
+
+    def one_pass(i, perm):
+        key = jnp.take(lax.dynamic_index_in_dim(stacked, i, keepdims=False),
+                       perm)
+        return lax.sort((key, perm), num_keys=1, is_stable=True)[1]
+
+    return lax.fori_loop(0, len(words), one_pass, iota)
+
+
+# ---- packed sort keys: columns -> bit fields -> 32-bit words ----
+#
+# A sort or a group-by over COLUMNS knows how few bits each key needs:
+# a stamped value range (the narrowed upload's `vrange`), a
+# dictionary's size, a column's own width. Each column lowers to bit
+# FIELDS, most significant first — (uint32 array, bits), the value
+# below 2**bits and its unsigned order the SQL order — which are laid
+# end to end behind one leading "dead row" bit and cut into 32-bit
+# words. One word: one sort on one 32-bit operand ("packed"). More:
+# one stable pass a word ("passes", `_sort_passes`).
+
+Field = Tuple[jnp.ndarray, int]
+
+_SORT_NOTES = contextvars.ContextVar("srtpu_sort_notes", default=None)
+
+
+@contextmanager
+def noting_sorts():
+    """Collect how the sorts traced inside were lowered: a list of
+    {"how": "packed" | "passes", "operands", "keyBits", "passes",
+    "slots"} (runtime/jit_cache.py keeps it with the program,
+    exec/fused.py reports it as `sort`)."""
+    notes: list = []
+    tok = _SORT_NOTES.set(notes)
+    try:
+        yield notes
+    finally:
+        _SORT_NOTES.reset(tok)
+
+
+def note_sort(how: str, operands: int, key_bits: int, slots: int,
+              passes: int = 1, by: str = "sort") -> None:
+    notes = _SORT_NOTES.get()
+    if notes is not None:
+        notes.append({"how": how, "by": by, "operands": operands,
+                      "keyBits": key_bits, "passes": passes,
+                      "slots": slots})
+
+
+def _u32(x: jnp.ndarray) -> jnp.ndarray:
+    return x.astype(jnp.uint32)
+
+
+def _split_i64(key: jnp.ndarray) -> List[Field]:
+    """A signed-orderable int64 key as two 32-bit fields."""
+    hi = (key >> 32) + jnp.int64(1 << 31)
+    return [(_u32(hi), 32), (_u32(key & jnp.int64(0xFFFFFFFF)), 32)]
+
+
+def _dictionary_ranks(dd) -> Optional[jnp.ndarray]:
+    """code -> rank of its value in byte order (equal values, equal
+    ranks), from the host's copy of the dictionary while the program
+    is traced (the dictionary's identity is in every program key), or
+    None where the host no longer holds it."""
+    import numpy as np
+
+    from spark_rapids_tpu.columnar import encoding as _enc
+
+    values = _enc.dictionary_values(dd.dict_id)
+    if values is None or len(values) != dd.num_values:
+        return None
+    raw = [v.encode() if v is not None else b""
+           for v in values.to_pylist()]
+    order = {v: r for r, v in enumerate(sorted(set(raw)))}
+    return jnp.asarray(np.array([order[v] for v in raw], np.uint32))
+
+
+def _value_fields(col: DeviceColumn, codes_ok: bool) -> List[Field]:
+    """One column's value as fields; null and dead rows are zeroed by
+    the caller."""
+    dd = getattr(col, "encoding", None)
+    if dd is not None:
+        bits = max(dd.num_values - 1, 1).bit_length()
+        codes = jnp.clip(col.data.astype(jnp.int32), 0,
+                         max(dd.num_values - 1, 0))
+        if codes_ok:
+            return [(_u32(codes), bits)]
+        ranks = _dictionary_ranks(dd)
+        if ranks is not None:
+            return [(jnp.take(ranks, codes), bits)]
+        from spark_rapids_tpu.columnar import encoding as _enc
+
+        col = _enc.decode_column(col)
+    dt, data = col.dtype, col.data
+    if isinstance(dt, StringType):
+        *words, lengths = _string_orderable(col)
+        return ([(_u32(w), 32) for w in words]
+                + [(_u32(lengths), max(col.max_bytes, 1).bit_length())])
+    if isinstance(dt, (FloatType, DoubleType)):
+        key = _float_orderable(data)
+        if data.dtype != jnp.float64:
+            return [(_u32(key + jnp.int64(1 << 31)), 32)]
+        if supports_64bit_bitcast():
+            return _split_i64(key)
+        # a TPU cannot bitcast 64 bits, and holds a double as a pair of
+        # f32 anyway: the double's order is that of its f32 rounding,
+        # then of what the rounding left (exact to the pair's ~48 bits;
+        # NaN - NaN and inf - inf are the one canonical NaN)
+        rest = data - data.astype(jnp.float32).astype(jnp.float64)
+        return [(_u32(key + jnp.int64(1 << 31)), 32),
+                (_u32(_float_orderable(rest.astype(jnp.float32))
+                      + jnp.int64(1 << 31)), 32)]
+    if isinstance(dt, BooleanType):
+        return [(_u32(data), 1)]
+    if data.ndim == 2:  # DECIMAL128 limb matrix
+        from spark_rapids_tpu.ops import decimal128 as _d128
+
+        return [f for limb in _d128.orderable_limbs(data)
+                for f in _split_i64(limb)]
+    wide = data.astype(jnp.int64)
+    if col.vrange is not None and jnp.issubdtype(data.dtype, jnp.integer):
+        lo, hi = col.vrange
+        if hi - lo < 1 << 32:
+            return [(_u32(wide - lo), max(hi - lo, 1).bit_length())]
+    width = 8 * data.dtype.itemsize
+    if width <= 32:
+        return [(_u32(wide + (1 << (width - 1))), width)]
+    return _split_i64(wide)
+
+
+def key_fields(col: DeviceColumn, ascending: bool, nulls_first: bool,
+               live: jnp.ndarray, codes_ok: bool = False) -> List[Field]:
+    """One column (+ sort direction) as fields: a null bit, then its
+    value. `codes_ok` as in `orderable_keys`; without it a dictionary
+    column orders by its values' ranks."""
+    valid = col.validity
+    rank = jnp.where(valid, 1, 0) if nulls_first else jnp.where(valid, 0, 1)
+    keep = valid & live
+    out = [(_u32(jnp.where(live, rank, 0)), 1)]
+    for value, bits in _value_fields(col, codes_ok):
+        if not ascending:
+            value = jnp.uint32((1 << bits) - 1) - value
+        out.append((jnp.where(keep, value, jnp.uint32(0)), bits))
+    return out
+
+
+def pack_fields(fields: List[Field], live: jnp.ndarray
+                ) -> Tuple[List[jnp.ndarray], int]:
+    """Fields (most significant first) behind a leading bit that sends
+    dead rows last -> (32-bit words, most significant first; the bits
+    used). A field that straddles two words is split."""
+    fields = [(_u32(~live), 1)] + list(fields)
+    total = sum(bits for _, bits in fields)
+    words, word, used = [], None, 0  # built from the least significant end
+    for value, bits in reversed(fields):
+        while bits:
+            take = min(bits, 32 - used)
+            part = value if take == 32 else \
+                value & jnp.uint32((1 << take) - 1)
+            word = part if word is None else word | (part << used)
+            used += take
+            bits -= take
+            if bits:
+                value = value >> take
+            if used == 32:
+                words.append(word)
+                word, used = None, 0
+    if word is not None:
+        words.append(word)
+    return words[::-1], total
+
+
+def sort_permutation_fields(fields: List[Field], live: jnp.ndarray,
+                            capacity: int, by: str = "sort"
+                            ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
+    """Stable sort of the rows by `fields`, dead rows last; -> (the
+    gather permutation, the packed words in the rows' own order: equal
+    words, equal keys)."""
+    words, bits = pack_fields(fields, live)
+    note_sort("packed" if len(words) == 1 else "passes", 1, bits, capacity,
+              passes=len(words), by=by)
+    return _sort_passes(words, capacity), words

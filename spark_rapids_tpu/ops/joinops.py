@@ -25,11 +25,13 @@ from __future__ import annotations
 from typing import List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax.numpy as jnp
+from jax import lax
 
 from spark_rapids_tpu.columnar.batch import ColumnBatch
 from spark_rapids_tpu.ops.common import (
     equality_keys,
     normalize_floating,
+    sort_permutation,
     sorted_with_permutation,
 )
 
@@ -61,6 +63,107 @@ class BuildIndex(NamedTuple):
         return self.keys[0].shape[0]
 
 
+class BuildPositions(NamedTuple):
+    """Build side of a lookup join whose ONE integer key's stamped
+    range is small enough for a table of it: `table[key - lo]` is the
+    build row that holds `key`, so a probe is one read where the
+    sorted index takes a search of log2(slots) steps. The batch stays
+    as it lies, as `BuildIndex`'s does."""
+
+    batch: Optional[ColumnBatch]   # UNSORTED; None: the join reads no column
+    table: jnp.ndarray             # int32 [hi - lo + 1]: the row holding
+    #                                key lo + i; -1: none; -2 - row: that
+    #                                row and at least one more
+    lo: jnp.ndarray                # scalar int64: the stamped range's low end
+    valid_bound: jnp.ndarray       # scalar int32: rows with non-null keys
+    num_rows: jnp.ndarray          # scalar int32: live rows
+
+    @property
+    def capacity(self) -> int:
+        return self.batch.capacity if self.batch is not None \
+            else self.table.shape[0]
+
+
+#: entries of a table of positions written by one scatter
+_TABLE_CHUNK = 1 << 20
+
+
+def key_range(batch: ColumnBatch, key_idxs: Sequence[int]
+              ) -> Optional[Tuple[int, int]]:
+    """The stamped (lo, hi) of a build side's one plain integer key
+    column, or None: what `build_positions` needs."""
+    if len(key_idxs) != 1:
+        return None
+    col = batch.columns[key_idxs[0]]
+    if (col.vrange is None or col.encoding is not None
+            or col.data.ndim != 1
+            or not jnp.issubdtype(col.data.dtype, jnp.integer)):
+        return None
+    return col.vrange
+
+
+def build_positions(batch: ColumnBatch, key_idxs: Sequence[int],
+                    live: Optional[jnp.ndarray] = None) -> BuildPositions:
+    """The row-or-absent table over the key's stamped range
+    (`key_range` is not None). No sort: one scatter of the row ids and
+    one of a count, which marks the keys that two rows hold."""
+    lo, hi = key_range(batch, key_idxs)
+    size = hi - lo + 1
+    cap = batch.capacity
+    rows = batch.num_rows
+    if live is None:
+        live = batch.live_mask()
+    else:
+        rows = jnp.sum(live).astype(jnp.int32)
+    col = batch.columns[key_idxs[0]]
+    valid = live & col.validity
+    # null-keyed and dead rows scatter out of range and are dropped
+    at = jnp.where(valid, col.data.astype(jnp.int64) - lo,
+                   size).astype(jnp.int32)
+    ids = jnp.arange(cap, dtype=jnp.int32)
+
+    def chunk(at, n):
+        """Entries [0, n) of the table from positions `at` (others
+        dropped): the row id, and the mark of a second holder."""
+        table = jnp.full((n,), -1, jnp.int32).at[at].set(ids, mode="drop")
+        held = jnp.zeros((n,), jnp.int32).at[at].add(1, mode="drop")
+        return jnp.where(held > 1, -2 - table, table)
+
+    if size <= _TABLE_CHUNK:
+        table = chunk(at, size)
+    else:
+        # a scatter into more than a few MB is one the TPU's compiler
+        # SORTS the indices of (13 s of compile for 73,049 rows into
+        # 4M entries, PERF.md PR 33): the table is written a chunk at
+        # a time, every row offered to every chunk
+        def one(k):
+            rel = at - k * _TABLE_CHUNK
+            inside = (rel >= 0) & (rel < _TABLE_CHUNK)
+            return chunk(jnp.where(inside, rel, _TABLE_CHUNK), _TABLE_CHUNK)
+
+        chunks = -(-size // _TABLE_CHUNK)
+        table = lax.map(one, jnp.arange(chunks, dtype=jnp.int32)
+                        ).reshape(-1)[:size]
+    return BuildPositions(batch, table, jnp.int64(lo),
+                          jnp.sum(valid).astype(jnp.int32), rows)
+
+
+def probe_positions(build: BuildPositions, probe: ColumnBatch,
+                    key_idxs: Sequence[int]
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Per-probe-row (build row, matched, dup), as `probe_unique` and
+    `rows_at` give them together: one read of the table."""
+    live = probe.live_mask()
+    vals, all_valid = _join_keys(probe, key_idxs, live)
+    size = build.table.shape[0]
+    at = vals[0] - build.lo
+    inside = all_valid & (at >= 0) & (at < size)
+    held = jnp.take(build.table, jnp.clip(at, 0, size - 1).astype(jnp.int32))
+    matched = inside & (held != -1)
+    row = jnp.where(held < -1, -2 - held, held)
+    return jnp.maximum(row, 0), matched, matched & (held < -1)
+
+
 def _join_keys(batch: ColumnBatch, key_idxs: Sequence[int],
                live: jnp.ndarray) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
     """Orderable value keys + "all keys valid" mask (rank keys excluded —
@@ -81,18 +184,12 @@ _ABOVE_32 = 2 ** 31 - 1  # a plain int: nothing here may touch the backend
 def _fits_32_bits(batch: ColumnBatch, key_idxs: Sequence[int]) -> bool:
     """One plain integer key column whose stamped value range (the
     narrowed upload's, exec/fused.py) leaves `_ABOVE_32` free: its
-    sort needs one 32-bit operand where the general one takes two of
-    64 bits, which the TPU's compiler takes minutes over and the chip
-    sorts, gathers and searches at a third of the speed."""
-    if len(key_idxs) != 1:
-        return False
-    col = batch.columns[key_idxs[0]]
-    if (col.vrange is None or col.encoding is not None
-            or col.data.ndim != 1
-            or not jnp.issubdtype(col.data.dtype, jnp.integer)):
-        return False
-    lo, hi = col.vrange
-    return -(2 ** 31) <= lo and hi < _ABOVE_32
+    sort needs one 32-bit operand where the general one takes a pass
+    for each of two 64-bit ones, which the chip sorts, gathers and
+    searches at a third of the speed."""
+    stamped = key_range(batch, key_idxs)
+    return (stamped is not None and -(2 ** 31) <= stamped[0]
+            and stamped[1] < _ABOVE_32)
 
 
 def build_index(batch: ColumnBatch, key_idxs: Sequence[int],
@@ -118,10 +215,10 @@ def build_index(batch: ColumnBatch, key_idxs: Sequence[int],
         sorted_keys, perm = sorted_with_permutation(vals, cap)
     else:
         # Sort null-keyed / dead rows to the end: leading rank 0 valid,
-        # 1 not.
+        # 1 not; one stable pass a key array, never one sort on all
         rank = jnp.where(all_valid, 0, 1).astype(jnp.int64)
-        ranked, perm = sorted_with_permutation([rank] + vals, cap)
-        sorted_keys = ranked[1:]
+        perm = sort_permutation([rank] + vals, cap)
+        sorted_keys = [jnp.take(v, perm) for v in vals]
     valid_bound = jnp.sum(all_valid).astype(jnp.int32)
     return BuildIndex(batch, sorted_keys, perm, valid_bound, rows)
 
@@ -153,8 +250,6 @@ def _binary_search(build_keys: List[jnp.ndarray],
                    build_cap: int, upper: bool) -> jnp.ndarray:
     """First index in [0, bound) where build[idx] >= probe (lower) or
     > probe (upper); vectorized over probe rows."""
-    from jax import lax
-
     n = probe_keys[0].shape[0]
     lo = jnp.zeros(n, dtype=jnp.int32)
     hi = jnp.broadcast_to(bound.astype(jnp.int32), (n,))

@@ -33,10 +33,10 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar.batch import ColumnBatch, DeviceColumn
 from spark_rapids_tpu.ops.common import (
-    equality_keys,
+    key_fields,
     normalize_floating,
     rows_equal_adjacent,
-    sort_permutation,
+    sort_permutation_fields,
 )
 
 
@@ -415,16 +415,18 @@ def group_by(batch: ColumnBatch, key_idxs: Sequence[int],
         gid = jnp.zeros((cap,), jnp.int32)
         first_pos = jnp.zeros((cap,), jnp.int32)
         return GroupedBatch(batch, gid, live, jnp.int32(1), first_pos)
-    keys: List[jnp.ndarray] = []
+    fields = []
     for i in key_idxs:
         # codes_ok: grouping is a single-batch EQUALITY context, so
         # dictionary-encoded keys group on their codes (interned
         # dictionaries make code equality == value equality) instead
         # of decoding to byte matrices
-        keys.extend(equality_keys(normalize_floating(batch.columns[i]),
-                                  live, codes_ok=True))
-    perm = sort_permutation(keys, cap)
-    sorted_keys = [jnp.take(k, perm) for k in keys]
+        fields.extend(key_fields(normalize_floating(batch.columns[i]),
+                                 True, True, live, codes_ok=True))
+    # every key of the group in as few 32-bit words as its stamped
+    # ranges allow, ONE sort operand a pass (ops/common.py)
+    perm, words = sort_permutation_fields(fields, live, cap, by="group")
+    sorted_keys = [jnp.take(w, perm) for w in words]
     live_s = jnp.take(live, perm)
     eq = rows_equal_adjacent(sorted_keys)
     boundary = live_s & ~eq

@@ -41,9 +41,21 @@ def order_keys(batch: ColumnBatch, orders) -> List[jnp.ndarray]:
 
 
 def sort_batch(batch: ColumnBatch, orders) -> ColumnBatch:
-    from spark_rapids_tpu.ops.common import sort_permutation
+    """The batch in the order of `orders`: the keys packed into as few
+    32-bit words as their stamped ranges, dictionaries and widths
+    allow, one sort operand a pass (ops/common.py)."""
+    from spark_rapids_tpu.ops.common import (
+        key_fields,
+        sort_permutation_fields,
+    )
 
-    perm = sort_permutation(order_keys(batch, orders), batch.capacity)
+    live = batch.live_mask()
+    ctx = EvalContext(batch)
+    fields = []
+    for o in orders:
+        fields.extend(key_fields(o.expr.eval(ctx), o.ascending,
+                                 o.nulls_first, live))
+    perm, _ = sort_permutation_fields(fields, live, batch.capacity)
     return batch.gather(perm, batch.num_rows)
 
 

@@ -31,10 +31,11 @@ from jax import lax
 from spark_rapids_tpu.columnar.batch import ColumnBatch, DeviceColumn
 from spark_rapids_tpu.ops import segmented
 from spark_rapids_tpu.ops.common import (
+    key_fields,
     normalize_floating,
     orderable_keys,
     rows_equal_adjacent,
-    sort_permutation,
+    sort_permutation_fields,
 )
 
 
@@ -72,9 +73,16 @@ def sort_for_window(batch: ColumnBatch,
     for c, asc, nulls_first in order_cols:
         order_keys.extend(orderable_keys(c, asc, nulls_first, live))
 
-    all_keys = part_keys + order_keys
-    if all_keys:
-        perm = sort_permutation(all_keys, cap)
+    if part_keys or order_keys:
+        # the sort takes the keys packed (ops/common.py); the int64
+        # keys above only say which neighbours are equal
+        fields = []
+        for c in part_cols:
+            fields.extend(key_fields(normalize_floating(c), True, True,
+                                     live))
+        for c, asc, nulls_first in order_cols:
+            fields.extend(key_fields(c, asc, nulls_first, live))
+        perm, _ = sort_permutation_fields(fields, live, cap, by="window")
     else:
         perm = pos  # dead rows already trail in the original layout
     live_s = jnp.take(live, perm)

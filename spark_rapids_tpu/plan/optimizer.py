@@ -1,7 +1,8 @@
-"""Logical optimizations: scan column pruning + parquet predicate
-pushdown (the reference gets these from Spark's optimizer + its own
-row-group filtering, GpuParquetScan.scala:556; standalone we run a small
-rewrite pass before physical planning).
+"""Logical optimizations: predicates pushed through joins, scan
+column pruning + parquet predicate pushdown (the reference gets these
+from Spark's optimizer — PushPredicateThroughJoin, ColumnPruning — and
+its own row-group filtering, GpuParquetScan.scala:556; standalone we
+run a small rewrite pass before physical planning).
 """
 
 from __future__ import annotations
@@ -27,12 +28,16 @@ _CMP_OPS = {EqualTo: "=", LessThan: "<", LessThanOrEqual: "<=",
 _FLIP = {"=": "=", "<": ">", "<=": ">=", ">": "<", ">=": "<="}
 
 
-def optimize(plan: L.LogicalPlan) -> L.LogicalPlan:
+def optimize(plan: L.LogicalPlan, notes: Optional[dict] = None
+             ) -> L.LogicalPlan:
+    """`notes`, where given, counts what the rules did:
+    `pushedThroughJoin`, the conjuncts moved below a join."""
     from spark_rapids_tpu.plan.struct_keys import expand_struct_keys
 
-    new_children = [optimize(c) for c in plan.children]
+    new_children = [optimize(c, notes) for c in plan.children]
     plan = _with_children(plan, new_children)
     plan = expand_struct_keys(plan)
+    plan = _push_through_join(plan, notes)
     plan = _push_filters(plan)
     plan = _prune_scan_columns(plan)
     return plan
@@ -76,6 +81,77 @@ def _filter_tuple(e: Expression, schema: StructType
             return None
         return (schema.names[b.ordinal], _FLIP[op], a.value)
     return None
+
+
+def _conjunction(conjuncts: List[Expression]) -> Optional[Expression]:
+    from spark_rapids_tpu.expr import And
+
+    out = None
+    for c in conjuncts:
+        out = c if out is None else And(out, c)
+    return out
+
+
+def _movable(e: Expression) -> bool:
+    """A conjunct may move below a join unless it calls user code,
+    whose result for a row may depend on which rows it has seen."""
+    if type(e).__name__.endswith("UDF"):
+        return False
+    return all(_movable(c) for c in e.children)
+
+
+#: join type -> which inputs a conjunct over that input alone may move
+#: to: both for an inner join, the preserved side of an outer one (a
+#: filter on the null-extended side is not the filter before the
+#: join), the left of a semi, anti or existence join (their output IS
+#: the left's rows). A full join preserves both and filters neither.
+_PUSH_SIDES = {"inner": (True, True), "cross": (True, True),
+               "left": (True, False), "right": (False, True),
+               "left_semi": (True, False), "left_anti": (True, False),
+               "existence": (True, False), "full": (False, False)}
+
+
+def _push_through_join(plan: L.LogicalPlan, notes: Optional[dict]
+                       ) -> L.LogicalPlan:
+    """Filter over Join: each conjunct that reads one side only moves
+    below the join, down to that side's relation (Catalyst's
+    PushPredicateThroughJoin); the rest stays. The dimensions of a
+    star query are then filtered BEFORE they are build sides."""
+    if not (isinstance(plan, L.Filter)
+            and isinstance(plan.children[0], L.Join)):
+        return plan
+    from spark_rapids_tpu.exec.joins import remap_refs
+
+    join: L.Join = plan.children[0]
+    to_left, to_right = _PUSH_SIDES[join.join_type]
+    n_l = len(join.children[0].schema.fields)
+    n_r = len(join.children[1].schema.fields)
+    left, right, stay = [], [], []
+    for conj in _split_conjuncts(plan.condition):
+        refs = conj.references()
+        if not refs or not _movable(conj):
+            stay.append(conj)
+        elif to_left and max(refs) < n_l:
+            left.append(conj)
+        elif to_right and n_l <= min(refs) and max(refs) < n_l + n_r:
+            right.append(remap_refs(conj, lambda o: o - n_l))
+        else:
+            stay.append(conj)
+    if not left and not right:
+        return plan
+    if notes is not None:
+        notes["pushedThroughJoin"] = (notes.get("pushedThroughJoin", 0)
+                                      + len(left) + len(right))
+    sides = []
+    for child, moved in zip(join.children, (left, right)):
+        if moved:
+            # further down where the side is itself a join, and into
+            # the scan where it is a parquet file
+            child = _push_filters(_push_through_join(
+                L.Filter(_conjunction(moved), child), notes))
+        sides.append(child)
+    out = _with_children(join, sides)
+    return L.Filter(_conjunction(stay), out) if stay else out
 
 
 def _push_filters(plan: L.LogicalPlan) -> L.LogicalPlan:

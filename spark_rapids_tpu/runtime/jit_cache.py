@@ -113,14 +113,17 @@ def _make_entry(key: Tuple, build: Callable[[], Callable],
             if jitted is None:
                 jitted = jax.jit(build(), **jit_kwargs)
                 from spark_rapids_tpu.obs import events as obs_events
+                from spark_rapids_tpu.ops import common
 
                 t0 = time.perf_counter()
                 # what is decided while the program is traced and
                 # reported with every later dispatch stays with it
                 with obs_events.span("compile", kind=tag) as sp, \
-                        _segmented().noting_sum_lowerings() as noted:
+                        _segmented().noting_sum_lowerings() as noted, \
+                        common.noting_sorts() as sorts:
                     out = jitted(*args, **kwargs)
                     entry.sum_lowerings = noted
+                    entry.sort_lowerings = sorts
                     # async dispatch returns once tracing+compilation
                     # are done (execution overlaps) — the cold-start
                     # quantity
@@ -159,6 +162,13 @@ def sum_lowerings(key: Tuple) -> dict:
     when it was traced, read by every dispatch that hits the cache."""
     fn = _cache.get(_mem_key(key + _env_token()))
     return getattr(fn, "sum_lowerings", {})
+
+
+def sort_lowerings(key: Tuple) -> list:
+    """How the resident program for `key` lowered its sorts and
+    group-bys (ops/common.py `noting_sorts`), as `sum_lowerings`."""
+    fn = _cache.get(_mem_key(key + _env_token()))
+    return getattr(fn, "sort_lowerings", [])
 
 
 def cache_size() -> int:

@@ -376,10 +376,19 @@ def q12_chain():
             return jitted(*inputs)
         return call
 
+    from spark_rapids_tpu.exec import joins
+
     mp = pytest.MonkeyPatch()
     mp.setattr(jit_cache, "cached_jit", spy)
     mp.setattr(run, "session_conf",  # the tests' compile cache, not
                lambda config: dict(config["session_conf"]))  # benchmark/'s
+    # at SF10 `o_orderkey`'s 67M values are far more than the 983,040
+    # survivors x 24 steps that probe them, and the build side keeps its
+    # sorted index; the rehearsal's 30,000 orders would get a table of
+    # positions (exec/fused.py `build_table`), which is not the program
+    # the cell runs: no key range here, as at SF10's sizes
+    mp.setattr(joins.TpuBroadcastHashJoinExec, "build_key_range",
+               lambda self, right: None)
     try:
         res = run.run_cell("tpch_q12_join_resident", 2_147_483_777, 0.1,
                            False, rows=120_000, any_platform=True)
@@ -448,6 +457,168 @@ def test_q12s_own_chain_scatters_no_row(q12_chain_at_sf10):
     # the sweep: one loop whose carries are the bins, 32,768 rows a step
     assert [ln for ln in q12_chain_at_sf10.splitlines()
             if " while(" in ln and "f32[4,32768]" in ln]
+
+
+# ------------------------------------- the star's own programs (q3)
+
+STAR_PART = 3_670_016     # slots of one of SF10 store_sales' 8 parts
+STAR_ROWS = 2_000_000     # the rehearsal: parts of 262,144 slots, the
+#                           least at which both joins place their bets
+
+
+@pytest.fixture(scope="module")
+def q3_programs():
+    """TPC-DS q3 as `tpcds_star_resident` runs it, traced on this CPU
+    at 2M fact rows: {kind: (the function the engine hands to jit, the
+    inputs of one call)} of its SETTLED run — the second one, in which
+    the date join, having lost its bet (d_moy = 11 keeps 8% of the
+    fact), has yielded to the item join."""
+    import tempfile
+
+    from benchmark import run
+    from spark_rapids_tpu.api.session import TpuSparkSession
+    from spark_rapids_tpu.runtime import jit_cache
+
+    seen = {}
+    real = jit_cache.cached_jit
+
+    def spy(key, build, **kw):
+        jitted = real(key, build, **kw)
+
+        def call(*inputs):
+            # the chain with the joins, not the 1-part one that only
+            # renames the final aggregate's columns
+            if key[0] == "fused" and (key[1] != "chain"
+                                      or len(inputs) > 1):
+                seen[key[1]] = (build(), inputs)
+            return jitted(*inputs)
+        return call
+
+    conf = run.load_json(run.HERE, "configs",
+                         "tpcds_sf10_store_sales_star.json")
+    gen = run.load_module("datagen", conf["generator"])
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jit_cache, "cached_jit", spy)
+    spark = TpuSparkSession(dict(conf["session_conf"]))
+    try:
+        with tempfile.TemporaryDirectory() as tmp:
+            dirs = gen.generate(conf, 2_147_483_777, tmp, rows=STAR_ROWS)
+            tables = {t: spark.read.parquet(d).cache(storage="device")
+                      for t, d in dirs.items()}
+            q3 = run.load_module("queries", "tpcds_spec_q3")
+            df = q3.build(spark, tables)
+            df.collect_arrow()
+            assert spark.last_execution["join"]["rerunReasons"] == [
+                "survivorOverflow"]
+            seen.clear()
+            df.collect_arrow()
+            rec = spark.last_execution
+    finally:
+        spark.stop()
+        mp.undo()
+    assert run.not_fused(rec) == "" and rec["join"]["runs"] == 1
+    # the item join first (93 of its 102,000 rows pass), then the date
+    assert [j["buildRows"] < 1_000 for j in rec["join"]["joins"]] == [
+        True, False]
+    assert [j["bet"] for j in rec["join"]["joins"]] == ["buildFilter"] * 2
+    return seen
+
+
+def _sort_operands(lowered_text):
+    """Operands of every sort a program hands to XLA, from its lowered
+    (StableHLO) text: a key and the permutation are 2. (The TPU's
+    compiler adds an operand of its own to a stable sort.)"""
+    import re
+
+    return [len(m.group(1).split(","))
+            for m in re.finditer(r'"stablehlo.sort"\(([^)]*)\)',
+                                 lowered_text)]
+
+
+def _timed_compile(fn, *avals):
+    """-> (compiled, its lowered text, seconds of lower + compile)."""
+    import time
+
+    t = time.perf_counter()
+    with pytest.MonkeyPatch.context() as mp:  # `as_tpu`, module-wide
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = jax.jit(fn).lower(*avals)
+        c = lowered.compile()
+    return c, lowered.as_text(), time.perf_counter() - t
+
+
+def test_q3s_own_chain_at_sf10_sorts_one_operand_and_probes_by_position(
+        q3_programs, one_chip, no_persistent_cache):
+    """The chain program — both positional probes, both bets, the
+    3-key partial aggregate — at SF10's widths: a part of 3,670,016
+    slots, the item join's matches at 57,344, the date join's at 1,024.
+    The parent's could not be compiled in 37 minutes (PERF.md, PR 23:
+    one sort on seven operands); this one holds ONE sort on one key
+    operand and the permutation, a loop of two passes over 1,024
+    slots, and compiles in seconds. At the rehearsal's size the date
+    join searches a sorted index (a table of 4,194,304 positions for
+    2,097,152 probe slots is not worth writing): the build side is
+    given here as the table SF10's run makes."""
+    from spark_rapids_tpu.exec.fused import survivor_capacity
+    from spark_rapids_tpu.ops import joinops
+
+    fn, (probe, *builds) = q3_programs["chain"]
+    plan = fn.__kwdefaults__["_plan"]
+    small = probe.capacity
+    dims = {small: STAR_PART,
+            survivor_capacity(small): survivor_capacity(STAR_PART)}
+    assert dims == {262_144: 3_670_016, 4_096: 57_344}
+    at_sf10 = type(fn)(fn.__code__, fn.__globals__, fn.__name__,
+                       fn.__defaults__, fn.__closure__)
+    at_sf10.__kwdefaults__ = dict(fn.__kwdefaults__, _plan=[
+        dict(jp, probe="position", probeSteps=1,
+             **{k: dims.get(jp[k], jp[k]) for k in
+                ("probeSlots", "searchedSlots", "outputCapacity")})
+        for jp in plan])
+    assert [jp["outputCapacity"] for jp in
+            at_sf10.__kwdefaults__["_plan"]] == [57_344, 1_024]
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(tuple(dims.get(d, d) for d in a.shape), a.dtype,
+                           one_chip), tree)
+
+    def positions(bt):
+        (key,) = [c for c, f in zip(bt.batch.columns,
+                                    bt.batch.schema.fields)
+                  if f.name in ("i_item_sk", "d_date_sk")]
+        size = key.vrange[1] - key.vrange[0] + 1
+        scalar = _sds((), jnp.int32, one_chip)
+        return joinops.BuildPositions(
+            sds(bt.batch), _sds((size,), jnp.int32, one_chip),
+            _sds((), jnp.int64, one_chip), scalar, scalar)
+
+    tables = [positions(bt) for bt in builds]
+    assert [t.table.shape[0] for t in tables] == [131_072, 4_194_304]
+    c, lowered, seconds = _timed_compile(at_sf10, sds(probe), *tables)
+    assert _sort_operands(lowered) == [2]  # one key, and the permutation
+    (sort,) = [ln for ln in c.as_text().splitlines() if " sort(" in ln]
+    assert "u32[1024]" in sort and "[57344]" not in sort
+    assert seconds < 30, seconds         # 3.0-3.9 s when written
+    assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
+
+
+@pytest.mark.parametrize("kind, limit_s", [("agg", 15), ("sort", 15)])
+def test_q3s_own_final_aggregate_and_sort_compile_in_seconds(
+        q3_programs, one_chip, no_persistent_cache, kind, limit_s):
+    """The final aggregate over the 8 parts' 1,024 slots each and the
+    ORDER BY over its 8,192 (d_year, the sum descending, brand_id: an
+    int, an f32-ordered double and an int): the shapes ARE SF10's,
+    since the bets cut every part to the same 1,024 slots. Each is one
+    loop of one-operand passes; the parent's compiled for 1,606 s and
+    1,029 s (sandbox, PERF.md section 7, finding 1, at PR 23)."""
+    fn, inputs = q3_programs[kind]
+    sds = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), inputs)
+    c, lowered, seconds = _timed_compile(fn, *sds)
+    operands = _sort_operands(lowered)
+    assert operands and max(operands) == 2
+    assert seconds < limit_s, seconds    # 1.3-1.8 s and 0.9-1.1 s
 
 
 def test_expanded_join_gather_maps(one_chip, as_tpu):

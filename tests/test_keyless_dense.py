@@ -343,7 +343,7 @@ def test_keyed_aggregates_lower_as_before():
                         batch.num_rows)
     sorted_text = _lowered(_agg(), "_partial", plain)
     assert "scatter" in sorted_text and "dot_general" not in sorted_text
-    assert _sha(sorted_text) == "cb3300ba48a52d86"
+    assert _sha(sorted_text) == "e3e3b9ab85876a11"  # PR 33: packed sort
     assert segmented.dense_traced_reductions == dense0
 
 
