@@ -1327,6 +1327,12 @@ class FusedSingleChipExecutor:
                         "probeSlots": probe_slots,
                         "searchedSlots": searched,
                         "outputCapacity": cap})
+                    if bet:
+                        # reads a slot brought to the front pays for
+                        # its row id, in the widest mask searched
+                        out[-1]["rowIdReads"] = joinops.search_reads(
+                            probe_slots if "probeFilter" in bet
+                            else searched)
                 elif isinstance(nd, ops.TpuFilterExec):
                     filtered = True
                 elif isinstance(nd, ops.TpuExpandExec):
@@ -1392,8 +1398,8 @@ class FusedSingleChipExecutor:
                     jp["buildSlots"] = slots
                     by_position = isinstance(bt, joinops.BuildPositions)
                     jp["probe"] = "position" if by_position else "search"
-                    jp["probeSteps"] = 1 if by_position else max(
-                        1, slots.bit_length())
+                    jp["probeSteps"] = 1 if by_position \
+                        else joinops.search_reads(slots)
                     note_join(key, jp, [bt.num_rows])
                     if "probeFilter" in jp["bet"]:
                         bets.append(key)
@@ -1463,6 +1469,9 @@ class FusedSingleChipExecutor:
             gather = build_gather(jn.join_type)
             ranges = [jn.build_key_range(p) for p in parts]
             slots = sum(p.capacity for p in parts)
+            # log2 of the slots, though the search now reads a row of
+            # 128 keys a level (joinops.search_reads): what WRITING a
+            # table costs did not change, and both cells' choices hold
             steps = max(1, slots.bit_length())
             by_position = bool(ranges) and None not in ranges and (
                 max(hi for _, hi in ranges) - min(lo for lo, _ in ranges)

@@ -1,4 +1,4 @@
-"""Equi-join kernels: sorted-build binary search + two-phase gather maps.
+"""Equi-join kernels: sorted-build search + two-phase gather maps.
 
 cuDF builds device hash tables (`Table.innerJoinGatherMaps`,
 `GpuHashJoin.scala:403,490`). HLO has no dynamic hash tables, so the TPU
@@ -6,11 +6,12 @@ formulation is a *sort-based* hash join replacement with the same
 gather-map contract:
 
   phase 1 (jit, fixed shape): sort the build side by orderable join keys;
-    vectorized multi-key binary search gives each probe row its matching
-    build range [lo, hi) and count. Null join keys never match (SQL equi-
-    join semantics) — null-keyed build rows sort to the end and are
-    excluded by the live bound; null-keyed probe rows are forced to
-    count 0.
+    a vectorized search (a row of 128 keys a level over a one-array
+    key, a multi-key binary search else) gives each probe row its
+    matching build range [lo, hi) and count. Null join keys never
+    match (SQL equi-join semantics) — null-keyed build rows sort to
+    the end and are excluded by the live bound; null-keyed probe rows
+    are forced to count 0.
   host: read total match count, pick the output capacity bucket.
   phase 2 (jit, fixed shape per bucket): expand (lo, count) into
     (probe_idx, build_idx) gather maps via searchsorted over the count
@@ -245,11 +246,119 @@ def _tuple_cmp_at(build_keys: List[jnp.ndarray], mid: jnp.ndarray,
     return lt | ~decided  # equal counts as <=
 
 
+#: keys in one node of a search's tree: the chip's lane width
+_LANES = 128
+#: probes searched at a time: the rows they read are held, 64 MiB of
+#: 32-bit keys a level (a full-width probe of 7.9M slots would hold 4 GB)
+_PROBE_BLOCK = 1024 * _LANES
+
+
+def _as_rows(keys: jnp.ndarray, fill) -> jnp.ndarray:
+    """(n,) -> (ceil(n / 128), 128), the tail filled with `fill`."""
+    pad = -keys.shape[0] % _LANES
+    if pad:
+        keys = jnp.concatenate([keys, jnp.full((pad,), fill, keys.dtype)])
+    return keys.reshape(-1, _LANES)
+
+
+def search_reads(slots: int) -> int:
+    """Data-dependent reads one probe pays in `_count_below` over an
+    array of `slots` keys: the levels of its tree, one row each."""
+    reads, rows = 1, -(-max(slots, 1) // _LANES)
+    while rows > _LANES:
+        reads, rows = reads + 1, -(-rows // _LANES)
+    return reads
+
+
+def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
+                 bound: Optional[jnp.ndarray], upper: bool) -> jnp.ndarray:
+    """Per probe, how many positions `i < bound` hold `keys[i] < probe`
+    (`<=` with `upper`): the lower / upper bound of each probe in a
+    ONE-array key that is sorted on [0, bound) (`bound` None: all of
+    it). A search whose node is a row of 128 keys: the array read as
+    rows, each level above it the last keys of the rows below, the top
+    level (one row at most) compared with no read. A probe reads ONE
+    row a level (`search_reads`) and counts across its lanes, where a
+    binary search reads one key for each of log2(n) steps: the chip
+    charges a 4-byte read 7.1 ns and a 512-byte row 2.6 (PERF.md,
+    PR 34). Nothing is built ahead: the levels are strided slices made
+    here."""
+    n, nq = keys.shape[0], probe.shape[0]
+    if n == 0 or nq == 0:
+        return jnp.zeros((nq,), jnp.int32)
+    integer = jnp.issubdtype(keys.dtype, jnp.integer)
+    top = jnp.iinfo(keys.dtype).max if integer else jnp.inf
+    outside = None
+    if (integer and jnp.issubdtype(probe.dtype, jnp.integer)
+            and probe.dtype.itemsize > keys.dtype.itemsize):
+        # a wider probe is compared at the keys' width (a 64-bit
+        # compare is emulated, a lane at a time); one outside that
+        # width lies below or above every key
+        info = jnp.iinfo(keys.dtype)
+        outside = (probe < info.min, probe > info.max)
+        probe = jnp.clip(probe, info.min, info.max).astype(keys.dtype)
+    limit = jnp.int32(n) if bound is None else bound.astype(jnp.int32)
+    lanes = jnp.arange(_LANES, dtype=jnp.int32)
+
+    levels = [_as_rows(keys, top)]
+    while True:
+        last = levels[-1][:, -1]
+        if len(levels) == 1 and bound is not None:
+            # past the bound the array is not sorted: a row that
+            # reaches there ends the descent
+            ends = jnp.arange(last.shape[0], dtype=jnp.int32) * _LANES
+            last = jnp.where(ends + (_LANES - 1) < limit, last, top)
+        if last.shape[0] <= _LANES:
+            break
+        levels.append(_as_rows(last, top))
+
+    def below(held, q):
+        return held <= q if upper else held < q
+
+    def count(hit):
+        # the lanes that hit, as a product with ones: exact (128 at
+        # most, of 0 and 1, summed in f32), and a matrix product where
+        # a reduction across lanes costs the chip the same and the
+        # CPU backend, which the tests run on, seventy times as much
+        ones = jnp.ones((hit.shape[1],), jnp.bfloat16)
+        return lax.dot(hit.astype(jnp.bfloat16), ones,
+                       preferred_element_type=jnp.float32
+                       ).astype(jnp.int32)
+
+    def search(q):
+        q = q[:, None]
+        node = count(below(last[None, :], q))
+        for level in reversed(levels):
+            node = jnp.minimum(node, level.shape[0] - 1)
+            hit = below(jnp.take(level, node, axis=0, mode="clip"), q)
+            if level is levels[0] and bound is not None:
+                hit = hit & (node[:, None] * _LANES + lanes < limit)
+            node = node * _LANES + count(hit)
+        return node
+
+    if nq <= _PROBE_BLOCK:
+        found = search(probe)
+    else:
+        blocks = -(-nq // _PROBE_BLOCK)
+        padded = jnp.pad(probe, (0, blocks * _PROBE_BLOCK - nq))
+        found = lax.map(search, padded.reshape(blocks, _PROBE_BLOCK)
+                        ).reshape(-1)[:nq]
+    found = jnp.minimum(found, limit)
+    if outside is not None:
+        found = jnp.where(outside[0], 0, jnp.where(outside[1], limit, found))
+    return found
+
+
 def _binary_search(build_keys: List[jnp.ndarray],
                    probe_keys: List[jnp.ndarray], bound: jnp.ndarray,
                    build_cap: int, upper: bool) -> jnp.ndarray:
     """First index in [0, bound) where build[idx] >= probe (lower) or
-    > probe (upper); vectorized over probe rows."""
+    > probe (upper); vectorized over probe rows. A ONE-array key is
+    searched a row of 128 keys a level (`_count_below`); a tuple of
+    key arrays (strings packed to words, several columns) a key a
+    step."""
+    if len(build_keys) == 1:
+        return _count_below(build_keys[0], probe_keys[0], bound, upper)
     n = probe_keys[0].shape[0]
     lo = jnp.zeros(n, dtype=jnp.int32)
     hi = jnp.broadcast_to(bound.astype(jnp.int32), (n,))
@@ -339,13 +448,13 @@ def front_row_ids(keep: jnp.ndarray, capacity: int
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Row ids of the first `capacity` rows where `keep`, in order, and
     how many rows `keep` holds in all (more than `capacity`: they did
-    not fit). No full-width scatter or gather: a prefix sum of the mask
-    and a search of 1..capacity in it, `capacity` x log2(n) steps, the
-    shape `expand_gather_maps` uses. Ids past the total are clamped
-    garbage."""
+    not fit). No full-width scatter or gather: the mask's prefix sum is
+    a sorted array, and rank j's row id is how many of its entries lie
+    below j: `capacity` x `search_reads(n)` row reads
+    (`_count_below`). Ids past the total are clamped garbage."""
     csum = jnp.cumsum(keep.astype(jnp.int32))
     j = jnp.arange(1, capacity + 1, dtype=jnp.int32)
-    ids = jnp.searchsorted(csum, j, side="left").astype(jnp.int32)
+    ids = _count_below(csum, j, None, upper=False)
     return jnp.clip(ids, 0, keep.shape[0] - 1), csum[-1]
 
 

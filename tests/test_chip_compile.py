@@ -310,10 +310,10 @@ def test_build_index_moves_no_row_at_q12_width(one_chip, as_tpu):
 
 def test_late_lookup_join_at_q12_width(one_chip, as_tpu):
     """The chain's side of a lookup join (exec/fused.py `lookup_join`
-    over a BuildIndex): the survivors' one search, then
-    the build columns read at `perm[lo]` from the batch as it lies —
-    three gathers of 122,880 slots where the build side had six of
-    15.7M."""
+    over a BuildIndex): the survivors' row-ids and their one search,
+    a row of 128 keys a level, then the build columns read at
+    `perm[lo]` from the batch as it lies — three gathers of 122,880
+    slots where the build side had six of 15.7M."""
     from spark_rapids_tpu.exec.fused import survivor_capacity
     from spark_rapids_tpu.ops import joinops
 
@@ -342,17 +342,33 @@ def test_late_lookup_join_at_q12_width(one_chip, as_tpu):
     c = _compile(kernel, build, probe,
                  _sds((Q12_PART,), jnp.bool_, one_chip))
     assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
-    # the search loop carries the sorted keys in fast memory (`S(1)`),
-    # as it does over a BuildTable: the permutation, which only a gather
-    # reads, must not be what the compiler prefetches across the
-    # program in their place (joinops.rows_at; read on jax 0.9.0 /
-    # libtpu 0.0.34: without it the search is three times slower)
-    text = c.as_text()
-    (search,) = [ln for ln in text.splitlines()
-                 if " while(" in ln and f"s32[{Q12_BUILD}]" in ln]
-    assert f"s32[{Q12_BUILD}]{{0:T(1024)S(1)}}" in search
-    assert not [ln for ln in text.splitlines()
-                if "cross_program_prefetch_index" in ln and "bt_perm" in ln]
+    _searches_read_rows(c.as_text())
+
+
+def _searches_read_rows(text):
+    """What a Q12 chain program's time hangs on since PR 34, at SF10's
+    widths: the survivors' row-ids and the one search of the build
+    keys descend a tree of 128-key rows (joinops._count_below), ONE
+    `slice_sizes={1,128}` read a level, and no loop re-reads the 15.7M
+    keys a key a step (168.5 + 161.4 ms of a 509 ms query were two such
+    loops: PERF.md, PR 34). Where the compiler keeps the keys no longer
+    matters: no placement is asserted."""
+    from spark_rapids_tpu.ops import joinops
+
+    lines = text.splitlines()
+    assert not [ln for ln in lines
+                if " while(" in ln and f"s32[{Q12_BUILD}]" in ln]
+    gathers = [ln.split(" = ")[1] for ln in lines if " gather(" in ln]
+    rows = [g for g in gathers if "slice_sizes={1,128}" in g]
+    assert len(rows) == (joinops.search_reads(Q12_PART)
+                         + joinops.search_reads(Q12_BUILD))
+    assert all(g.startswith("s32[122880,128]") for g in rows)
+    # every other gather reads one element a survivor; of 32-bit
+    # integers that is the build key at `lo` and at `lo + 1` and the
+    # permutation at `lo` — the row-ids read none
+    assert all("slice_sizes={1}" in g for g in gathers if g not in rows)
+    assert len([g for g in gathers
+                if g not in rows and g.startswith("s32[")]) <= 3
 
 
 @pytest.fixture(scope="module")
@@ -401,7 +417,8 @@ def q12_chain():
 @pytest.fixture(scope="module")
 def q12_chain_at_sf10(q12_chain, one_chip, no_persistent_cache):
     """The program the cell runs, lowered again at SF10's widths and
-    compiled for the described chip: its text."""
+    compiled for the described chip: (its text, the seconds that
+    took)."""
     from spark_rapids_tpu.exec.fused import survivor_capacity
 
     fn, (probe, build) = q12_chain
@@ -420,27 +437,21 @@ def q12_chain_at_sf10(q12_chain, one_chip, no_persistent_cache):
             lambda a: _sds(tuple(slots if d == small else d
                                  for d in a.shape), a.dtype, one_chip), tree)
 
-    with pytest.MonkeyPatch.context() as mp:  # `as_tpu`, module-wide
-        mp.setattr(jax, "default_backend", lambda: "tpu")
-        c = _compile(at_sf10, widened(probe, probe.capacity, Q12_PART),
-                     widened(build, build.capacity, Q12_BUILD))
+    c, _, seconds = _timed_compile(
+        at_sf10, widened(probe, probe.capacity, Q12_PART),
+        widened(build, build.capacity, Q12_BUILD))
     assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
-    return c.as_text()
+    return c.as_text(), seconds
 
 
-def test_q12s_own_chain_searches_keys_held_in_fast_memory(
-        q12_chain_at_sf10):
-    """The cell's own chain program at SF10's widths: its
-    search loop carries the 63 MB of sorted build keys in fast memory
-    (`S(1)`). 330 ms of a 509 ms query hang on that placement, which
-    is the compiler's to make (joinops.rows_at; PERF.md, PR 30): with
-    the keys in HBM the search takes 21 ns a slot and step, not 7.1.
-    Read on jax 0.9.0 / libtpu 0.0.34."""
-    import re
-
-    (search,) = [ln for ln in q12_chain_at_sf10.splitlines()
-                 if " while(" in ln and f"s32[{Q12_BUILD}]" in ln]
-    assert re.search(rf"s32\[{Q12_BUILD}\]\{{0:T\(1024\)S\(1\)\}}", search)
+def test_q12s_own_chain_searches_a_row_a_level(q12_chain_at_sf10):
+    """The cell's own chain program at SF10's widths holds the row
+    reads of `_searches_read_rows` and nothing of the two loops, and
+    the compiler takes it in seconds (each loop's program compiled
+    slower than the tree that replaced it)."""
+    text, seconds = q12_chain_at_sf10
+    _searches_read_rows(text)
+    assert seconds < 60
 
 
 def test_q12s_own_chain_scatters_no_row(q12_chain_at_sf10):
@@ -451,11 +462,12 @@ def test_q12s_own_chain_scatters_no_row(q12_chain_at_sf10):
     PR 32) are gone, and the one scatter left moves no row: it
     brings the occupied bins to the front (`dense_bin_perm`, 1,024
     slots of 32 bits)."""
-    scatters = [ln.split(" = ")[1] for ln in q12_chain_at_sf10.splitlines()
+    text, _ = q12_chain_at_sf10
+    scatters = [ln.split(" = ")[1] for ln in text.splitlines()
                 if " scatter(" in ln]
     assert len(scatters) == 1 and scatters[0].startswith("s32[1024]")
     # the sweep: one loop whose carries are the bins, 32,768 rows a step
-    assert [ln for ln in q12_chain_at_sf10.splitlines()
+    assert [ln for ln in text.splitlines()
             if " while(" in ln and "f32[4,32768]" in ln]
 
 
@@ -599,6 +611,11 @@ def test_q3s_own_chain_at_sf10_sorts_one_operand_and_probes_by_position(
     assert _sort_operands(lowered) == [2]  # one key, and the permutation
     (sort,) = [ln for ln in c.as_text().splitlines() if " sort(" in ln]
     assert "u32[1024]" in sort and "[57344]" not in sort
+    # both bets' row ids read a row of the mask's prefix sum a level
+    # (the part's 3,670,016 slots, then the 57,344 that were left)
+    assert len([ln for ln in c.as_text().splitlines()
+                if " gather(" in ln and "slice_sizes={1,128}" in ln]) == (
+        joinops.search_reads(STAR_PART) + joinops.search_reads(57_344))
     assert seconds < 30, seconds         # 3.0-3.9 s when written
     assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
 

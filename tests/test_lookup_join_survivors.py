@@ -122,9 +122,13 @@ def test_survivor_join_equals_full_width_and_plain_join(
         assert j["lowering"] == "lookupSurvivors"
         assert (j["probeSlots"], j["searchedSlots"],
                 j["outputCapacity"]) == (65_536, CAPACITY, CAPACITY)
+        # a survivor's row id: two row reads in the prefix sum of the
+        # 65,536-slot mask (512 rows of 128, their last keys 4 rows)
+        assert (j["bet"], j["rowIdReads"]) == ("probeFilter", 2)
     else:  # no mask below the join, or a bet that was lost
         assert j["lowering"] == "lookup"
         assert j["searchedSlots"] == j["probeSlots"] == 65_536
+        assert "rowIdReads" not in j
 
     # the full-width lowering, in a session of its own
     monkeypatch.setattr(fused, "survivor_capacity", lambda n: None)

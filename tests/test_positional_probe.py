@@ -110,7 +110,9 @@ def test_join_by_position_equals_plain_join(spark, how, keys):
     assert rec["join"]["rerunReasons"] == []
     assert j["lowering"] == "lookup" and j["bet"] == ""
     if keys == "sparse":
-        assert (j["probe"], j["probeSteps"]) == ("search", 17)
+        # 65,536 sorted keys are 512 rows of 128, whose last keys are 4
+        # rows: two row reads under a top of 4 (joinops.search_reads)
+        assert (j["probe"], j["probeSteps"]) == ("search", 2)
     else:
         assert (j["probe"], j["probeSteps"]) == ("position", 1)
     assert j["searchedSlots"] == j["probeSlots"] == 65_536
@@ -143,9 +145,13 @@ def test_a_filtered_build_side_bets_on_the_joins_matches(spark, how, keys):
         assert (j["lowering"], j["bet"]) == ("lookupSurvivors",
                                              "buildFilter")
         assert j["outputCapacity"] == CAPACITY
+        # each match brought to the front read two rows of the mask's
+        # prefix sum for its row id
+        assert j["rowIdReads"] == 2
     else:
         assert (j["lowering"], j["bet"]) == ("lookup", "")
         assert j["outputCapacity"] == 65_536
+        assert "rowIdReads" not in j
     # the filter went into the build side's own program: no program
     # that only compacts the rows it keeps
     assert j["buildRows"] == sum(
