@@ -88,6 +88,15 @@ def _count_nodes(node) -> int:
     return 1 + sum(_count_nodes(c) for c in node.children)
 
 
+def _count_swapped(node) -> int:
+    """Inner joins that build the child written on the LEFT, the one
+    with fewer rows (plan/overrides.py `_convert_join`); a right outer
+    join run as a left outer one is not among them."""
+    return ((getattr(node, "build_side", None) == "left"
+             and node.join_type == "inner")
+            + sum(_count_swapped(c) for c in node.children))
+
+
 def _pin_query_time(plan):
     """Replace current_date/current_timestamp markers with ONE literal
     per query (Spark pins both at query start), applied at physical
@@ -738,7 +747,7 @@ class DataFrame:
 
         rec = {"engine": None, "fallbacks": [], "compile": None,
                "degradations": [], "scheduler": None, "plan": None,
-               "join": None, "agg": None, "sort": None}
+               "join": None, "agg": None, "groups": None, "sort": None}
         self._last_exec = rec
         self.session.last_execution = rec
         # admission front door (runtime/admission.py): the OUTERMOST
@@ -844,7 +853,8 @@ class DataFrame:
             self._optimizer_notes = None  # a prebuilt plan leaves none
             phys, meta = self._physical()
             rec["plan"] = dict(self._optimizer_notes or {},
-                               nodes=_count_nodes(phys))
+                               nodes=_count_nodes(phys),
+                               buildSidesSwapped=_count_swapped(phys))
             sp.set(**rec["plan"])
         # structured twin of the NOT_ON_TPU explain: one placement
         # event per plan node, with the verbatim fallback reason —
@@ -1028,6 +1038,7 @@ class DataFrame:
                             ex.last_compile_metrics["variantCount"]
                     rec["join"] = ex.last_join_metrics
                     rec["agg"] = ex.last_agg_metrics
+                    rec["groups"] = ex.last_group_metrics
                     rec["sort"] = ex.last_sort_metrics
                     breaker.record_success(fkey)
                     return ran("fused", out)

@@ -105,7 +105,8 @@ def rewrite_chain(nodes: list) -> Optional[list]:
     if any(not f.jittable or isinstance(f, First) for f in fns):
         return None
     join_idx = [i for i, n in enumerate(nodes[:-1])
-                if isinstance(n, J.TpuBroadcastHashJoinExec)]
+                if isinstance(n, (J.TpuBroadcastHashJoinExec,
+                                  J.TpuShuffledHashJoinExec))]
     if not join_idx:
         return None
     ji = join_idx[-1]
@@ -213,6 +214,10 @@ def rewrite_chain(nodes: list) -> Optional[list]:
     lj_b = J.TpuBroadcastHashJoinExec(
         agg_a, build, lj.join_type, lkeys_b, list(lj.right_keys),
         join_schema, conf_)
+    # for the join's record: the sides are the planner's
+    lj_b.build_side = getattr(lj, "build_side", "right")
+    lj_b.chosen_by = getattr(lj, "chosen_by", "written")
+    lj_b.origin = lj  # whose uniqueness bet this join places
     rep.append(lj_b)
     na = len(afields)
 

@@ -76,6 +76,10 @@ from spark_rapids_tpu.sqltypes import StringType, StructType
 # buckets) because padding bytes cross the host->device link and sit
 # in HBM (what the granularity is worth on a chip: not measured)
 _UPLOAD_ALIGN = 1 << 16
+# rows from which a PLAIN file's batch is padded to its bucket: below,
+# `bucket_capacity`'s steps are the 64Ki floor, up to 2^16 slots of
+# padding for a table of a few thousand rows
+_PLAIN_BUCKET_ROWS = 1 << 20
 
 
 class FusedCompileError(NotImplementedError):
@@ -85,9 +89,30 @@ class FusedCompileError(NotImplementedError):
 
 class LookupUniquenessLost(Exception):
     """The lookup-join lowering's unique-build-key bet failed (a probe
-    row saw >1 matches). Internal to the fused retry loop: the re-run
-    keeps the same capacity factors but lowers joins via the expanded
-    blocking path."""
+    row saw >1 matches). Internal to the fused retry loop: `joins`
+    holds (plan key, "unique") of the inner and left lookup joins of
+    the programs that saw it, the re-run keeps the same capacity
+    factors but lowers those via the expanded blocking path, and the
+    executor's `wide_joins` remembers them."""
+
+    def __init__(self, joins):
+        super().__init__("duplicate build keys; re-lowering joins "
+                         "expanded")
+        self.joins = joins
+
+
+class GroupOverflow(Exception):
+    """A final aggregate found more groups than the capacity it shrinks
+    its output to. Internal to the fused retry loop: `aggs` holds the
+    plan keys of the aggregates that overflowed, the re-run gives those
+    four times the capacity (only their programs and the ones after
+    them recompile), and the executor's `wide_joins` remembers the
+    capacity that held."""
+
+    def __init__(self, aggs):
+        super().__init__("more groups than a final aggregate's "
+                         "capacity; re-running it larger")
+        self.aggs = aggs
 
 
 class PushdownOverflow(Exception):
@@ -111,27 +136,37 @@ class SurvivorOverflow(Exception):
         self.joins = joins
 
 
-def _check_host_flags(host: np.ndarray, n_ovf: int,
-                      n_uniq: int = 0, n_push: int = 0,
+def _check_host_flags(host: np.ndarray, ovf_aggs: tuple,
+                      uniq_joins: tuple = (), n_push: int = 0,
                       survivor_joins: tuple = ()) -> None:
     """host = [capacity | uniqueness | pushdown | survivors | ansi
-    3-vectors]. A lost survivor bet wins: its run dropped rows, so
-    every other flag is re-checked by the re-run on the full data.
-    Then capacity overflow, then the lookup-uniqueness and pushdown
-    re-lowering retries, then ANSI raises per error class."""
+    3-vectors]. `ovf_aggs` names, for each capacity flag, the final
+    aggregate whose shrink it is (else None); `uniq_joins`, for each
+    uniqueness flag, the joins of its program that made the bet. A
+    lost survivor bet wins: its run dropped rows, so every other flag
+    is re-checked by the re-run on the full data. Then capacity
+    overflow (a final aggregate's own where no other program
+    overflowed), then the lookup-uniqueness and pushdown re-lowering
+    retries, then ANSI raises per error class."""
     from spark_rapids_tpu.expr.ansicheck import raise_host
 
+    n_ovf, n_uniq = len(ovf_aggs), len(uniq_joins)
     flagged = n_ovf + n_uniq + n_push
     lost = host[flagged:flagged + len(survivor_joins)]
     if bool(np.any(lost)):
         raise SurvivorOverflow(
             {k for k, f in zip(survivor_joins, lost) if f})
-    if bool(np.any(host[:n_ovf])):
+    over = [agg for agg, f in zip(ovf_aggs, host[:n_ovf]) if f]
+    if over:
+        if None not in over:
+            raise GroupOverflow(set(over))
         raise TpuSplitAndRetryOOM(
             "fused program capacity overflow; recompiling larger")
-    if bool(np.any(host[n_ovf:n_ovf + n_uniq])):
+    dup = host[n_ovf:n_ovf + n_uniq]
+    if bool(np.any(dup)):
         raise LookupUniquenessLost(
-            "duplicate build keys; re-lowering joins expanded")
+            {(k, "unique") for ks, f in zip(uniq_joins, dup) if f
+             for k in ks})
     if bool(np.any(host[n_ovf + n_uniq:n_ovf + n_uniq + n_push])):
         raise PushdownOverflow(
             "probe join-key cardinality exceeds group capacity; "
@@ -250,6 +285,29 @@ def _narrowed_host_batch(table: pa.Table, capacity: Optional[int],
             sum(c.device_size_bytes() for c in cols))
 
 
+def same_ranges(parts: List[ColumnBatch]) -> List[ColumnBatch]:
+    """The parts of ONE source with every column's stamped value range
+    made the envelope of its parts' ranges. A range is static metadata
+    of a traced program (it sizes packed sort keys and tables of
+    positions), so parts that differ in nothing else would each trace
+    and compile a program of their own: a table clustered by its key
+    (TPC-H `lineitem` by `l_orderkey`) stamps another power-of-two
+    envelope on every other file, and the 8 parts of one shape compiled
+    the chain above them four times, 20 s each (PERF.md, PR 35). No
+    data moves."""
+    if len(parts) < 2:
+        return parts
+    out = [list(p.columns) for p in parts]
+    for i in range(len(out[0])):
+        ranges = {p.columns[i].vrange for p in parts}
+        if len(ranges) > 1 and None not in ranges:
+            env = (min(lo for lo, _ in ranges), max(hi for _, hi in ranges))
+            for cols in out:
+                cols[i] = cols[i].replace(vrange=env)
+    return [ColumnBatch(p.schema, cols, p.num_rows)
+            for p, cols in zip(parts, out)]
+
+
 def widen_traced(batch: ColumnBatch) -> ColumnBatch:
     """In-trace inverse of the narrowed upload: restore each column's
     logical dtype (free relative to HBM bandwidth; fused with the first
@@ -298,6 +356,48 @@ def survivor_capacity(n: int) -> Optional[int]:
     return cap if cap * 4 <= n else None
 
 
+#: A filter that keeps this many times the survivors' share or more by
+#: its own columns' stamped ranges is a bet not worth placing: its loss
+#: costs a run and a compile of every program above it.
+_HOPELESS = 4
+
+
+def filter_share(condition, batch: ColumnBatch) -> Optional[float]:
+    """The share of `batch`'s rows that `condition` keeps, by what the
+    host knows without a sync: every conjunct an integer or date
+    column against a literal, each taken for uniform over the range
+    its upload stamped (`_narrow`: a power-of-two envelope) and
+    independent of the others. None where a conjunct is anything else:
+    two columns compared, a string, a function — which may keep any
+    share (TPC-H Q12's keeps 0.5 % where its date range alone keeps
+    14 %)."""
+    from spark_rapids_tpu.plan.optimizer import (
+        _filter_tuple,
+        _split_conjuncts,
+    )
+
+    share = 1.0
+    for conj in _split_conjuncts(condition):
+        found = _filter_tuple(conj, batch.schema)
+        if found is None:
+            return None
+        name, op, value = found
+        col = batch.columns[batch.schema.names.index(name)]
+        if (col.vrange is None or isinstance(value, bool)
+                or not isinstance(value, (int, np.integer))):
+            return None
+        lo, hi = col.vrange
+        below = min(max((value - lo) / (hi - lo + 1), 0.0), 1.0)
+        share *= {"<": below, "<=": below, ">": 1.0 - below,
+                  ">=": 1.0 - below, "=": 1.0 / (hi - lo + 1)}[op]
+    return share
+
+
+#: what the planner calls a join decides nothing on one chip, where
+#: every partition is co-resident and an exchange passes through: a
+#: shuffled hash join takes the lookup lowering as a broadcast one does
+_HASH_JOINS = (J.TpuBroadcastHashJoinExec, J.TpuShuffledHashJoinExec)
+
 #: lookup joins that read no build column: row-preserving whatever the
 #: build keys hold, and nothing of the build side's payload is moved
 _NO_BUILD_COLUMN = ("left_semi", "left_anti", "existence")
@@ -340,9 +440,14 @@ class FusedSingleChipExecutor:
         from spark_rapids_tpu.config import rapids_conf as rc
 
         self.conf = conf
-        #: plan keys of the lookup joins that lost their survivor bet
-        #: (SurvivorOverflow): never placed again by whoever owns the
-        #: set, which is the session (api/dataframe.py)
+        #: the bets this plan's programs lost, never placed again by
+        #: whoever owns the set, which is the session
+        #: (api/dataframe.py): the plan key of a lookup join that lost
+        #: its survivor bet (SurvivorOverflow; `(key, "matches")` the
+        #: bet on its own matches), `(key, "unique")` of one whose build
+        #: keys were not unique (LookupUniquenessLost), `("groups",
+        #: key, capacity)` of a final aggregate that found more groups
+        #: than it shrank to (GroupOverflow)
         self._wide_joins = wide_joins if wide_joins is not None else set()
 
         def c(entry):
@@ -378,6 +483,12 @@ class FusedSingleChipExecutor:
         #: sorted nothing: session.last_execution["sort"]
         self.last_sort_metrics = None
         self._run_sorts: List[dict] = []
+        #: the settled run's final aggregates, one {"capacity",
+        #: "found"} each in plan order: the slots its output was shrunk
+        #: to and the groups it found, or None where it had none:
+        #: session.last_execution["groups"]
+        self.last_group_metrics = None
+        self._run_groups: List[dict] = []
 
     # --- source preparation (once; survives expansion retries) ---
 
@@ -398,8 +509,14 @@ class FusedSingleChipExecutor:
                           parent=None) -> Optional[ColumnBatch]:
         """Device-direct scan of one PLAIN parquet file
         (io/parquet_plain.py): page payloads become zero-copy typed
-        views, integers narrow for the link, capacity == rows so no pad
-        copy touches the big float columns. None -> general reader."""
+        views, integers narrow for the link. A file of a million rows
+        or more takes the capacity of its rows' bucket, as the general
+        reader's batches do (`bucket_capacity`: TPC-H `lineitem`'s
+        eight files at SF10 hold 7,498,257 or 7,498,256 rows, and every
+        program above them would compile twice); a smaller one, one
+        that fills its bucket, or any with bucketing off keeps capacity
+        == rows and no pad copy touches the columns. None -> general
+        reader."""
         from spark_rapids_tpu.io.parquet_plain import read_plain_columns
         from spark_rapids_tpu.obs import events as obs_events
         from spark_rapids_tpu.obs import telemetry
@@ -413,12 +530,19 @@ class FusedSingleChipExecutor:
             if cols_np is None:
                 return None
             n = len(cols_np[names[0]])
+            cap = bucket_capacity(n) \
+                if self._shape_buckets and n >= _PLAIN_BUCKET_ROWS else n
+            valid = np.zeros(cap, dtype=np.bool_)
+            valid[:n] = True
             cols: List[DeviceColumn] = []
             for f in scan.schema.fields:
                 vals, vrange = _narrow(cols_np[f.name])
-                cols.append(DeviceColumn(
-                    f.dataType, vals, np.ones(n, dtype=np.bool_),
-                    vrange=vrange))
+                if cap != n:
+                    padded = np.zeros(cap, dtype=vals.dtype)
+                    padded[:n] = vals
+                    vals = padded
+                cols.append(DeviceColumn(f.dataType, vals, valid,
+                                         vrange=vrange))
             nbytes = sum(c.device_size_bytes() for c in cols)
             sp.set(rows=n, bytes=nbytes)
         return telemetry.put_watched(
@@ -505,7 +629,7 @@ class FusedSingleChipExecutor:
                     raise FusedCompileError("source exceeds HBM budget")
                 ps = [upload_narrowed(table, bucket=self._shape_buckets)]
             total += sum(b.device_size_bytes() for b in ps)
-            parts[id(s)] = ps
+            parts[id(s)] = same_ranges(ps)
         if total * 4 > self._hbm_budget():
             raise FusedCompileError("working set exceeds HBM budget")
         self._src_parts = parts
@@ -582,11 +706,12 @@ class FusedSingleChipExecutor:
     def _run_with_retry(self, phys: PhysicalPlan, as_parts: bool):
         """One settled run under the retry loop; returns
         (result, (expansion, group_cap, use_lookup)) at the settings
-        that succeeded. Capacity overflow doubles the factors; a lost
-        lookup-uniqueness bet only flips joins to the expanded blocking
-        lowering (same factors — nothing else recompiles bigger); a
-        lost survivor bet lowers that join at full width, for as long
-        as `wide_joins` lives."""
+        that succeeded. Capacity overflow doubles the factors; a final
+        aggregate's own overflow raises its capacity alone; a lost
+        lookup-uniqueness bet only flips the joins that made it to the
+        expanded blocking lowering (same factors — nothing else
+        recompiles bigger); a lost survivor bet lowers that join at
+        full width; each for as long as `wide_joins` lives."""
         expansion, group_cap = self._expansion, self._group_cap
         use_lookup = use_pushdown = True
         reruns: List[str] = []
@@ -600,6 +725,7 @@ class FusedSingleChipExecutor:
                         use_pushdown))
                 self._record_joins(reruns)
                 self.last_agg_metrics = dict(self._run_agg) or None
+                self.last_group_metrics = self._run_groups or None
                 self.last_sort_metrics = {
                     "maxKeyOperands": max(
                         s["operands"] for s in self._run_sorts),
@@ -609,9 +735,16 @@ class FusedSingleChipExecutor:
             except SurvivorOverflow as e:
                 self._wide_joins.update(e.joins)
                 reruns.append("survivorOverflow")
-            except LookupUniquenessLost:
-                use_lookup = False
+            except LookupUniquenessLost as e:
+                if e.joins <= self._wide_joins:
+                    use_lookup = False  # nothing new to learn: all of them
+                self._wide_joins.update(e.joins)
                 reruns.append("uniquenessLost")
+            except GroupOverflow as e:
+                self._wide_joins.update(
+                    ("groups", k, 4 * self._final_cap(k, group_cap))
+                    for k in e.aggs)
+                reruns.append("groupOverflow")
             except PushdownOverflow:
                 use_pushdown = False
                 reruns.append("pushdownOverflow")
@@ -621,6 +754,18 @@ class FusedSingleChipExecutor:
                 expansion *= 2
                 group_cap *= 4
                 reruns.append("capacityOverflow")
+
+    def _known_cap(self, key) -> int:
+        """The largest capacity the session remembers for the final
+        aggregate with plan key `key`, or 0: it has not run yet."""
+        return max([0] + [k[2] for k in self._wide_joins
+                          if k[0] == "groups" and k[1] == key])
+
+    def _final_cap(self, key, group_cap: int) -> int:
+        """What that aggregate shrinks its output to: `group_cap`, or
+        what the first look at its rows, or an overflow of its own,
+        has brought it to."""
+        return max(group_cap, self._known_cap(key))
 
     def _record_joins(self, reruns: List[str]) -> None:
         """The settled run's joins -> `last_join_metrics`, one `join`
@@ -668,6 +813,8 @@ class FusedSingleChipExecutor:
                 sp.set(join=self.last_join_metrics)
             if self.last_agg_metrics is not None:
                 sp.set(agg=self.last_agg_metrics)
+            if self.last_group_metrics is not None:
+                sp.set(groups=self.last_group_metrics)
             if self.last_sort_metrics is not None:
                 sp.set(sort=self.last_sort_metrics)
             return out
@@ -766,17 +913,19 @@ class FusedSingleChipExecutor:
 
     def _is_lookup_join(self, node: PhysicalPlan,
                         use_lookup: bool) -> bool:
-        """Broadcast equi-joins that lower as a ROW-PRESERVING lookup
-        inside the per-partition chain: each probe row gathers its
+        """Equi-joins that lower as a ROW-PRESERVING lookup inside the
+        per-partition chain, whatever the planner called them
+        (`_HASH_JOINS`): each probe row gathers its
         single build match (or its absence becomes a pending-mask /
         null-validity fact), so the join needs NO expansion buffer and
         fuses with the downstream aggregate — the star-schema shape.
         semi/anti/existence are row-preserving unconditionally;
         inner/left additionally assume UNIQUE build keys, checked by a
-        dedicated uniqueness flag — a duplicate-key build re-runs with
-        `use_lookup=False` (same capacity factors) and lowers via the
-        expanded blocking path (`emit_blocking`)."""
-        if not isinstance(node, J.TpuBroadcastHashJoinExec) \
+        dedicated uniqueness flag — a duplicate-key build re-runs
+        (same capacity factors) with that join lowered via the
+        expanded blocking path (`emit_blocking`), which `_run`'s
+        `is_lookup` then keeps it on (`wide_joins`)."""
+        if not isinstance(node, _HASH_JOINS) \
                 or node.condition is not None:
             return False
         if not self._lookup_conf:
@@ -799,16 +948,20 @@ class FusedSingleChipExecutor:
         )
         from spark_rapids_tpu.runtime.jit_cache import cached_jit
 
-        flags: List[jnp.ndarray] = []       # capacity overflow, scalar
-        uniq_flags: List[jnp.ndarray] = []  # lookup uniqueness, scalar
+        flags: List[tuple] = []       # (final agg's key | None, scalar)
+        uniq_flags: List[tuple] = []  # (its joins' keys, scalar)
         push_flags: List[jnp.ndarray] = []  # pushdown shrink, scalar
         surv_flags: List[tuple] = []        # (join key, scalar): lost bet
         ansi_flags: List[jnp.ndarray] = []  # (3,) [arith, div0, cast]
         # what each join of this run did, by its plan key, summed over
         # the parts; `buildRows` holds device scalars until the fetch
         joins: Dict[tuple, dict] = {}
+        # the final aggregates: their capacity and, a device scalar
+        # until the fetch, the groups they found
+        groups: List[dict] = []
         plan_keys: Dict[int, tuple] = {}
         self._run_joins = []
+        self._run_groups = []
         self._run_agg = collections.Counter()
         self._run_sorts = []
         ansi_on = self._ansi
@@ -851,7 +1004,8 @@ class FusedSingleChipExecutor:
 
         def dispatch(name, sp, key_tag, nodes_key, fn, inputs,
                      uses_expansion=False, uses_group_cap=False,
-                     uses_ansi=False, survivor_joins=()):
+                     uses_ansi=False, survivor_joins=(), uniq_joins=(),
+                     final_agg=None):
             # chaos site device.dispatch: an injected fault here is the
             # fused engine "dying mid-dispatch"; the dispatch ladder
             # (api/dataframe.py) demotes the query to the eager engine
@@ -874,7 +1028,9 @@ class FusedSingleChipExecutor:
             # multiplied variants at 482 s of cold start.
             key = ("fused", key_tag, nodes_key,
                    expansion if uses_expansion else None,
-                   group_cap if uses_group_cap else None,
+                   # True: the run's; a number: a final aggregate's own
+                   (group_cap if uses_group_cap is True
+                    else uses_group_cap or None),
                    bool(uses_ansi), shapes_key(inputs))
             from spark_rapids_tpu.runtime import compile_cache as cc
             from spark_rapids_tpu.runtime import jit_cache as jc
@@ -889,6 +1045,13 @@ class FusedSingleChipExecutor:
                     cc.stats.on_hit()
                 else:
                     m["programsRequested"] += 1
+                    # a compile is seconds, a fetch of the flags so far
+                    # is not: a run that has already lost a bet stops
+                    # HERE, before it compiles programs for shapes the
+                    # re-run will not have (a join's survivors, an
+                    # aggregate's capacity)
+                    if flags and not defer_flags:
+                        _check_host_flags(*flags_so_far())
             sp.set(cacheHit=hit)
             # the XLA module is `jit_<name>`: what the device trace
             # calls this program
@@ -917,9 +1080,9 @@ class FusedSingleChipExecutor:
             # one lost-bet flag for each of `survivor_joins`, then what
             # nothing reads (joinops.rows_at)
             fl = jnp.asarray(fl).reshape(-1)
-            flags.append(fl[0])
+            flags.append((final_agg, fl[0]))
             if fl.shape[0] > 1:
-                uniq_flags.append(fl[1])
+                uniq_flags.append((tuple(uniq_joins), fl[1]))
                 push_flags.append(fl[2])
             surv_flags.extend(
                 (k, fl[3 + i]) for i, k in enumerate(survivor_joins))
@@ -940,7 +1103,7 @@ class FusedSingleChipExecutor:
             return ansicheck.flags_vec(list(exprs), b, live)
 
         def chain_traced(nodes, batch, builds=(), ansi_live=False,
-                         join_plan=()):
+                         join_plan=(), masked=None):
             """Apply a bottom-up list of per-partition operators inside
             one trace; returns (batch, overflow). `builds` holds the
             already-materialized build batch for each lookup join in
@@ -949,6 +1112,14 @@ class FusedSingleChipExecutor:
             expressions can raise traces to the SAME program with ANSI
             on or off, and keying on the hoisted fact instead of the
             session flag lets the two share the compiled executable.
+
+            `masked`: the chain feeds a join's build side, whose index
+            takes a mask of live rows and moves no row itself: the
+            output is then (the batch UNCOMPACTED, its live mask), and
+            of its plain columns only the ordinals in `masked` are
+            computed (the others are zeros nobody reads: a gather of a
+            column the probe side never asks for is most of such a
+            chain's time).
 
             Filters are carried as a PENDING MASK rather than a physical
             compaction: an aggregation consumes the mask directly (its
@@ -992,22 +1163,36 @@ class FusedSingleChipExecutor:
                 # `at`: the matching build row itself, or its place in
                 # the sorted index
                 by_position = isinstance(bt, joinops.BuildPositions)
-                at, matched, dup = (
-                    joinops.probe_positions if by_position
-                    else joinops.probe_unique)(bt, work_l, lk)
+                if by_position:
+                    at, matched, dup = joinops.probe_positions(
+                        bt, work_l, lk)
+                else:
+                    at, matched = joinops.probe_matched(bt, work_l, lk)
                 jt = nd.join_type
                 over = None
 
                 def and_mask(m):
                     return m if mask is None else mask & m
 
+                def second(b, at, matched):
+                    """A second match of a row of `b` matched at `at`:
+                    the table of positions marked it; a search reads
+                    the key after the match, at the width the caller
+                    has brought the matches to."""
+                    if by_position:
+                        return dup
+                    work, keys = nd._prepare_keys(b, nd.left_keys)
+                    return joinops.second_match(bt, work, keys, at,
+                                                matched)
+
                 if jt in ("left_semi", "inner"):
                     # a visible probe row with >1 matches trips the
                     # uniqueness flag (inner: the re-run lowers this
                     # join via the expanded blocking path, same
                     # capacity factors; a semi join does not care)
-                    if jt == "inner":
-                        uniq = uniq | jnp.any(dup & visible(b, mask))
+                    if jt == "inner" and (bet_to is None or by_position):
+                        uniq = uniq | jnp.any(
+                            second(b, at, matched) & visible(b, mask))
                     mask = and_mask(matched)
                     if bet_to is not None:
                         ids, total = joinops.front_row_ids(
@@ -1017,6 +1202,11 @@ class FusedSingleChipExecutor:
                             < total
                         at = jnp.take(at, ids)
                         mask, over = None, total > bet_to
+                        if jt == "inner" and not by_position:
+                            # every visible match is among these rows
+                            # unless the bet is lost, and then the run
+                            # is repeated
+                            uniq = uniq | jnp.any(second(b, at, matched))
                     if jt == "left_semi":
                         return b, mask, uniq, over
                 elif jt == "left_anti":
@@ -1024,7 +1214,8 @@ class FusedSingleChipExecutor:
                 elif jt == "existence":
                     return nd._exists_batch(b, matched), mask, uniq, over
                 else:  # left: a miss keeps its row, its build columns null
-                    uniq = uniq | jnp.any(dup & visible(b, mask))
+                    uniq = uniq | jnp.any(
+                        second(b, at, matched) & visible(b, mask))
                 # inner / left: unique-build single-match gather
                 rows = at
                 if not by_position:
@@ -1048,7 +1239,7 @@ class FusedSingleChipExecutor:
                         total > cap)
 
             for nd in nodes:
-                if isinstance(nd, J.TpuBroadcastHashJoinExec):
+                if isinstance(nd, _HASH_JOINS):
                     jp = join_plan.pop(0)
                     # the host's walk (chain_joins) and this trace are
                     # one decision: a capacity it did not foresee is a
@@ -1115,13 +1306,28 @@ class FusedSingleChipExecutor:
                         push = push | o
                     else:
                         ovf = ovf | o
-            out = materialized(b, mask)
+            if masked is None:
+                out = materialized(b, mask)
+            else:
+                def dead(c):
+                    return DeviceColumn(c.dtype, jnp.zeros_like(c.data),
+                                        jnp.zeros_like(c.validity),
+                                        vrange=c.vrange)
+
+                cols = [c if i in masked or c.data.ndim != 1
+                        or c.encoding is not None or c.children is not None
+                        else dead(c) for i, c in enumerate(b.columns)]
+                out = (ColumnBatch(b.schema, cols, b.capacity),
+                       visible(b, mask))
             fl = jnp.stack([ovf, uniq, push] + lost + unread)
             if ansi_live:
                 return out, fl, ansi
             return out, fl
 
-        def emit_parts(node: PhysicalPlan) -> List[ColumnBatch]:
+        def emit_parts(node: PhysicalPlan, read=None) -> List[ColumnBatch]:
+            """`read`: the ordinals of `node`'s output its consumer
+            reads (None: all), for a chain that builds a join's side
+            (`live_after`)."""
             if id(node) in src_parts:
                 return src_parts[id(node)]
             if (isinstance(node, ops.TpuCoalesceBatchesExec)
@@ -1132,7 +1338,7 @@ class FusedSingleChipExecutor:
                 return src_parts[id(node.children[0])]
             if isinstance(node, ops.TpuShuffleExchangeExec):
                 # single chip: every partition is already co-resident
-                return emit_parts(node.children[0])
+                return emit_parts(node.children[0], read)
             if isinstance(node, ops.UnionExec):
                 return [b for c in node.children for b in emit_parts(c)]
             if chainable(node):
@@ -1142,22 +1348,36 @@ class FusedSingleChipExecutor:
                         and not bets_on_matches(nodes, base):
                     rep = rewrite_memo(nodes)
                     if rep is not None:
-                        nodes = rep
-                return run_chain(nodes, base)
+                        nodes, read = rep, None
+                return run_chain(nodes, base, read=read)
             return [emit_blocking(node)]
 
+        def is_lookup(n) -> bool:
+            """`_is_lookup_join`, less the inner and left joins whose
+            build keys this session has seen twice."""
+            return (self._is_lookup_join(n, use_lookup)
+                    and (n.join_type in _NO_BUILD_COLUMN
+                         or (plan_key_memo(n), "unique")
+                         not in self._wide_joins))
+
         def chainable(n):
-            return (self._is_per_partition(n)
-                    or self._is_lookup_join(n, use_lookup))
+            return self._is_per_partition(n) or is_lookup(n)
+
+        def through_exchanges(n):
+            # single chip: every partition is already co-resident
+            while isinstance(n, ops.TpuShuffleExchangeExec):
+                n = n.children[0]
+            return n
 
         def collect_chain(node):
-            """Walk the chainable span below `node` (inclusive);
+            """Walk the chainable span below `node` (inclusive), through
+            the exchanges the planner put under a shuffled join;
             -> (exec-order nodes, the non-chainable base)."""
             chain = [node]
-            cur = node.children[0]
+            cur = through_exchanges(node.children[0])
             while chainable(cur) and id(cur) not in src_parts:
                 chain.append(cur)
-                cur = cur.children[0]
+                cur = through_exchanges(cur.children[0])
             return yield_lost_bets(list(reversed(chain))), cur
 
         def yield_lost_bets(nodes):
@@ -1192,8 +1412,7 @@ class FusedSingleChipExecutor:
 
         def yields_to(a, b) -> bool:
             def plain_inner(n):
-                return (self._is_lookup_join(n, use_lookup)
-                        and n.join_type == "inner")
+                return is_lookup(n) and n.join_type == "inner"
 
             if not (plain_inner(a) and plain_inner(b)):
                 return False
@@ -1214,12 +1433,15 @@ class FusedSingleChipExecutor:
             probe = list(a.children[0].schema.fields)
             a_cols = list(a.schema.fields[len(probe):])
             b_cols = list(b.schema.fields[len(a.schema.fields):])
-            first = J.TpuBroadcastHashJoinExec(
+            first = type(b)(
                 a.children[0], b.children[1], b.join_type, b.left_keys,
                 b.right_keys, StructType(probe + b_cols), b.conf)
-            second = J.TpuBroadcastHashJoinExec(
+            second = type(a)(
                 first, a.children[1], a.join_type, a.left_keys,
                 a.right_keys, StructType(probe + b_cols + a_cols), a.conf)
+            for new, old in ((first, b), (second, a)):
+                new.build_side, new.chosen_by = planned_sides(old)
+                new.origin = old
             n_p, n_a, n_b = len(probe), len(a_cols), len(b_cols)
             order = (list(range(n_p))
                      + list(range(n_p + n_b, n_p + n_b + n_a))
@@ -1258,12 +1480,11 @@ class FusedSingleChipExecutor:
             (`chain_joins`): it is then a selective filter, and an
             aggregate pushed below it would group the rows it drops.
             Once that bet is lost the pushdown applies again."""
-            if not any(isinstance(n, J.TpuBroadcastHashJoinExec)
-                       for n in nodes):
+            if not any(isinstance(n, _HASH_JOINS) for n in nodes):
                 return False
             keys = chain_keys(nodes)
             return any("buildFilter" in chain_joins(
-                nodes, keys, b.capacity)[-1]["bet"] for b in base)
+                nodes, keys, b)[-1]["bet"] for b in base)
 
         def chain_has_ansi(nodes) -> bool:
             """Hoisted ANSI relevance for one chain: True only when the
@@ -1286,26 +1507,34 @@ class FusedSingleChipExecutor:
                     return True
             return False
 
-        def chain_joins(nodes, keys, capacity):
+        def chain_joins(nodes, keys, base):
             """The host's walk of a chain that `chain_traced` follows:
             for each lookup join, bottom-up, what it is lowered to and
-            the capacities around it, from the capacity of the chain's
-            input. A join under a pending FILTER (a mask that a match
+            the capacities around it, from the chain's input `base`. A
+            join under a pending FILTER (a mask that a match
             alone left is no bet) searches the filter's survivors at
-            `survivor_capacity`, unless the batch is too small or the
-            join lost that bet before (`wide_joins`); the batch goes
-            on at that capacity. An inner or semi join whose BUILD side
+            `survivor_capacity`, unless the batch is too small, the
+            join lost that bet before (`wide_joins`) or the filters,
+            read off `base`'s own columns, keep `_HOPELESS` times that
+            share by `filter_share` (`filterShare` in the record); the
+            batch goes on at that capacity. An inner or semi join whose BUILD side
             sits under a filter is itself a filter: it places the same
             bet on its own matches, brought to the front before any
             build column is read (`bet`: which of the two a
             "lookupSurvivors" join placed). After an aggregate the
             capacity is the aggregate's own (None here)."""
-            cap, filtered, out = capacity, False, []
+            cap, filtered, out = base.capacity, False, []
+            # what the pending filters keep, while they still read
+            # `base`'s own columns (None: not known)
+            share, at_base = 1.0, True
             for nd, key in zip(nodes, keys):
-                if isinstance(nd, J.TpuBroadcastHashJoinExec):
+                if isinstance(nd, _HASH_JOINS):
                     bet, probe_slots = [], cap
+                    hopeless = (share is not None and share
+                                * _SURVIVOR_SHARE >= _HOPELESS)
                     to = (survivor_capacity(cap)
                           if filtered and cap is not None
+                          and not hopeless
                           and key not in self._wide_joins else None)
                     if to:
                         bet.append("probeFilter")
@@ -1322,11 +1551,15 @@ class FusedSingleChipExecutor:
                     out.append({
                         "lowering": "lookupSurvivors" if bet else "lookup",
                         "bet": "+".join(bet),
+                        **join_labels(nd),
                         "joinType": nd.join_type,
                         "buildGather": build_gather(nd.join_type),
                         "probeSlots": probe_slots,
                         "searchedSlots": searched,
                         "outputCapacity": cap})
+                    if filtered and share is not None:
+                        out[-1]["filterShare"] = round(share, 4)
+                    share, at_base = None, False
                     if bet:
                         # reads a slot brought to the front pays for
                         # its row id, in the widest mask searched
@@ -1335,15 +1568,45 @@ class FusedSingleChipExecutor:
                             else searched)
                 elif isinstance(nd, ops.TpuFilterExec):
                     filtered = True
+                    one = filter_share(nd.condition, base) \
+                        if at_base else None
+                    share = None if None in (share, one) else share * one
+                elif isinstance(nd, ops.TpuCoalesceBatchesExec):
+                    pass
+                elif isinstance(nd, ops.TpuProjectExec):
+                    at_base = False
                 elif isinstance(nd, ops.TpuExpandExec):
-                    filtered = False
+                    filtered, share, at_base = False, None, False
                     cap = cap and cap * len(nd.projections)
                 elif isinstance(nd, ops.TpuGenerateExec):
-                    filtered = False
+                    filtered, share, at_base = False, None, False
                     cap = cap and next_capacity(expansion * cap)
-                elif not isinstance(nd, (ops.TpuProjectExec,
-                                         ops.TpuCoalesceBatchesExec)):
+                else:
                     cap, filtered = None, False  # an aggregate's own
+                    share, at_base = None, False
+            return out
+
+        def planned_sides(nd) -> tuple:
+            # a join made here, not by the planner, says "written"
+            return (getattr(nd, "build_side", "right"),
+                    getattr(nd, "chosen_by", "written"))
+
+        def join_labels(nd) -> dict:
+            """What the planner called the join and which child, as
+            written, it builds (plan/overrides.py `_convert_join`);
+            `buildJoins`, where the build side holds joins itself."""
+            side, by = planned_sides(nd)
+            out = {"planned": ("broadcast" if isinstance(
+                       nd, J.TpuBroadcastHashJoinExec) else "shuffled"),
+                   "buildSide": side, "chosenBy": by}
+
+            def count(n) -> int:
+                return isinstance(n, _HASH_JOINS) + sum(
+                    count(c) for c in n.children)
+
+            inside = count(nd.children[1])
+            if inside:
+                out["buildJoins"] = inside
             return out
 
         def note_join(key, rec, rows):
@@ -1359,7 +1622,39 @@ class FusedSingleChipExecutor:
                 was[k] = (None if None in (was[k], rec[k])
                           else was[k] + rec[k])
 
-        def run_chain(nodes, base):
+        def agg_reads(agg):
+            return {r for e in list(agg.grouping) + list(agg.aggs)
+                    for r in e.references()}
+
+        def live_after(nodes, i, read=None):
+            """Ordinals of `nodes[i]`'s output that the rest of the
+            chain reads, or None: all of them (the chain's output is
+            materialized and its consumer did not say what it reads
+            of it, `read`; or a node is not understood)."""
+            last = nodes[-1]
+            if i == len(nodes) - 1:
+                return read
+            if isinstance(last, ops.TpuHashAggregateExec):
+                need, rest = agg_reads(last), nodes[i + 1:-1]
+            elif read is not None:
+                need, rest = set(read), nodes[i + 1:]
+            else:
+                return None
+            for nd in reversed(rest):
+                if isinstance(nd, ops.TpuProjectExec):
+                    need = {r for o in need
+                            for r in nd.exprs[o].references()}
+                elif isinstance(nd, ops.TpuFilterExec):
+                    need = need | set(nd.condition.references())
+                elif isinstance(nd, _HASH_JOINS):
+                    n_probe = len(nd.children[0].schema.fields)
+                    need = {o for o in need if o < n_probe} | {
+                        r for k in nd.left_keys for r in k.references()}
+                elif not isinstance(nd, ops.TpuCoalesceBatchesExec):
+                    return None
+            return need
+
+        def run_chain(nodes, base, masked=None, read=None):
             keys = chain_keys(nodes)
             nodes_key = tuple(
                 k if isinstance(n, agg_pushdown.MergeTail) else k[:2]
@@ -1367,18 +1662,30 @@ class FusedSingleChipExecutor:
             # lookup-join build sides are indexed ONCE, outside the
             # per-partition programs, and ride in as extra inputs
             join_keys = [k for n, k in zip(nodes, keys)
-                         if isinstance(n, J.TpuBroadcastHashJoinExec)]
-            join_nodes = [n for n in nodes
-                          if isinstance(n, J.TpuBroadcastHashJoinExec)]
+                         if isinstance(n, _HASH_JOINS)]
+            join_nodes = [n for n in nodes if isinstance(n, _HASH_JOINS)]
+            # the inner and left ones bet on unique build keys
+            # (a join the engine made of the plan's own, to push an
+            # aggregate below it or to let it yield, bets for that one)
+            uniq_keys = [plan_key_memo(getattr(n, "origin", n))
+                         for n in join_nodes
+                         if n.join_type not in _NO_BUILD_COLUMN]
             # the host's walk of every part's chain comes first: how a
             # build side is indexed follows from the slots it is
             # probed from
-            plans = [chain_joins(nodes, keys, b.capacity)
+            plans = [chain_joins(nodes, keys, b)
                      if join_nodes else [] for b in base]
+            def build_columns_read(n):
+                need = live_after(nodes, nodes.index(n), read)
+                n_probe = len(n.children[0].schema.fields)
+                return None if need is None else {
+                    o - n_probe for o in need if o >= n_probe}
+
             built = [
                 build_table(n, sum(plan[i]["searchedSlots"] or 0
                                    for plan in plans),
-                            sum(b.capacity for b in base))
+                            sum(b.capacity for b in base),
+                            build_columns_read(n))
                 for i, n in enumerate(join_nodes)]
             builds = [bt for bt, _ in built]
             build_slots = [slots for _, slots in built]
@@ -1409,7 +1716,7 @@ class FusedSingleChipExecutor:
                 def stage_fn(b, *bs, _nodes=nodes, _al=ansi_live,
                              _plan=plan):
                     return chain_traced(_nodes, b, bs, ansi_live=_al,
-                                        join_plan=_plan)
+                                        join_plan=_plan, masked=masked)
 
                 # the lowering is structural: which joins search their
                 # survivors, what each reads of a build side left as it
@@ -1429,14 +1736,17 @@ class FusedSingleChipExecutor:
                 if plan:
                     marked += (("buildGather",
                                 tuple(jp["buildGather"] for jp in plan)),)
+                if masked is not None:
+                    marked += (("masked", tuple(sorted(masked))),)
                 return run_program("chain", marked, stage_fn,
                                    [b] + builds, join_fields=plan,
-                                   survivor_joins=tuple(bets), **uses)
+                                   survivor_joins=tuple(bets),
+                                   uniq_joins=tuple(uniq_keys), **uses)
 
             return [one(b, plan) for b, plan in zip(base, plans)]
 
         def build_table(jn: PhysicalPlan, probed_slots: int,
-                        chain_slots: int):
+                        chain_slots: int, columns_read=None):
             """-> (the build side of one lookup join, indexed where it
             lies, its slots) — ONE buildprep program per join per run,
             shared by every per-partition chain program as an extra
@@ -1451,21 +1761,45 @@ class FusedSingleChipExecutor:
             sparse key under a selective probe-side filter (TPC-H
             Q12's `o_orderkey`: 67M values for 983,040 survivors x 24
             steps) keeps the sorted index and its search
-            (joinops.BuildIndex)."""
+            (joinops.BuildIndex). A build side that is a chain itself
+            (TPC-H Q3: `orders` under the segment's customers) hands
+            its parts over uncompacted, each with its mask, and
+            computes only the columns in `columns_read` (None: all),
+            its keys and what its filters read (`chain_traced`,
+            `masked`)."""
             # filters right above the build side's source are taken
             # into this program as its mask of live rows: the index
             # sends the rows they drop last, or leaves them out of its
             # table, and a program that only compacted them is saved
             src, filters = jn.children[1], []
             while (isinstance(src, (ops.TpuFilterExec,
-                                    ops.TpuCoalesceBatchesExec))
+                                    ops.TpuCoalesceBatchesExec,
+                                    ops.TpuShuffleExchangeExec))
                    and id(src) not in src_parts):
                 if isinstance(src, ops.TpuFilterExec):
                     filters.insert(0, src)
                 src = src.children[0]
             if chain_has_ansi(filters):
                 src, filters = jn.children[1], []
-            parts = emit_parts(src)
+            masks = []
+            derived = id(src) not in src_parts and chainable(src)
+            if derived:
+                nodes, cur = collect_chain(src)
+                derived = not isinstance(
+                    nodes[-1], (ops.TpuHashAggregateExec,
+                                agg_pushdown.MergeTail))
+            if derived:
+                n_cols = len(src.schema.fields)
+                keep = set(range(n_cols)) if columns_read is None \
+                    else set(columns_read)
+                keep |= {r for e in list(jn.right_keys)
+                         + [f.condition for f in filters]
+                         for r in e.references()}
+                pairs = run_chain(nodes, emit_parts(cur), masked=keep)
+                parts, masks = ([p for p, _ in pairs],
+                                [m for _, m in pairs])
+            else:
+                parts = emit_parts(src)
             gather = build_gather(jn.join_type)
             ranges = [jn.build_key_range(p) for p in parts]
             slots = sum(p.capacity for p in parts)
@@ -1473,9 +1807,19 @@ class FusedSingleChipExecutor:
             # 128 keys a level (joinops.search_reads): what WRITING a
             # table costs did not change, and both cells' choices hold
             steps = max(1, slots.bit_length())
-            by_position = bool(ranges) and None not in ranges and (
-                max(hi for _, hi in ranges) - min(lo for lo, _ in ranges)
-                < min(probed_slots * steps, chain_slots))
+            by_position = bool(ranges) and None not in ranges
+            if by_position:
+                entries = (max(hi for _, hi in ranges)
+                           - min(lo for lo, _ in ranges))
+                # a table of more than one chunk is written a chunk at
+                # a time, every build slot offered to every chunk
+                # (joinops.build_positions): that must not cost more
+                # than one read a probed slot either (TPC-H Q3's
+                # 15.7M-slot derived `orders` into 58 chunks: not taken)
+                chunks = -(-(entries + 1) // joinops.TABLE_CHUNK)
+                by_position = (
+                    entries < min(probed_slots * steps, chain_slots)
+                    and (chunks == 1 or chunks * slots <= probed_slots))
 
             def bp_fn(*ps):
                 from spark_rapids_tpu.expr import EvalContext
@@ -1483,7 +1827,10 @@ class FusedSingleChipExecutor:
                 # the parts end to end, uncompacted: the build side's
                 # sort sends every dead row last anyway, and its table
                 # of positions takes no dead row
-                cb, live = concat_in_place(concat_inputs(list(ps)))
+                cb, live = concat_in_place(
+                    concat_inputs(list(ps[:len(parts)])))
+                if masks:
+                    live = live & jnp.concatenate(ps[len(parts):])
                 for f in filters:
                     pred = f.condition.eval(EvalContext(cb))
                     live = live & pred.data & pred.validity
@@ -1502,7 +1849,10 @@ class FusedSingleChipExecutor:
             if by_position:
                 marked += (("probe", "position"),)
             marked += (("buildGather", gather),)
-            return run_program("buildprep", marked, bp_fn, parts), slots
+            if masks:
+                marked += (("masked",),)
+            return run_program("buildprep", marked, bp_fn,
+                               parts + masks), slots
 
         def concat_inputs(parts):
             return [widen_traced(p) for p in parts]
@@ -1532,7 +1882,26 @@ class FusedSingleChipExecutor:
                                            _plan_key(node)[:2],
                                            mf_fn, parts,
                                            uses_group_cap=True)
-                parts = emit_parts(node.children[0])
+                parts = emit_parts(
+                    node.children[0],
+                    agg_reads(node) if mode == "complete" else None)
+                # the shrink of THIS aggregate's output is a bet of its
+                # own: where it alone overflowed, it alone grows
+                # (GroupOverflow), and the session remembers by how much
+                agg_key = _plan_key(node)[:2]
+                if not self._known_cap(agg_key):
+                    # the first time a session runs this aggregate it
+                    # looks before it bets: the groups are no more than
+                    # the rows that reach it (one fetch, once; TPC-H
+                    # Q3's 114K groups would lose the bet, and the
+                    # lost run's programs are compiled for nothing)
+                    rows = sum(int(r) for r in telemetry.ledgered_get(
+                        [p.num_rows for p in parts], "fused.flags"))
+                    fit = group_cap
+                    while fit < min(rows, sum(p.capacity for p in parts)):
+                        fit *= 4
+                    self._wide_joins.add(("groups", agg_key, fit))
+                cap = self._final_cap(agg_key, group_cap)
 
                 def agg_fn(*ps):
                     cb = concat_traced(concat_inputs(list(ps)))
@@ -1545,7 +1914,7 @@ class FusedSingleChipExecutor:
                             cb, cb.live_mask())
                         cb = node._partial(cb)
                     out = node._merge_final(cb)
-                    out, ovf = shrink_traced(out, group_cap)
+                    out, ovf = shrink_traced(out, cap)
                     if av is not None:
                         return out, ovf, av
                     return out, ovf
@@ -1555,9 +1924,12 @@ class FusedSingleChipExecutor:
                 agg_ansi = (ansi_on and mode == "complete" and any(
                     ansicheck.has_ansi_checks(e)
                     for e in list(node.grouping) + list(node.aggs)))
-                return run_program("agg", _plan_key(node)[:2], agg_fn,
-                                   parts, uses_group_cap=True,
-                                   uses_ansi=agg_ansi)
+                out = run_program("agg", agg_key, agg_fn, parts,
+                                  uses_group_cap=cap == group_cap or cap,
+                                  uses_ansi=agg_ansi, final_agg=agg_key)
+                groups.append({"capacity": out.capacity,
+                               "found": out.num_rows})
+                return out
             if isinstance(node, ops.TpuSortExec):
                 child = node.children[0]
                 if isinstance(child, ops.TpuShuffleExchangeExec):
@@ -1606,7 +1978,11 @@ class FusedSingleChipExecutor:
                 build_slots = sum(p.capacity for p in rparts)
                 out_cap = next_capacity(
                     expansion * max(probe_slots, build_slots))
-                rec = {"lowering": "expand", "joinType": node.join_type,
+                rec = {"lowering": "expand", **join_labels(node),
+                       # the buffer an expanding join fills is sized by
+                       # the session's factor, before a count is known
+                       "capacityFrom": "factor",
+                       "joinType": node.join_type,
                        "probeSlots": probe_slots,
                        "searchedSlots": probe_slots,
                        "outputCapacity": out_cap,
@@ -1627,33 +2003,58 @@ class FusedSingleChipExecutor:
             raise FusedCompileError(type(node).__name__)
 
         def all_flags_arr():
-            ovf = ([f.reshape((1,)) for f in flags]
-                   or [jnp.zeros((1,), bool)])
-            uq = [f.reshape((1,)) for f in uniq_flags]
+            tagged = flags or [(None, jnp.zeros((), bool))]
+            ovf = [f.reshape((1,)) for _, f in tagged]
+            uq = [f.reshape((1,)) for _, f in uniq_flags]
             pf = [f.reshape((1,)) for f in push_flags]
             sf = [f.reshape((1,)) for _, f in surv_flags]
             return (jnp.concatenate(ovf + uq + pf + sf + ansi_flags),
-                    (len(ovf), len(uq), len(pf),
-                     tuple(k for k, _ in surv_flags)))
+                    flag_tags(tagged))
+
+        def flag_tags(tagged):
+            # what `_check_host_flags` takes beside the flags
+            return (tuple(a for a, _ in tagged),
+                    tuple(ks for ks, _ in uniq_flags), len(push_flags),
+                    tuple(k for k, _ in surv_flags))
+
+        def flags_so_far():
+            """-> (host flags, *ns) as `all_flags_arr` lays them out,
+            assembled on the HOST from the scalars as they lie: asked
+            before each compile of a cold run, with another count of
+            flags each time, a device-side concatenate would be a
+            program of its own to compile for every one."""
+            host = telemetry.ledgered_get(
+                ([f for _, f in flags], [f for _, f in uniq_flags],
+                 push_flags, [f for _, f in surv_flags], ansi_flags),
+                "fused.flags")
+            return (np.concatenate(
+                        [np.asarray(x, bool).reshape(-1)
+                         for part in host for x in part]),
+                    *flag_tags(flags))
 
         def assembled_flags(sp):
             """all_flags_arr() inside the `fetch` span `sp`: a dozen
             tiny device operations enqueued from the host, timed apart
             (`flagsNs`) from the wait that follows. The build sides'
-            row counts ride the same fetch."""
+            row counts and the final aggregates' groups ride the same
+            fetch."""
             t0 = time.monotonic_ns()
             arr, ns = all_flags_arr()
             sp.set(flagsNs=time.monotonic_ns() - t0)
-            return (arr, [j["buildRows"] for j in joins.values()]), ns
+            return (arr, [j["buildRows"] for j in joins.values()],
+                    [g["found"] for g in groups]), ns
 
         def settle(host, ns):
-            """The fetched (flags, build rows): raise what the flags
-            say, else complete the run's join record."""
-            host_flags, host_rows = host
+            """The fetched (flags, build rows, groups found): raise
+            what the flags say, else complete the run's records."""
+            host_flags, host_rows, host_found = host
             _check_host_flags(np.asarray(host_flags), *ns)
             for rec, rows in zip(joins.values(), host_rows):
                 rec["buildRows"] = sum(int(r) for r in rows)
+            for rec, found in zip(groups, host_found):
+                rec["found"] = int(found)
             self._run_joins = list(joins.values())
+            self._run_groups = groups
 
         parts = emit_parts(phys)
         if as_parts:

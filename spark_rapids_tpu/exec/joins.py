@@ -427,18 +427,28 @@ class _DeviceJoinBase(PhysicalPlan):
     def build_is_filtered(self) -> bool:
         """Whether the build side sits under a filter: the join then
         filters its probe side, as a WHERE on a dimension's attributes
-        does once it is pushed below the join."""
-        from spark_rapids_tpu.exec import operators as ops
+        does once it is pushed below the join. An inner or semi join on
+        the way down is a filter itself where ITS build side is one
+        (TPC-H Q3: `orders` under the segment's customers), and its
+        probe side is looked at as well."""
+        return _is_filtered(self.children[1])
 
-        node = self.children[1]
-        while True:
-            if isinstance(node, ops.TpuFilterExec):
+
+def _is_filtered(node) -> bool:
+    from spark_rapids_tpu.exec import operators as ops
+
+    while True:
+        if isinstance(node, ops.TpuFilterExec):
+            return True
+        if (isinstance(node, _DeviceJoinBase)
+                and node.join_type in ("inner", "left_semi")):
+            if node.build_is_filtered():
                 return True
-            if not isinstance(node, (ops.TpuProjectExec,
-                                     ops.TpuCoalesceBatchesExec,
-                                     ops.TpuShuffleExchangeExec)):
-                return False
-            node = node.children[0]
+        elif not isinstance(node, (ops.TpuProjectExec,
+                                   ops.TpuCoalesceBatchesExec,
+                                   ops.TpuShuffleExchangeExec)):
+            return False
+        node = node.children[0]
 
 
 class TpuShuffledHashJoinExec(_DeviceJoinBase):

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 from typing import List, NamedTuple, Optional, Sequence, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -86,7 +87,7 @@ class BuildPositions(NamedTuple):
 
 
 #: entries of a table of positions written by one scatter
-_TABLE_CHUNK = 1 << 20
+TABLE_CHUNK = 1 << 20
 
 
 def key_range(batch: ColumnBatch, key_idxs: Sequence[int]
@@ -130,7 +131,7 @@ def build_positions(batch: ColumnBatch, key_idxs: Sequence[int],
         held = jnp.zeros((n,), jnp.int32).at[at].add(1, mode="drop")
         return jnp.where(held > 1, -2 - table, table)
 
-    if size <= _TABLE_CHUNK:
+    if size <= TABLE_CHUNK:
         table = chunk(at, size)
     else:
         # a scatter into more than a few MB is one the TPU's compiler
@@ -138,11 +139,11 @@ def build_positions(batch: ColumnBatch, key_idxs: Sequence[int],
         # 4M entries, PERF.md PR 33): the table is written a chunk at
         # a time, every row offered to every chunk
         def one(k):
-            rel = at - k * _TABLE_CHUNK
-            inside = (rel >= 0) & (rel < _TABLE_CHUNK)
-            return chunk(jnp.where(inside, rel, _TABLE_CHUNK), _TABLE_CHUNK)
+            rel = at - k * TABLE_CHUNK
+            inside = (rel >= 0) & (rel < TABLE_CHUNK)
+            return chunk(jnp.where(inside, rel, TABLE_CHUNK), TABLE_CHUNK)
 
-        chunks = -(-size // _TABLE_CHUNK)
+        chunks = -(-size // TABLE_CHUNK)
         table = lax.map(one, jnp.arange(chunks, dtype=jnp.int32)
                         ).reshape(-1)[:size]
     return BuildPositions(batch, table, jnp.int64(lo),
@@ -271,7 +272,8 @@ def search_reads(slots: int) -> int:
 
 
 def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
-                 bound: Optional[jnp.ndarray], upper: bool) -> jnp.ndarray:
+                 bound: Optional[jnp.ndarray], upper: bool,
+                 with_equal: bool = False):
     """Per probe, how many positions `i < bound` hold `keys[i] < probe`
     (`<=` with `upper`): the lower / upper bound of each probe in a
     ONE-array key that is sorted on [0, bound) (`bound` None: all of
@@ -282,10 +284,16 @@ def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
     binary search reads one key for each of log2(n) steps: the chip
     charges a 4-byte read 7.1 ns and a 512-byte row 2.6 (PERF.md,
     PR 34). Nothing is built ahead: the levels are strided slices made
-    here."""
+    here. `with_equal`: -> also whether the key AT a lower bound
+    equals its probe, read off the row the last level fetched (the
+    row that holds the bound: the level above chose the first row
+    whose last key is not below the probe) and so for no read at all,
+    where a gather of `keys[lo]` costs a full-width probe 27 ns a slot
+    (PERF.md, PR 35)."""
     n, nq = keys.shape[0], probe.shape[0]
     if n == 0 or nq == 0:
-        return jnp.zeros((nq,), jnp.int32)
+        found = jnp.zeros((nq,), jnp.int32)
+        return (found, jnp.zeros((nq,), bool)) if with_equal else found
     integer = jnp.issubdtype(keys.dtype, jnp.integer)
     top = jnp.iinfo(keys.dtype).max if integer else jnp.inf
     outside = None
@@ -328,25 +336,36 @@ def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
     def search(q):
         q = q[:, None]
         node = count(below(last[None, :], q))
+        equal = None
         for level in reversed(levels):
             node = jnp.minimum(node, level.shape[0] - 1)
-            hit = below(jnp.take(level, node, axis=0, mode="clip"), q)
-            if level is levels[0] and bound is not None:
-                hit = hit & (node[:, None] * _LANES + lanes < limit)
+            row = jnp.take(level, node, axis=0, mode="clip")
+            hit = below(row, q)
+            if level is levels[0]:
+                inside = (node[:, None] * _LANES + lanes
+                          < (limit if bound is not None else n))
+                if bound is not None:
+                    hit = hit & inside
+                if with_equal:
+                    equal = count((row == q) & inside) > 0
             node = node * _LANES + count(hit)
-        return node
+        return (node, equal) if with_equal else node
 
     if nq <= _PROBE_BLOCK:
-        found = search(probe)
+        out = search(probe)
     else:
         blocks = -(-nq // _PROBE_BLOCK)
         padded = jnp.pad(probe, (0, blocks * _PROBE_BLOCK - nq))
-        found = lax.map(search, padded.reshape(blocks, _PROBE_BLOCK)
-                        ).reshape(-1)[:nq]
+        out = jax.tree_util.tree_map(
+            lambda a: a.reshape(-1)[:nq],
+            lax.map(search, padded.reshape(blocks, _PROBE_BLOCK)))
+    found, equal = out if with_equal else (out, None)
     found = jnp.minimum(found, limit)
     if outside is not None:
         found = jnp.where(outside[0], 0, jnp.where(outside[1], limit, found))
-    return found
+        if with_equal:
+            equal = equal & ~outside[0] & ~outside[1]
+    return (found, equal) if with_equal else found
 
 
 def _binary_search(build_keys: List[jnp.ndarray],
@@ -403,28 +422,51 @@ def _keys_equal_at(build_keys: List[jnp.ndarray], idx: jnp.ndarray,
     return eq
 
 
+def probe_matched(build: Union[BuildTable, BuildIndex],
+                  probe: ColumnBatch, key_idxs: Sequence[int]
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-probe-row (lo, matched) for a lookup join: ONE lower-bound
+    search. Over a one-array key whether the build key at `lo` equals
+    the probe is read off the row the search fetched last
+    (`_count_below`), for no read of its own; a tuple of key arrays
+    reads the keys at `lo`."""
+    vals, all_valid = _join_keys(probe, key_idxs, probe.live_mask())
+    if len(build.keys) == 1:
+        lo, equal = _count_below(build.keys[0], vals[0], build.valid_bound,
+                                 upper=False, with_equal=True)
+        return lo, all_valid & equal
+    cap = build.keys[0].shape[0]
+    lo = _binary_search(build.keys, vals, build.valid_bound, cap,
+                        upper=False)
+    return lo, all_valid & _equal_at(build, lo, vals)
+
+
+def _equal_at(build, idx: jnp.ndarray, vals: List[jnp.ndarray]
+              ) -> jnp.ndarray:
+    cap = build.keys[0].shape[0]
+    return (idx < build.valid_bound.astype(jnp.int32)) & _keys_equal_at(
+        build.keys, jnp.clip(idx, 0, cap - 1), vals)
+
+
+def second_match(build: Union[BuildTable, BuildIndex], probe: ColumnBatch,
+                 key_idxs: Sequence[int], lo: jnp.ndarray,
+                 matched: jnp.ndarray) -> jnp.ndarray:
+    """Per probe row that `probe_matched` matched at `lo`, whether the
+    build key at `lo + 1` equals it as well: the build keys are not
+    unique for this row. One gather, which a caller that has brought
+    its matches to the front of a small batch pays there and not at
+    the probe's full width."""
+    vals, _ = _join_keys(probe, key_idxs, probe.live_mask())
+    return matched & _equal_at(build, lo + 1, vals)
+
+
 def probe_unique(build: Union[BuildTable, BuildIndex], probe: ColumnBatch,
                  key_idxs: Sequence[int]
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Per-probe-row (lo, matched, dup) for a lookup join, which needs
-    one match and the fact of a second, not the count: ONE lower-bound
-    search, then the build key at `lo` (equal, inside the live bound:
-    matched) and at `lo + 1` (equal as well: the build keys are not
-    unique for this row). Two gathers in place of `probe_ranges`'
-    second search."""
-    live = probe.live_mask()
-    vals, all_valid = _join_keys(probe, key_idxs, live)
-    cap = build.keys[0].shape[0]
-    bound = build.valid_bound.astype(jnp.int32)
-    lo = _binary_search(build.keys, vals, build.valid_bound, cap,
-                        upper=False)
-
-    def equal_at(idx):
-        return (idx < bound) & _keys_equal_at(
-            build.keys, jnp.clip(idx, 0, cap - 1), vals)
-
-    matched = all_valid & equal_at(lo)
-    return lo, matched, matched & equal_at(lo + 1)
+    """Per-probe-row (lo, matched, dup): `probe_matched` and
+    `second_match` together, where both are wanted at one width."""
+    lo, matched = probe_matched(build, probe, key_idxs)
+    return lo, matched, second_match(build, probe, key_idxs, lo, matched)
 
 
 def rows_at(build: BuildIndex, pos: jnp.ndarray

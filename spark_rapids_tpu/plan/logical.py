@@ -10,6 +10,7 @@ physical planning never deals with unresolved attributes.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import pyarrow as pa
@@ -528,8 +529,12 @@ def plan_own_key(plan: LogicalPlan) -> tuple:
 def estimate_size_bytes(plan: LogicalPlan) -> Optional[int]:
     """Best-effort plan-size estimate for broadcast decisions (the
     reference relies on Spark's statistics + autoBroadcastJoinThreshold;
-    standalone, we estimate from source sizes and propagate up).
-    Returns None when unknown (joins/aggregates change cardinality)."""
+    standalone, we estimate from source sizes and propagate up): the
+    bytes of a source's files or table, kept through the operators that
+    do not widen a row. Returns None for a join's or an aggregate's
+    output and for a source of unknown size: the planner then does not
+    broadcast it. Which SIDE of a join is built is `estimate_rows`'s
+    question, which does answer for a join."""
     import os
 
     if isinstance(plan, LocalRelation):
@@ -539,10 +544,7 @@ def estimate_size_bytes(plan: LogicalPlan) -> Optional[int]:
         # not be materialized yet at plan time)
         return estimate_size_bytes(plan.entry.logical)
     if isinstance(plan, Range):
-        step = plan.step or 1
-        total = max(0, (plan.end - plan.start + step -
-                        (1 if step > 0 else -1)) // step)
-        return total * 8
+        return _range_rows(plan) * 8
     if isinstance(plan, FileScan):
         from spark_rapids_tpu.io import readers
 
@@ -560,3 +562,94 @@ def estimate_size_bytes(plan: LogicalPlan) -> Optional[int]:
             return None
         return sum(sizes)
     return None
+
+
+def _range_rows(plan: "Range") -> int:
+    step = plan.step or 1
+    return max(0, (plan.end - plan.start + step -
+                   (1 if step > 0 else -1)) // step)
+
+
+@functools.lru_cache(maxsize=4096)
+def _parquet_rows(path: str, mtime_ns: int, size: int) -> int:
+    """Rows a parquet file's footer states; kept by what `os.stat`
+    says of the file, so a plan costs a stat a file, not a read."""
+    import pyarrow.parquet as pq
+
+    return pq.read_metadata(path).num_rows
+
+
+def estimate_rows(plan: LogicalPlan) -> Optional[int]:
+    """Rows a plan gives, at most, as far as the planner can know
+    without running it: a table's rows, a parquet footer's, those of a
+    cached relation's own sources. A filter, a projection, a sort and
+    an aggregate keep their child's count (no selectivity is guessed).
+    An equi-join is taken for a foreign key meeting its primary key,
+    every row of the larger side matching one row of the smaller at
+    most: the larger side's count (a cross join: the product). What a
+    join's build side is chosen by (plan/overrides.py `_convert_join`);
+    None where a source is not known, and the order of writing then
+    decides."""
+    import os
+
+    if isinstance(plan, LocalRelation):
+        return plan.table.num_rows
+    if isinstance(plan, CachedRelation):
+        # once an entry: a cached relation is not invalidated when its
+        # files change (exec/relation_cache.py), and a hot query's plan
+        # must not stat them again (5 ms of a 290 ms query; PERF.md,
+        # PR 35)
+        entry = plan.entry
+        if not hasattr(entry, "estimated_rows"):
+            entry.estimated_rows = estimate_rows(entry.logical)
+        return entry.estimated_rows
+    if isinstance(plan, Range):
+        return _range_rows(plan)
+    if isinstance(plan, FileScan):
+        if plan.fmt != "parquet":
+            return None
+        from spark_rapids_tpu.io import readers
+
+        try:
+            total = 0
+            for f in readers.expand_paths(plan.paths, ".parquet"):
+                st = os.stat(f)
+                total += _parquet_rows(f, st.st_mtime_ns, st.st_size)
+            return total
+        except Exception:  # unreadable footer: unknown, not an error
+            return None
+    if isinstance(plan, Limit):
+        rows = estimate_rows(plan.children[0])
+        return None if rows is None else min(rows, plan.n)
+    if isinstance(plan, (Project, Filter, Sort, Repartition, Window,
+                         Aggregate)):
+        return estimate_rows(plan.children[0])
+    if isinstance(plan, (Union, Join)):
+        rows = [estimate_rows(c) for c in plan.children]
+        if any(r is None for r in rows):
+            return None
+        if isinstance(plan, Union):
+            return sum(rows)
+        if plan.join_type in ("left_semi", "left_anti", "existence"):
+            return rows[0]
+        if plan.join_type == "cross" or not plan.left_keys:
+            return rows[0] * rows[1]
+        return max(rows)
+    return None
+
+
+def rows_are_a_bound(plan: LogicalPlan) -> bool:
+    """Whether `estimate_rows(plan)` is only an upper bound: the plan
+    holds an operator that drops rows by a share nobody knows before
+    the run (a filter, a limit, an aggregate, a semi or anti join)."""
+    if isinstance(plan, CachedRelation):
+        return rows_are_a_bound(plan.entry.logical)
+    if isinstance(plan, (Filter, Limit, Aggregate)):
+        return True
+    if isinstance(plan, FileScan) and getattr(plan, "pushed_filters", None):
+        return True
+    if isinstance(plan, Join) and plan.join_type in ("left_semi",
+                                                     "left_anti"):
+        return True
+    return any(rows_are_a_bound(c) for c in plan.children)
+

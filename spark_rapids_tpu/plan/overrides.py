@@ -502,17 +502,36 @@ class TpuOverrides:
         n_l = len(node.children[0].schema.fields)
         n_r = len(node.children[1].schema.fields)
         build_logical = node.children[1]
+        # the build side is the RIGHT child of the exec node. A right
+        # outer join is a swapped left outer; an inner equi-join builds
+        # the side with fewer rows by what the planner knows of them
+        # (Spark's JoinSelection picks the smaller side; the reference
+        # plugin's GpuShuffledSymmetricHashJoinExec at run time), not
+        # the side written last. Either way the columns come out in
+        # the written order through a projection of bare references.
         swapped = join_type == "right"
+        chosen_by = "written"
+        if join_type == "inner" and left_keys:
+            rows = [L.estimate_rows(c) for c in node.children]
+            # a count that is only a bound (a filter below) may hide a
+            # far smaller side: where the written build side alone has
+            # one, the order of writing stands; and it stands against
+            # anything short of twice the rows
+            if None not in rows and (
+                    L.rows_are_a_bound(node.children[0])
+                    or not L.rows_are_a_bound(node.children[1])):
+                chosen_by = "rows"
+                swapped = 2 * rows[0] <= rows[1]
         if swapped:
-            # right outer = swapped left outer + column reorder
             left, right = right, left
             left_keys, right_keys = right_keys, left_keys
-            join_type = "left"
+            if join_type == "right":
+                join_type = "left"
             build_logical = node.children[0]
             if condition is not None:
                 condition = swap_condition(condition, n_l, n_r)
-        exec_schema = (self._swapped_schema(left, right) if swapped
-                       else node.schema)
+        exec_schema = (self._swapped_schema(left, right, join_type)
+                       if swapped else node.schema)
         if not left_keys or join_type == "cross":
             joined = self._nested_loop_join(
                 left, right, join_type, condition, exec_schema)
@@ -520,28 +539,30 @@ class TpuOverrides:
             joined = self._hash_join(
                 left, right, join_type, left_keys, right_keys, condition,
                 exec_schema, build_logical, shuffle_parts)
+        # for the join's record (exec/fused.py): which child as written
+        # is built, and what chose it
+        joined.build_side = "left" if swapped else "right"
+        joined.chosen_by = chosen_by
         if not swapped:
             return joined
         # swapped layout is [orig-right fields | orig-left fields];
         # reorder back to node.schema = [left | right]
         swapped_schema = joined.schema
-        reorder = [Alias(BoundReference(n_r + i,
-                                        swapped_schema.fields[n_r + i]
-                                        .dataType, True),
-                         swapped_schema.fields[n_r + i].name)
-                   for i in range(n_l)]
-        reorder += [Alias(BoundReference(i,
-                                         swapped_schema.fields[i].dataType,
-                                         True),
-                          swapped_schema.fields[i].name)
-                    for i in range(n_r)]
+        order = list(range(n_r, n_r + n_l)) + list(range(n_r))
+        reorder = [Alias(BoundReference(o, swapped_schema.fields[o].dataType,
+                                        f.nullable), f.name)
+                   for o, f in zip(order, node.schema.fields)]
         return ops.TpuProjectExec(reorder, joined, node.schema, conf)
 
-    def _swapped_schema(self, left, right):
+    def _swapped_schema(self, left, right, join_type):
+        """[left | right] of the swapped children: a right outer join
+        run as a left outer one has every field of its new left side
+        nullable, as it always had here; an inner join changes none."""
         from spark_rapids_tpu.sqltypes import StructField, StructType
 
+        outer = join_type == "left"
         return StructType(
-            [StructField(f.name, f.dataType, True)
+            [StructField(f.name, f.dataType, True if outer else f.nullable)
              for f in left.schema.fields] +
             [StructField(f.name, f.dataType, f.nullable)
              for f in right.schema.fields])

@@ -638,6 +638,133 @@ def test_q3s_own_final_aggregate_and_sort_compile_in_seconds(
     assert seconds < limit_s, seconds    # 1.3-1.8 s and 0.9-1.1 s
 
 
+# ------------------------- TPC-H Q3's own chain (customer, orders, lineitem)
+
+H3_PART = 7_864_320       # slots of one of SF10 lineitem's 8 parts
+H3_BUILD = 15_728_640     # `orders` under `customer`: 8 parts of 1,966,080
+H3_ROWS = 1_000_000       # the rehearsal: parts of 131,072 slots
+
+
+@pytest.fixture(scope="module")
+def tpch_q3_chain():
+    """`tpch_q3_join_resident`'s big chain program — `lineitem`'s
+    filter, the shuffled join to the derived build side (`orders` under
+    the segment's customers) at full width, the bet on its matches, the
+    3-key partial aggregate — as a hot query of a 1,000,000-row
+    rehearsal traces it on this CPU: (the function the engine hands to
+    jit, the inputs of one call)."""
+    from benchmark import run
+    from spark_rapids_tpu.exec import joins
+    from spark_rapids_tpu.ops import joinops
+    from spark_rapids_tpu.runtime import jit_cache
+
+    seen = []
+    real = jit_cache.cached_jit
+
+    def spy(key, build, **kw):
+        jitted = real(key, build, **kw)
+
+        def call(*inputs):
+            if key[1] == "chain" and any(
+                    isinstance(i, joinops.BuildIndex) for i in inputs):
+                seen.append((build(), inputs))
+            return jitted(*inputs)
+        return call
+
+    def holds_a_join(node):
+        return isinstance(node, joins._DeviceJoinBase) or any(
+            holds_a_join(c) for c in node.children)
+
+    range_of = joins._DeviceJoinBase.build_key_range
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jit_cache, "cached_jit", spy)
+    mp.setattr(run, "session_conf",
+               lambda config: dict(config["session_conf"]))
+    # at SF10 a table of `o_orderkey`'s 60M values would take 58 chunks
+    # of 15.7M offers each and the derived build side keeps its sorted
+    # index (exec/fused.py `build_table`); the rehearsal's 1M values are
+    # one chunk: no key range there, as at SF10's sizes
+    mp.setattr(joins._DeviceJoinBase, "build_key_range",
+               lambda self, right: None if holds_a_join(self.children[1])
+               else range_of(self, right))
+    try:
+        res = run.run_cell("tpch_q3_join_resident", 2_147_483_777, 0.1,
+                           False, rows=H3_ROWS, any_platform=True)
+    finally:
+        mp.undo()
+    assert res["correct"] and seen
+    return seen[-1]
+
+
+def test_tpch_q3s_own_chain_at_sf10_searches_a_row_a_level_at_full_width(
+        tpch_q3_chain, one_chip, no_persistent_cache):
+    """The program the cell spends its time in, lowered again at SF10's
+    widths: 7,864,320 probe slots search 15,728,640 build slots a row
+    of 128 keys a level (3 reads, in blocks of 131,072 probes), the
+    matches' row ids descend the mask's prefix sum the same way, the
+    122,880 slots they are brought to carry every later gather, and no
+    buffer of the program has the 2^28 slots an expanding join would
+    ask for (PERF.md section 6, PR 35)."""
+    from spark_rapids_tpu.exec.fused import survivor_capacity
+    from spark_rapids_tpu.ops import joinops
+
+    fn, (probe, build) = tpch_q3_chain
+    (jp,) = fn.__kwdefaults__["_plan"]
+    assert (jp["lowering"], jp["bet"], jp["probe"]) == (
+        "lookupSurvivors", "buildFilter", "search")
+    assert jp["searchedSlots"] == jp["probeSlots"] == probe.capacity
+    dims = {probe.capacity: H3_PART, build.capacity: H3_BUILD,
+            survivor_capacity(probe.capacity): survivor_capacity(H3_PART)}
+    # (a rehearsal's files are under the million rows from which a PLAIN
+    # file's batch takes its bucket: capacity == rows, 8 x 125,000)
+    assert len(dims) == 3 and dims[2_048] == 122_880
+    # the stamped ranges of SF10's keys: 60M order keys, 1.5M customers
+    ranges = {(0, 1_048_575): (0, 67_108_863), (0, 32_767): (0, 2_097_151)}
+    at_sf10 = type(fn)(fn.__code__, fn.__globals__, fn.__name__,
+                       fn.__defaults__, fn.__closure__)
+    at_sf10.__kwdefaults__ = dict(fn.__kwdefaults__, _plan=[{
+        k: dims.get(v, v) if type(v) is int else v for k, v in jp.items()}])
+
+    def widened(tree):
+        def column(c):
+            if not isinstance(c, DeviceColumn):
+                return _sds(tuple(dims.get(d, d) for d in c.shape), c.dtype,
+                            one_chip)
+            leaves, aux = c._tree_flatten()
+            aux = aux[:4] + (ranges.get(aux[4], aux[4]),) + aux[5:]
+            return DeviceColumn._tree_unflatten(
+                aux, [widened(leaf) for leaf in leaves])
+        return jax.tree_util.tree_map(
+            column, tree, is_leaf=lambda x: isinstance(x, DeviceColumn))
+
+    c, _, seconds = _timed_compile(at_sf10, widened(probe), widened(build))
+    text = c.as_text()
+    lines = text.splitlines()
+    assert "268435456" not in text and "67108864" not in text
+    assert not [ln for ln in lines
+                if " while(" in ln and f"s32[{H3_BUILD}]" in ln
+                and "128]" not in ln]
+    rows = [ln for ln in lines
+            if " gather(" in ln and "slice_sizes={1,128}" in ln]
+    assert len(rows) == (joinops.search_reads(H3_BUILD)
+                         + joinops.search_reads(H3_PART))
+    # the key's search runs a block of probes at a time, the row ids of
+    # the matches at their own capacity
+    assert sum("[131072,128]" in ln for ln in rows) == 3
+    assert sum("[122880,128]" in ln for ln in rows) == 3
+    # whether a probe matched is read off the row the search fetched
+    # last, and the key after a match is read at the survivors' width:
+    # no gather of one element a full-width slot (27 ns a slot, 1.7 s a
+    # query each of the two: PERF.md section 6, PR 35)
+    assert not [ln for ln in lines if " gather(" in ln
+                and f"[{H3_PART}]" in ln.split(" gather(")[0]]
+    # one sort, the group-by's, over the survivors' slots only
+    (sort,) = [ln for ln in lines if " sort(" in ln]
+    assert "[122880]" in sort
+    assert seconds < 120, seconds        # 33 s when written (sandbox)
+    assert c.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
 def test_expanded_join_gather_maps(one_chip, as_tpu):
     """The dup-key join's blocking lowering: (lo, counts) expanded to
     probe/build gather maps at a static output capacity (2 matches per

@@ -129,7 +129,7 @@ def test_a_conjunct_goes_down_through_two_joins_to_its_relation():
         df.explain()
         assert df.collect_arrow().num_rows >= 0
         assert spark.last_execution["plan"] == {
-            "pushedThroughJoin": 3,
+            "pushedThroughJoin": 3, "buildSidesSwapped": 0,
             "nodes": spark.last_execution["plan"]["nodes"]}
     finally:
         spark.stop()

@@ -110,12 +110,52 @@ def test_probes_past_one_block_are_searched_a_block_at_a_time(monkeypatch):
                                   expected(keys, probe, bound, False))
 
 
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("n,bound,block", [
+    (1, 1, None), (127, 100, None), (128 * 128 + 5, 128 * 128 - 3, None),
+    (3_000, 2_500, 256), (128 ** 3 + 1, 128 ** 3 - 9, None)])
+def test_the_lower_bound_says_whether_its_key_equals_the_probe(
+        monkeypatch, dtype, n, bound, block):
+    """`with_equal`: whether `keys[lo] == probe` inside the bound, read
+    off the row the last level fetched (a lookup join's `matched`, for
+    no gather of its own): duplicates, rows that end at the bound, a
+    probe wider than the keys and outside their width."""
+    if block:
+        monkeypatch.setattr(joinops, "_PROBE_BLOCK", block)
+    rng = np.random.default_rng([n, bound])
+    keys = sorted_keys(rng, dtype, n, bound)
+    probe = np.concatenate([
+        probes(rng, keys, bound),
+        np.array([np.iinfo(np.int64).min, np.iinfo(np.int64).max,
+                  2 ** 40, -2 ** 40], np.int64)])
+    lo, equal = joinops._count_below(
+        jnp.asarray(keys), jnp.asarray(probe), jnp.int32(bound), False,
+        with_equal=True)
+    want = expected(keys, probe, bound, False)
+    np.testing.assert_array_equal(np.asarray(lo), want)
+    at = np.clip(want, 0, n - 1)
+    np.testing.assert_array_equal(
+        np.asarray(equal), (want < bound) & (keys[at] == probe))
+    # and with no bound: all of the array is sorted
+    whole = np.sort(keys)
+    lo, equal = joinops._count_below(jnp.asarray(whole), jnp.asarray(probe),
+                                     None, False, with_equal=True)
+    want = np.searchsorted(whole, probe, side="left")
+    np.testing.assert_array_equal(np.asarray(lo), want)
+    np.testing.assert_array_equal(
+        np.asarray(equal),
+        (want < n) & (whole[np.clip(want, 0, n - 1)] == probe))
+
+
 def test_no_key_and_no_probe():
     none = jnp.zeros((0,), jnp.int64)
     some = jnp.arange(4, dtype=jnp.int64)
     assert joinops._count_below(none, some, jnp.int32(0), False).tolist() \
         == [0, 0, 0, 0]
     assert joinops._count_below(some, none, jnp.int32(4), True).shape == (0,)
+    lo, equal = joinops._count_below(none, some, jnp.int32(0), False,
+                                     with_equal=True)
+    assert lo.tolist() == [0] * 4 and equal.tolist() == [False] * 4
 
 
 @pytest.mark.parametrize("arrays,loops", [(1, False), (2, True)],
