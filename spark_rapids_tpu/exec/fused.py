@@ -1005,7 +1005,7 @@ class FusedSingleChipExecutor:
         def dispatch(name, sp, key_tag, nodes_key, fn, inputs,
                      uses_expansion=False, uses_group_cap=False,
                      uses_ansi=False, survivor_joins=(), uniq_joins=(),
-                     final_agg=None):
+                     window_joins=(), final_agg=None):
             # chaos site device.dispatch: an injected fault here is the
             # fused engine "dying mid-dispatch"; the dispatch ladder
             # (api/dataframe.py) demotes the query to the eager engine
@@ -1078,7 +1078,9 @@ class FusedSingleChipExecutor:
                                        for s in sorts)
             # fl: scalar=[cap] | [cap, uniq, push] (chain programs), then
             # one lost-bet flag for each of `survivor_joins`, then what
-            # nothing reads (joinops.rows_at)
+            # nothing reads (joinops.rows_at); rest: the ANSI vector
+            # where the program has one, then, a scalar for each of
+            # `window_joins`, the blocks of its search that took a window
             fl = jnp.asarray(fl).reshape(-1)
             flags.append((final_agg, fl[0]))
             if fl.shape[0] > 1:
@@ -1086,8 +1088,10 @@ class FusedSingleChipExecutor:
                 push_flags.append(fl[2])
             surv_flags.extend(
                 (k, fl[3 + i]) for i, k in enumerate(survivor_joins))
-            if rest:
-                ansi_flags.append(rest[0])
+            if uses_ansi:
+                ansi_flags.append(rest.pop(0))
+            for k, took in zip(window_joins, rest):
+                joins[k]["windowedBlocks"].append(took)
             return out
 
         def ansi_vec(exprs, b, live):
@@ -1138,6 +1142,7 @@ class FusedSingleChipExecutor:
             join_plan = list(join_plan)
             lost = []  # one flag per "lookupSurvivors" join
             unread = []  # joinops.rows_at: an output's place, no flag
+            windows = []  # one count per join whose search is blocked
 
             def materialized(b, mask):
                 return b if mask is None else filterops.compact(b, mask)
@@ -1146,7 +1151,8 @@ class FusedSingleChipExecutor:
                 return b.live_mask() if mask is None \
                     else mask & b.live_mask()
 
-            def lookup_join(nd, b, mask, bt, uniq, bet_to=None):
+            def lookup_join(nd, b, mask, bt, uniq, bet_to=None,
+                            blocked=False):
                 """Row-preserving join-as-gather (see _is_lookup_join):
                 probe rows keep their positions; match/no-match lands
                 in the pending mask (inner/semi/anti), the exists
@@ -1158,7 +1164,10 @@ class FusedSingleChipExecutor:
                 `bet_to`: the join's build side sits under a filter,
                 so its MATCHES are brought to the front of a batch of
                 that capacity before any build column is read
-                (chain_joins); -> also whether they did not fit."""
+                (chain_joins); -> also whether they did not fit.
+                `blocked`: the probes are searched a block at a time
+                (`searchBlocks`), and how many blocks took a window of
+                the index goes to `windows`."""
                 work_l, lk = nd._prepare_keys(b, nd.left_keys)
                 # `at`: the matching build row itself, or its place in
                 # the sorted index
@@ -1167,7 +1176,9 @@ class FusedSingleChipExecutor:
                     at, matched, dup = joinops.probe_positions(
                         bt, work_l, lk)
                 else:
-                    at, matched = joinops.probe_matched(bt, work_l, lk)
+                    at, matched, *took = joinops.probe_matched(
+                        bt, work_l, lk, with_windowed=blocked)
+                    windows.extend(took)
                 jt = nd.join_type
                 over = None
 
@@ -1253,7 +1264,8 @@ class FusedSingleChipExecutor:
                     b, mask, uniq, over = lookup_join(
                         nd, b, mask, builds.pop(0), uniq,
                         jp["outputCapacity"]
-                        if "buildFilter" in jp["bet"] else None)
+                        if "buildFilter" in jp["bet"] else None,
+                        blocked=jp["searchBlocks"] > 0)
                     if over is not None:
                         lost.append(over)
                 elif isinstance(nd, ops.TpuFilterExec):
@@ -1320,9 +1332,8 @@ class FusedSingleChipExecutor:
                 out = (ColumnBatch(b.schema, cols, b.capacity),
                        visible(b, mask))
             fl = jnp.stack([ovf, uniq, push] + lost + unread)
-            if ansi_live:
-                return out, fl, ansi
-            return out, fl
+            return (out, fl) + ((ansi,) if ansi_live else ()) \
+                + tuple(windows)
 
         def emit_parts(node: PhysicalPlan, read=None) -> List[ColumnBatch]:
             """`read`: the ordinals of `node`'s output its consumer
@@ -1613,12 +1624,13 @@ class FusedSingleChipExecutor:
             """Add one program's share of a join to the run's record;
             `rows`: the device scalars that sum to its build rows."""
             if key not in joins:
-                joins[key] = dict(rec, buildRows=rows)
+                joins[key] = dict(rec, buildRows=rows, windowedBlocks=[])
                 return
             was = joins[key]
             if rec["lowering"] not in was["lowering"].split("+"):
                 was["lowering"] += "+" + rec["lowering"]
-            for k in ("probeSlots", "searchedSlots", "outputCapacity"):
+            for k in ("probeSlots", "searchedSlots", "outputCapacity",
+                      "searchBlocks"):
                 was[k] = (None if None in (was[k], rec[k])
                           else was[k] + rec[k])
 
@@ -1707,6 +1719,10 @@ class FusedSingleChipExecutor:
                     jp["probe"] = "position" if by_position else "search"
                     jp["probeSteps"] = 1 if by_position \
                         else joinops.search_reads(slots)
+                    # a width that is an aggregate's own is not known
+                    # here, and its search is not counted
+                    jp["searchBlocks"] = 0 if by_position \
+                        else joinops.search_blocks(jp["searchedSlots"] or 0)
                     note_join(key, jp, [bt.num_rows])
                     if "probeFilter" in jp["bet"]:
                         bets.append(key)
@@ -1741,7 +1757,10 @@ class FusedSingleChipExecutor:
                 return run_program("chain", marked, stage_fn,
                                    [b] + builds, join_fields=plan,
                                    survivor_joins=tuple(bets),
-                                   uniq_joins=tuple(uniq_keys), **uses)
+                                   uniq_joins=tuple(uniq_keys),
+                                   window_joins=tuple(
+                                       k for k, jp in zip(join_keys, plan)
+                                       if jp["searchBlocks"]), **uses)
 
             return [one(b, plan) for b, plan in zip(base, plans)]
 
@@ -1985,6 +2004,8 @@ class FusedSingleChipExecutor:
                        "joinType": node.join_type,
                        "probeSlots": probe_slots,
                        "searchedSlots": probe_slots,
+                       # only a lookup join's search is counted
+                       "searchBlocks": 0,
                        "outputCapacity": out_cap,
                        "buildSlots": build_slots,
                        # shard_equi_join sorts the whole build side
@@ -2036,21 +2057,25 @@ class FusedSingleChipExecutor:
             """all_flags_arr() inside the `fetch` span `sp`: a dozen
             tiny device operations enqueued from the host, timed apart
             (`flagsNs`) from the wait that follows. The build sides'
-            row counts and the final aggregates' groups ride the same
-            fetch."""
+            row counts, the blocks of their searches that took a window
+            and the final aggregates' groups ride the same fetch."""
             t0 = time.monotonic_ns()
             arr, ns = all_flags_arr()
             sp.set(flagsNs=time.monotonic_ns() - t0)
             return (arr, [j["buildRows"] for j in joins.values()],
-                    [g["found"] for g in groups]), ns
+                    [g["found"] for g in groups],
+                    [j["windowedBlocks"] for j in joins.values()]), ns
 
         def settle(host, ns):
-            """The fetched (flags, build rows, groups found): raise
-            what the flags say, else complete the run's records."""
-            host_flags, host_rows, host_found = host
+            """The fetched (flags, build rows, groups found, windowed
+            blocks): raise what the flags say, else complete the run's
+            records."""
+            host_flags, host_rows, host_found, host_took = host
             _check_host_flags(np.asarray(host_flags), *ns)
-            for rec, rows in zip(joins.values(), host_rows):
+            for rec, rows, took in zip(joins.values(), host_rows,
+                                       host_took):
                 rec["buildRows"] = sum(int(r) for r in rows)
+                rec["windowedBlocks"] = sum(int(t) for t in took)
             for rec, found in zip(groups, host_found):
                 rec["found"] = int(found)
             self._run_joins = list(joins.values())

@@ -57,7 +57,8 @@ EVENT_TYPES: Dict[str, str] = {
     "compile": "kind (miss|hit), seconds",
     "degrade": "kind, from, to, reason",
     "join": "lowering, joinType, buildRows, buildSlots, probeSlots, "
-            "searchedSlots, outputCapacity, runs",
+            "searchedSlots, searchBlocks, windowedBlocks, "
+            "outputCapacity, runs",
     "chaos": "site",
     "admission.queued": "queryId, depth, running",
     "admission.admitted": "queryId, waitMs",

@@ -252,6 +252,16 @@ _LANES = 128
 #: probes searched at a time: the rows they read are held, 64 MiB of
 #: 32-bit keys a level (a full-width probe of 7.9M slots would hold 4 GB)
 _PROBE_BLOCK = 1024 * _LANES
+#: rows of the bottom level that a block of probes whose answers lie
+#: close together searches in place of the whole level: as many keys
+#: as the block has probes, so a block of clustered foreign keys whose
+#: parent keys each appear at least once always fits. A row read from
+#: such a slice (512 KB, in fast memory) costs the chip 2.48 ns, as one
+#: from 128 rows does (2.49), wherever its indices fall; from the whole
+#: 63 MB level of TPC-H Q3's index 16.3 ns where a block's sorted
+#: indices repeat over 27 rows, 9.3 where they are spread or shuffled
+#: (PERF.md section 6, PR 36)
+_WINDOW_ROWS = _PROBE_BLOCK // _LANES
 
 
 def _as_rows(keys: jnp.ndarray, fill) -> jnp.ndarray:
@@ -271,9 +281,18 @@ def search_reads(slots: int) -> int:
     return reads
 
 
+def search_blocks(probes: int) -> int:
+    """Blocks `_count_below` searches `probes` probes in, each free to
+    take a window of the keys' bottom level; 0: they fit one block and
+    are searched at once, over all of it."""
+    return -(-probes // _PROBE_BLOCK) if probes > _PROBE_BLOCK else 0
+
+
 def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
                  bound: Optional[jnp.ndarray], upper: bool,
-                 with_equal: bool = False):
+                 with_equal: bool = False,
+                 valid: Optional[jnp.ndarray] = None,
+                 with_windowed: bool = False):
     """Per probe, how many positions `i < bound` hold `keys[i] < probe`
     (`<=` with `upper`): the lower / upper bound of each probe in a
     ONE-array key that is sorted on [0, bound) (`bound` None: all of
@@ -289,13 +308,39 @@ def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
     row that holds the bound: the level above chose the first row
     whose last key is not below the probe) and so for no read at all,
     where a gather of `keys[lo]` costs a full-width probe 27 ns a slot
-    (PERF.md, PR 35)."""
+    (PERF.md, PR 35).
+
+    More probes than `_PROBE_BLOCK` are searched a block at a time
+    (`search_blocks`), and each block looks at its own probes first:
+    the bounds of its smallest and largest key, two probes' descent,
+    enclose every other's. Where they lie within `_WINDOW_ROWS` rows
+    of each other (a probe side clustered by the key it probes with:
+    a fact table written in its parent's order) the block descends
+    the levels of THAT window of the bottom level, a slice small
+    enough for fast memory, and not the whole array's; where they do
+    not, the whole array's, as a single block does. One descent, two
+    sets of levels. `valid`: the probes that count for a block's
+    span (None: all); the others get some position in range, and
+    their callers mask them (so a block of dead slots alone, the end
+    of a part that is not full, takes a window too). `with_windowed`:
+    -> also how many blocks took the window, an int32 scalar."""
     n, nq = keys.shape[0], probe.shape[0]
+
+    def asked_of(found, equal, took):
+        """(positions[, equal][, blocks windowed]) as the caller asked;
+        `took`: a flag a block, or None where none was searched."""
+        out = (found,) + ((equal,) if with_equal else ())
+        if with_windowed:
+            out += (jnp.zeros((), jnp.int32) if took is None
+                    else jnp.sum(took, dtype=jnp.int32),)
+        return out if len(out) > 1 else out[0]
+
     if n == 0 or nq == 0:
-        found = jnp.zeros((nq,), jnp.int32)
-        return (found, jnp.zeros((nq,), bool)) if with_equal else found
+        return asked_of(jnp.zeros((nq,), jnp.int32),
+                        jnp.zeros((nq,), bool), None)
     integer = jnp.issubdtype(keys.dtype, jnp.integer)
     top = jnp.iinfo(keys.dtype).max if integer else jnp.inf
+    bottom = jnp.iinfo(keys.dtype).min if integer else -jnp.inf
     outside = None
     if (integer and jnp.issubdtype(probe.dtype, jnp.integer)
             and probe.dtype.itemsize > keys.dtype.itemsize):
@@ -308,17 +353,27 @@ def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
     limit = jnp.int32(n) if bound is None else bound.astype(jnp.int32)
     lanes = jnp.arange(_LANES, dtype=jnp.int32)
 
-    levels = [_as_rows(keys, top)]
-    while True:
-        last = levels[-1][:, -1]
-        if len(levels) == 1 and bound is not None:
-            # past the bound the array is not sorted: a row that
-            # reaches there ends the descent
-            ends = jnp.arange(last.shape[0], dtype=jnp.int32) * _LANES
-            last = jnp.where(ends + (_LANES - 1) < limit, last, top)
-        if last.shape[0] <= _LANES:
-            break
-        levels.append(_as_rows(last, top))
+    def from_row(base, row):
+        # the array's row that is row `row` of a tree's bottom level
+        # (None: the tree is the whole array's, and adds nothing)
+        return row if base is None else base + row
+
+    def levels_over(rows, base=None):
+        """The tree over `rows`, which are the array's rows from row
+        `base` on: -> (levels, bottom up; the top level's last keys,
+        one row at most)."""
+        levels = [rows]
+        while True:
+            last = levels[-1][:, -1]
+            if len(levels) == 1 and bound is not None:
+                # past the bound the array is not sorted: a row that
+                # reaches there ends the descent
+                ends = from_row(base, jnp.arange(
+                    last.shape[0], dtype=jnp.int32)) * _LANES
+                last = jnp.where(ends + (_LANES - 1) < limit, last, top)
+            if last.shape[0] <= _LANES:
+                return levels, last
+            levels.append(_as_rows(last, top))
 
     def below(held, q):
         return held <= q if upper else held < q
@@ -333,7 +388,10 @@ def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
                        preferred_element_type=jnp.float32
                        ).astype(jnp.int32)
 
-    def search(q):
+    def descend(q, tree, base=None):
+        """Each probe's position (and `equal`) by the levels of
+        `tree`, whose bottom rows are the array's from row `base`."""
+        levels, last = tree
         q = q[:, None]
         node = count(below(last[None, :], q))
         equal = None
@@ -342,6 +400,7 @@ def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
             row = jnp.take(level, node, axis=0, mode="clip")
             hit = below(row, q)
             if level is levels[0]:
+                node = from_row(base, node)
                 inside = (node[:, None] * _LANES + lanes
                           < (limit if bound is not None else n))
                 if bound is not None:
@@ -351,33 +410,69 @@ def _count_below(keys: jnp.ndarray, probe: jnp.ndarray,
             node = node * _LANES + count(hit)
         return (node, equal) if with_equal else node
 
-    if nq <= _PROBE_BLOCK:
+    whole = levels_over(_as_rows(keys, top))
+    rows = whole[0][0].shape[0]
+
+    def search(q):
+        return descend(q, whole)
+
+    def search_block(block):
+        """One block of probes and their `valid` -> (what `search`
+        gives, whether the block took the window)."""
+        q, ok = block
+        qmin = jnp.min(jnp.where(ok, q, top))
+        qmax = jnp.max(jnp.where(ok, q, bottom))
+        ends = search(jnp.stack([qmin, qmax]))
+        r0, r1 = (ends[0] if with_equal else ends) // _LANES
+        # (no valid probe: r1 is row 0, and any window serves)
+        narrow = r1 - r0 < _WINDOW_ROWS
+        if not integer:
+            # a NaN among the probes says nothing of the others
+            narrow = narrow & ~(jnp.isnan(qmin) | jnp.isnan(qmax))
+
+        def windowed(q):
+            start = jnp.clip(r0, 0, rows - _WINDOW_ROWS)
+            win = lax.dynamic_slice(whole[0][0],
+                                    (start, jnp.zeros_like(start)),
+                                    (_WINDOW_ROWS, _LANES))
+            return descend(q, levels_over(win, start), start)
+
+        return (lax.cond(narrow, windowed, search, q),
+                narrow.astype(jnp.int32))
+
+    blocks, took = search_blocks(nq), None
+    if not blocks:
         out = search(probe)
     else:
-        blocks = -(-nq // _PROBE_BLOCK)
-        padded = jnp.pad(probe, (0, blocks * _PROBE_BLOCK - nq))
-        out = jax.tree_util.tree_map(
-            lambda a: a.reshape(-1)[:nq],
-            lax.map(search, padded.reshape(blocks, _PROBE_BLOCK)))
+        pad = (0, blocks * _PROBE_BLOCK - nq)
+        ok = jnp.ones((nq,), bool) if valid is None else valid
+        one = search_block if rows > _WINDOW_ROWS \
+            else lambda block: (search(block[0]), jnp.int32(0))
+        out, took = lax.map(
+            one, (jnp.pad(probe, pad).reshape(blocks, _PROBE_BLOCK),
+                  jnp.pad(ok, pad).reshape(blocks, _PROBE_BLOCK)))
+        out = jax.tree_util.tree_map(lambda a: a.reshape(-1)[:nq], out)
     found, equal = out if with_equal else (out, None)
     found = jnp.minimum(found, limit)
     if outside is not None:
         found = jnp.where(outside[0], 0, jnp.where(outside[1], limit, found))
         if with_equal:
             equal = equal & ~outside[0] & ~outside[1]
-    return (found, equal) if with_equal else found
+    return asked_of(found, equal, took)
 
 
 def _binary_search(build_keys: List[jnp.ndarray],
                    probe_keys: List[jnp.ndarray], bound: jnp.ndarray,
-                   build_cap: int, upper: bool) -> jnp.ndarray:
+                   build_cap: int, upper: bool,
+                   valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """First index in [0, bound) where build[idx] >= probe (lower) or
     > probe (upper); vectorized over probe rows. A ONE-array key is
-    searched a row of 128 keys a level (`_count_below`); a tuple of
-    key arrays (strings packed to words, several columns) a key a
-    step."""
+    searched a row of 128 keys a level (`_count_below`, which `valid`
+    is for); a tuple of key arrays (strings packed to words, several
+    columns) a key a step."""
     if len(build_keys) == 1:
-        return _count_below(build_keys[0], probe_keys[0], bound, upper)
+        return _count_below(build_keys[0], probe_keys[0], bound, upper,
+                            valid=valid)
     n = probe_keys[0].shape[0]
     lo = jnp.zeros(n, dtype=jnp.int32)
     hi = jnp.broadcast_to(bound.astype(jnp.int32), (n,))
@@ -407,9 +502,9 @@ def probe_ranges(build: BuildTable, probe: ColumnBatch,
     live = probe.live_mask()
     vals, all_valid = _join_keys(probe, key_idxs, live)
     lo = _binary_search(build.keys, vals, build.valid_bound,
-                        build.batch.capacity, upper=False)
+                        build.batch.capacity, upper=False, valid=all_valid)
     hi = _binary_search(build.keys, vals, build.valid_bound,
-                        build.batch.capacity, upper=True)
+                        build.batch.capacity, upper=True, valid=all_valid)
     counts = jnp.where(all_valid, hi - lo, 0).astype(jnp.int32)
     return lo, counts
 
@@ -423,22 +518,25 @@ def _keys_equal_at(build_keys: List[jnp.ndarray], idx: jnp.ndarray,
 
 
 def probe_matched(build: Union[BuildTable, BuildIndex],
-                  probe: ColumnBatch, key_idxs: Sequence[int]
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                  probe: ColumnBatch, key_idxs: Sequence[int],
+                  with_windowed: bool = False):
     """Per-probe-row (lo, matched) for a lookup join: ONE lower-bound
     search. Over a one-array key whether the build key at `lo` equals
     the probe is read off the row the search fetched last
     (`_count_below`), for no read of its own; a tuple of key arrays
-    reads the keys at `lo`."""
+    reads the keys at `lo`. `with_windowed`: -> also how many of the
+    search's blocks took a window of the keys (`search_blocks`)."""
     vals, all_valid = _join_keys(probe, key_idxs, probe.live_mask())
     if len(build.keys) == 1:
-        lo, equal = _count_below(build.keys[0], vals[0], build.valid_bound,
-                                 upper=False, with_equal=True)
-        return lo, all_valid & equal
+        lo, equal, *took = _count_below(
+            build.keys[0], vals[0], build.valid_bound, upper=False,
+            with_equal=True, valid=all_valid, with_windowed=with_windowed)
+        return (lo, all_valid & equal, *took)
     cap = build.keys[0].shape[0]
     lo = _binary_search(build.keys, vals, build.valid_bound, cap,
                         upper=False)
-    return lo, all_valid & _equal_at(build, lo, vals)
+    return (lo, all_valid & _equal_at(build, lo, vals)) \
+        + ((jnp.zeros((), jnp.int32),) if with_windowed else ())
 
 
 def _equal_at(build, idx: jnp.ndarray, vals: List[jnp.ndarray]

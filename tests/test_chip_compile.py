@@ -700,7 +700,8 @@ def test_tpch_q3s_own_chain_at_sf10_searches_a_row_a_level_at_full_width(
         tpch_q3_chain, one_chip, no_persistent_cache):
     """The program the cell spends its time in, lowered again at SF10's
     widths: 7,864,320 probe slots search 15,728,640 build slots a row
-    of 128 keys a level (3 reads, in blocks of 131,072 probes), the
+    of 128 keys a level (3 reads, in blocks of 131,072 probes; 2, of
+    a window of the index, where a block's keys lie close), the
     matches' row ids descend the mask's prefix sum the same way, the
     122,880 slots they are brought to carry every later gather, and no
     buffer of the program has the 2^28 slots an expanding join would
@@ -722,8 +723,10 @@ def test_tpch_q3s_own_chain_at_sf10_searches_a_row_a_level_at_full_width(
     ranges = {(0, 1_048_575): (0, 67_108_863), (0, 32_767): (0, 2_097_151)}
     at_sf10 = type(fn)(fn.__code__, fn.__globals__, fn.__name__,
                        fn.__defaults__, fn.__closure__)
-    at_sf10.__kwdefaults__ = dict(fn.__kwdefaults__, _plan=[{
-        k: dims.get(v, v) if type(v) is int else v for k, v in jp.items()}])
+    at_sf10.__kwdefaults__ = dict(fn.__kwdefaults__, _plan=[dict(
+        {k: dims.get(v, v) if type(v) is int else v for k, v in jp.items()},
+        # the rehearsal's 125,000 probes fit one block; a part's do not
+        searchBlocks=joinops.search_blocks(H3_PART))])
 
     def widened(tree):
         def column(c):
@@ -746,12 +749,46 @@ def test_tpch_q3s_own_chain_at_sf10_searches_a_row_a_level_at_full_width(
                 and "128]" not in ln]
     rows = [ln for ln in lines
             if " gather(" in ln and "slice_sizes={1,128}" in ln]
-    assert len(rows) == (joinops.search_reads(H3_BUILD)
-                         + joinops.search_reads(H3_PART))
-    # the key's search runs a block of probes at a time, the row ids of
-    # the matches at their own capacity
-    assert sum("[131072,128]" in ln for ln in rows) == 3
-    assert sum("[122880,128]" in ln for ln in rows) == 3
+
+    def table_of(ln):
+        """Shape of the table a gather's line reads: its first operand,
+        as the lines above define it."""
+        name = ln.split(" gather(")[1].split(",")[0]
+        return next(above.split(" = ")[1].split("{")[0]
+                    for above in reversed(lines[:lines.index(ln)])
+                    if above.strip().startswith(name + " = "))
+
+    def reads(where, width):
+        return sorted(table_of(ln) for ln in rows
+                      if where in ln and f"[{width},128]" in
+                      ln.split(" gather(")[0])
+
+    # the key's search runs a block of 131,072 probes at a time. A
+    # block first descends the whole index for its smallest and its
+    # largest key, 3 reads each
+    level = f"s32[{H3_BUILD // 128},128]"
+    whole = sorted(["s32[8,128]", "s32[960,128]", level])
+    assert reads("while/body", 2) == whole
+    # then takes one of two branches. Where they lie far apart, the
+    # whole index again, a row a level
+    assert reads("cond/branch_0_fun", 131_072) == whole
+    # where they lie close (a probe side clustered by its key), a
+    # window of 1,024 rows cut from the bottom level and the 8 rows of
+    # its last keys, both small enough for fast memory, and NEVER the
+    # 63 MB level itself: 17.4 ns a row there, 1,095 of the query's
+    # 2,890 device ms (PERF.md section 6, PR 36)
+    window = f"s32[{joinops._WINDOW_ROWS},128]"
+    assert reads("cond/branch_1_fun", 131_072) == sorted(
+        ["s32[8,128]", window])
+    (cut,) = [ln for ln in lines if " dynamic-slice(" in ln
+              and ln.strip().startswith("ROOT")
+              and f" {window}" in ln.split(" dynamic-slice(")[0]]
+    assert "S(1)" in cut.split(" dynamic-slice(")[0]
+    # the row ids of the matches descend the mask's prefix sum at their
+    # own capacity, outside the loop
+    assert len(reads("", 122_880)) == joinops.search_reads(H3_PART) == 3
+    assert len(rows) == 2 * joinops.search_reads(H3_BUILD) + 2 + 3
+    assert sum(" conditional(" in ln for ln in lines) == 1
     # whether a probe matched is read off the row the search fetched
     # last, and the key after a match is read at the survivors' width:
     # no gather of one element a full-width slot (27 ns a slot, 1.7 s a
