@@ -610,6 +610,7 @@ class DataFrame:
         """What the optimizer's rules did on the way (plan/optimizer.py
         `optimize`) is left in `self._optimizer_notes`."""
         from spark_rapids_tpu.config import rapids_conf as rc
+        from spark_rapids_tpu.obs import events as obs_events
         from spark_rapids_tpu.plan.optimizer import optimize
         from spark_rapids_tpu.plan.overrides import plan_query
 
@@ -630,8 +631,11 @@ class DataFrame:
         plan = self.session.cache_manager.substitute(self._plan)
         plan = _pin_query_time(plan)
         self._optimizer_notes = {"pushedThroughJoin": 0}
-        return plan_query(optimize(plan, self._optimizer_notes),
-                          self.session.rapids_conf)
+        plan = optimize(plan, self._optimizer_notes)
+        # the overrides pass: tagging, conversion, row estimates and
+        # the build sides
+        with obs_events.span("plan.convert"):
+            return plan_query(plan, self.session.rapids_conf)
 
     # --- caching ---
     #

@@ -40,10 +40,10 @@ out-of-core engine, which remains the path for HBM-exceeding inputs.
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import hashlib
 import os
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
 
@@ -1052,7 +1052,6 @@ class FusedSingleChipExecutor:
                     # aggregate's capacity)
                     if flags and not defer_flags:
                         _check_host_flags(*flags_so_far())
-            sp.set(cacheHit=hit)
             # the XLA module is `jit_<name>`: what the device trace
             # calls this program
             fn.__name__ = fn.__qualname__ = name
@@ -1061,8 +1060,13 @@ class FusedSingleChipExecutor:
             # PJRT client surfacing here fences the engine for warm
             # recovery instead of leaking an XlaRuntimeError (or being
             # mistaken for a ladder-demotable dispatch fault)
+            # a built program's call is the PJRT enqueue (it blocks
+            # once about five programs are outstanding); a build's first
+            # call stays under its `compile` span
             with _dm.guard("fused.dispatch", detail=str(key_tag),
-                           inject=True):
+                           inject=True), (
+                    obs_events.span("fused.enqueue") if hit
+                    else contextlib.nullcontext()):
                 out, fl, *rest = jitted(*inputs)
             # how the program's partial aggregate lowered its sums was
             # decided when it was traced, and is kept with it
@@ -2053,18 +2057,26 @@ class FusedSingleChipExecutor:
                          for part in host for x in part]),
                     *flag_tags(flags))
 
-        def assembled_flags(sp):
-            """all_flags_arr() inside the `fetch` span `sp`: a dozen
-            tiny device operations enqueued from the host, timed apart
-            (`flagsNs`) from the wait that follows. The build sides'
-            row counts, the blocks of their searches that took a window
-            and the final aggregates' groups ride the same fetch."""
-            t0 = time.monotonic_ns()
+        def assembled_flags(result, copied=()):
+            """all_flags_arr(), a dozen tiny device operations enqueued
+            from the host, then the `fetch.wait` until they and
+            `result` are ready on the device: where the host learns
+            that the query's device work is done. The copies to the
+            host of the flags and of `copied` are started before the
+            wait, as the device_get that follows would start them, so
+            the wait adds no round trip. The build sides' row counts,
+            the blocks of their searches that took a window and the
+            final aggregates' groups ride the same fetch."""
             arr, ns = all_flags_arr()
-            sp.set(flagsNs=time.monotonic_ns() - t0)
-            return (arr, [j["buildRows"] for j in joins.values()],
-                    [g["found"] for g in groups],
-                    [j["windowedBlocks"] for j in joins.values()]), ns
+            extra = (arr, [j["buildRows"] for j in joins.values()],
+                     [g["found"] for g in groups],
+                     [j["windowedBlocks"] for j in joins.values()])
+            for leaf in jax.tree_util.tree_leaves((copied, extra)):
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
+            with obs_events.span("fetch.wait"):
+                jax.block_until_ready((result, extra))
+            return extra, ns
 
         def settle(host, ns):
             """The fetched (flags, build rows, groups found, windowed
@@ -2089,7 +2101,7 @@ class FusedSingleChipExecutor:
                 return parts, arr, ns
             # one host sync for overflow + ANSI; parts stay on device
             with obs_events.span("fetch", rows=0) as sp:
-                extra, ns = assembled_flags(sp)
+                extra, ns = assembled_flags(parts)
                 settle(telemetry.ledgered_get(extra, "fused.flags"), ns)
                 sp.set(bytes=extra[0].nbytes)
             return parts
@@ -2105,12 +2117,14 @@ class FusedSingleChipExecutor:
                 return widen_traced(b), jnp.zeros((), bool)
 
             result = run_program("collect1", ("collect1",), one_fn, parts)
-        # `fetch` blocks on the device: its length is the device time
-        # the dispatches left outstanding plus the transfer's own
+        # `fetch` is the device time the dispatches left outstanding
+        # (its `fetch.wait`) plus the host's own copy, conversion and
+        # settle
         with obs_events.span("fetch") as sp:
-            extra, ns = assembled_flags(sp)
             nbytes = result.device_size_bytes()
-            if nbytes <= self._fetch_fused_bytes:
+            small = nbytes <= self._fetch_fused_bytes
+            extra, ns = assembled_flags(result, result if small else ())
+            if small:
                 # small result: ONE round trip for rows+flags+data (the
                 # standard path pays three — row_count, flags, fetch)
                 from spark_rapids_tpu.columnar.arrow_bridge import (
