@@ -1723,6 +1723,10 @@ class FusedSingleChipExecutor:
                     jp["probe"] = "position" if by_position else "search"
                     jp["probeSteps"] = 1 if by_position \
                         else joinops.search_reads(slots)
+                    # the one read of a probe by position: a row of the
+                    # table where it has few enough, else an entry
+                    jp["tableRows"] = joinops.table_rows(
+                        bt.table.shape[0]) if by_position else 0
                     # a width that is an aggregate's own is not known
                     # here, and its search is not counted
                     jp["searchBlocks"] = 0 if by_position \

@@ -150,20 +150,53 @@ def build_positions(batch: ColumnBatch, key_idxs: Sequence[int],
                           jnp.sum(valid).astype(jnp.int32), rows)
 
 
+def table_rows(entries: int) -> int:
+    """Rows of 128 entries a probe by position reads a table of
+    `entries` as, a row a slot; 0: more than `_POSITION_ROWS`, and it
+    reads one entry a slot."""
+    rows = -(-entries // _LANES)
+    return rows if rows <= _POSITION_ROWS else 0
+
+
 def probe_positions(build: BuildPositions, probe: ColumnBatch,
                     key_idxs: Sequence[int]
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Per-probe-row (build row, matched, dup), as `probe_unique` and
-    `rows_at` give them together: one read of the table."""
+    `rows_at` give them together: one read of the table, a row of 128
+    entries whose lane the slot picks where the table has no more than
+    `_POSITION_ROWS` of them (`table_rows`), else the entry alone."""
     live = probe.live_mask()
     vals, all_valid = _join_keys(probe, key_idxs, live)
     size = build.table.shape[0]
     at = vals[0] - build.lo
     inside = all_valid & (at >= 0) & (at < size)
-    held = jnp.take(build.table, jnp.clip(at, 0, size - 1).astype(jnp.int32))
+    at = jnp.clip(at, 0, size - 1).astype(jnp.int32)
+    if table_rows(size):
+        held = _entries_by_row(_as_rows(build.table, -1), at)
+    else:
+        held = jnp.take(build.table, at)
     matched = inside & (held != -1)
     row = jnp.where(held < -1, -2 - held, held)
     return jnp.maximum(row, 0), matched, matched & (held < -1)
+
+
+def _entries_by_row(rows: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
+    """`rows.reshape(-1)[at]`: each slot reads its entry's row and keeps
+    the lane it names (exact: the other lanes add 0), `_PICK_BLOCK`
+    slots at a time, so the rows read are held a block at a time."""
+    lanes = jnp.arange(_LANES, dtype=jnp.int32)
+
+    def pick(at):
+        row = jnp.take(rows, at // _LANES, axis=0)
+        return jnp.sum(jnp.where(lanes == (at % _LANES)[:, None], row, 0),
+                       axis=1, dtype=rows.dtype)
+
+    n = at.shape[0]
+    if n <= _PICK_BLOCK:
+        return pick(at)
+    blocks = -(-n // _PICK_BLOCK)
+    at = jnp.pad(at, (0, blocks * _PICK_BLOCK - n))
+    return lax.map(pick, at.reshape(blocks, _PICK_BLOCK)).reshape(-1)[:n]
 
 
 def _join_keys(batch: ColumnBatch, key_idxs: Sequence[int],
@@ -262,6 +295,18 @@ _PROBE_BLOCK = 1024 * _LANES
 #: indices repeat over 27 rows, 9.3 where they are spread or shuffled
 #: (PERF.md section 6, PR 36)
 _WINDOW_ROWS = _PROBE_BLOCK // _LANES
+#: rows of 128 entries a table of positions may have and be read a row
+#: a slot: 3,670,016 uniform probes read a row and keep one lane at
+#: 3.21-3.28 ns a slot from tables of 73,049 to 4,194,304 entries (292
+#: KB to 16 MB), at 10.06-10.10 from 8,388,608 and 16,777,216 (32 and
+#: 64 MB), where one entry costs 7.33-7.41 ns at every size (TPU v5e;
+#: PERF.md section 6)
+_POSITION_ROWS = 32 * 1024
+#: probes that read their rows at a time: 4 MiB of rows held, 3.24-3.28
+#: ns a slot (3.24 in blocks of 131,072, which the CPU backend the tests
+#: run on reads four times slower); at full width the same reads write
+#: 1.8 GB of rows to HBM first, 3.33-3.39 ns (PERF.md section 6)
+_PICK_BLOCK = 8 * 1024
 
 
 def _as_rows(keys: jnp.ndarray, fill) -> jnp.ndarray:

@@ -612,10 +612,14 @@ def test_q3s_own_chain_at_sf10_sorts_one_operand_and_probes_by_position(
     (sort,) = [ln for ln in c.as_text().splitlines() if " sort(" in ln]
     assert "u32[1024]" in sort and "[57344]" not in sort
     # both bets' row ids read a row of the mask's prefix sum a level
-    # (the part's 3,670,016 slots, then the 57,344 that were left)
+    # (the part's 3,670,016 slots, then the 57,344 that were left), and
+    # both probes read a row of their table of positions a slot
+    assert [joinops.table_rows(t.table.shape[0]) for t in tables] == [
+        1_024, 32_768]
     assert len([ln for ln in c.as_text().splitlines()
                 if " gather(" in ln and "slice_sizes={1,128}" in ln]) == (
-        joinops.search_reads(STAR_PART) + joinops.search_reads(57_344))
+        joinops.search_reads(STAR_PART) + joinops.search_reads(57_344)
+        + len(tables))
     assert seconds < 30, seconds         # 3.0-3.9 s when written
     assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
 

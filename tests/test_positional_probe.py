@@ -8,13 +8,17 @@ did in `session.last_execution["join"]`."""
 
 import collections
 
+import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pytest
 
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.api.session import TpuSparkSession
+from spark_rapids_tpu.columnar.batch import ColumnBatch, DeviceColumn
 from spark_rapids_tpu.exec import fused
+from spark_rapids_tpu.ops import joinops
+from spark_rapids_tpu.sqltypes import LongType, StructField, StructType
 
 PROBE_ROWS, BUILD_ROWS = 20_000, 3_000
 CAPACITY = fused.survivor_capacity(65_536)
@@ -112,9 +116,12 @@ def test_join_by_position_equals_plain_join(spark, how, keys):
     if keys == "sparse":
         # 65,536 sorted keys are 512 rows of 128, whose last keys are 4
         # rows: two row reads under a top of 4 (joinops.search_reads)
-        assert (j["probe"], j["probeSteps"]) == ("search", 2)
+        assert (j["probe"], j["probeSteps"], j["tableRows"]) == (
+            "search", 2, 0)
     else:
-        assert (j["probe"], j["probeSteps"]) == ("position", 1)
+        # a row of the table of 4,096 entries a slot
+        assert (j["probe"], j["probeSteps"], j["tableRows"]) == (
+            "position", 1, 32)
     assert j["searchedSlots"] == j["probeSlots"] == 65_536
     assert j["buildGather"] == (
         "matched" if how in ("inner", "left") else "none")
@@ -178,6 +185,83 @@ def test_a_lost_bet_on_the_matches_reruns_once_and_is_remembered(spark, how):
     # another filter over the same tables is another bet
     query(spark, probe, build, how, 0.01).collect_arrow()
     assert spark.last_execution["join"]["joins"][0]["bet"] == "buildFilter"
+
+
+#: entries of the largest table of positions read as rows of 128
+ROW_GATE = joinops._POSITION_ROWS * joinops._LANES
+
+
+def probe_batch(keys, nulls, rows):
+    schema = StructType([StructField("k", LongType(), True)])
+    return ColumnBatch(schema, [DeviceColumn(
+        LongType(), jnp.asarray(keys), jnp.asarray(~nulls))],
+        jnp.int32(rows))
+
+
+def plain_positions(table, lo, keys, nulls, rows):
+    """(row, matched, dup) of each probe slot, from numpy."""
+    at = keys - lo
+    inside = (~nulls & (np.arange(len(keys)) < rows)
+              & (at >= 0) & (at < len(table)))
+    held = table[np.clip(at, 0, len(table) - 1)]
+    matched = inside & (held != -1)
+    row = np.maximum(np.where(held < -1, -2 - held, held), 0)
+    return row, matched, matched & (held < -1)
+
+
+@pytest.mark.parametrize("entries", [1, 127, 128, 129, ROW_GATE,
+                                     ROW_GATE + 1])
+def test_a_table_read_by_rows_gives_what_its_entries_give(entries,
+                                                          monkeypatch):
+    """`probe_positions` reads a table of at most `_POSITION_ROWS` rows
+    of 128 entries a row a slot and keeps one lane, a larger one an
+    entry a slot: both give every probe slot the same (row, matched,
+    dup) — absent (-1) and twice-held (-2 - row) entries, keys below
+    and above the stamped range, null-keyed and dead slots."""
+    rng = np.random.default_rng(entries)
+    lo, slots, rows = 7_000, 20_000, 19_000
+    ids = rng.integers(0, 102_000, entries)
+    kind = rng.random(entries)
+    table = np.where(kind < 0.2, -1, np.where(kind < 0.3, -2 - ids, ids)
+                     ).astype(np.int32)
+    table[0] = -2 - ids[0]  # held twice, whatever the size
+    keys = rng.integers(lo - 40, lo + entries + 40, slots)
+    keys[:4] = [-2 ** 40, 2 ** 40, lo - 1, lo + entries]
+    nulls = rng.random(slots) < 0.05
+    build = joinops.BuildPositions(None, jnp.asarray(table),
+                                   jnp.int64(lo), jnp.int32(0),
+                                   jnp.int32(0))
+    probe = probe_batch(keys, nulls, rows)
+    assert (joinops.table_rows(entries) > 0) == (entries <= ROW_GATE)
+    by_rows = joinops.probe_positions(build, probe, [0])
+    monkeypatch.setattr(joinops, "_POSITION_ROWS", 0)  # entries alone
+    by_entries = joinops.probe_positions(build, probe, [0])
+    for got, one in zip(by_rows, by_entries):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+    # and both are right: a row is what a match reads
+    row, matched, dup = plain_positions(table, lo, keys, nulls, rows)
+    np.testing.assert_array_equal(np.asarray(by_rows[1]), matched)
+    np.testing.assert_array_equal(np.asarray(by_rows[2]), dup)
+    np.testing.assert_array_equal(np.asarray(by_rows[0])[matched],
+                                  row[matched])
+    assert matched.any() and dup.any() and (~matched[:rows]).any()
+
+
+@pytest.mark.parametrize("read", ["rows", "entries"])
+def test_the_join_record_says_how_the_table_was_read(spark, read,
+                                                     monkeypatch):
+    """`tableRows`: the rows of 128 entries a probe by position read its
+    table as (the dense key's 4,096 entries: 32), or 0 where the table
+    has more rows than `_POSITION_ROWS` and each slot read its entry."""
+    if read == "entries":
+        monkeypatch.setattr(joinops, "_POSITION_ROWS", 31)
+    probe, build = tables("dense")
+    got = query(spark, probe, build, "inner").collect_arrow()
+    rec = spark.last_execution
+    assert rows_of(got, "inner") == plain_join(probe, build, "inner")
+    (j,) = rec["join"]["joins"]
+    assert (j["probe"], j["probeSteps"]) == ("position", 1)
+    assert j["tableRows"] == (32 if read == "rows" else 0)
 
 
 def two_dimensions(seed=5):
