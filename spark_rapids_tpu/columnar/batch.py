@@ -213,12 +213,34 @@ class DeviceColumn:
             epoch=kw.get("epoch", self.epoch),
         )
 
-    def gather(self, indices) -> "DeviceColumn":
+    def gather(self, indices, take=None) -> "DeviceColumn":
         """Row gather; indices must be in [0, capacity). Gathered values
         are a subset, so the static vrange bound survives — and for an
         encoded column only the [cap] CODES move (the dictionary is
         shared, which is exactly why join payload gathers over encoded
-        strings are cheap)."""
+        strings are cheap).
+
+        `take(array, indices)`: how the caller reads a 1-D int32 array
+        at the indices (a lookup join reads a small one a row of 128
+        entries a slot, ops/joinops.py `read_entries`). Given one, a
+        plain integer column (dictionary codes too) whose stamped range
+        spans fewer than 2^31 values is read through it as ONE 32-bit
+        array, its value less the range's low end and -1 where it is
+        null, where the gather below reads the data (two 32-bit halves
+        for a 64-bit column) and the validity, each a 4-byte read a
+        slot. Making that array is a pass over the whole column, which
+        a read of about the column's own width repays and a gather of
+        a few rows from a large column does not."""
+        if take is not None and self._reads_as_one_array():
+            lo = self.vrange[0]
+            wide = jnp.int64 if self.data.dtype.itemsize > 4 else jnp.int32
+            coded = jnp.where(self.validity, self.data.astype(wide) - lo,
+                              -1).astype(jnp.int32)
+            got = take(coded, indices)
+            valid = got >= 0
+            data = jnp.where(valid, got.astype(wide) + lo, 0)
+            return self.replace(data=data.astype(self.data.dtype),
+                                validity=valid)
         return DeviceColumn(
             self.dtype,
             jnp.take(self.data, indices, axis=0),
@@ -237,6 +259,17 @@ class DeviceColumn:
             encoding=self.encoding,
             epoch=self.epoch,
         )
+
+    def _reads_as_one_array(self) -> bool:
+        """A flat integer column with a stamped range of fewer than
+        2^31 values: its value less the range's low end, or -1, fits
+        one int32."""
+        vr = self.vrange
+        return (vr is not None and self.data.ndim == 1
+                and jnp.issubdtype(self.data.dtype, jnp.integer)
+                and vr[1] - vr[0] < 2 ** 31 - 1 and self.lengths is None
+                and self.elem_validity is None and self.map_values is None
+                and self.children is None and self.elem_lengths is None)
 
     def _tree_flatten(self):
         leaves = [self.data, self.validity]

@@ -1193,7 +1193,8 @@ class FusedSingleChipExecutor:
                     """A second match of a row of `b` matched at `at`:
                     the table of positions marked it; a search reads
                     the key after the match, at the width the caller
-                    has brought the matches to."""
+                    has brought the matches to, where the index holds
+                    a key twice (joinops.second_match)."""
                     if by_position:
                         return dup
                     work, keys = nd._prepare_keys(b, nd.left_keys)
@@ -1237,7 +1238,8 @@ class FusedSingleChipExecutor:
                     rows, plain_read = joinops.rows_at(
                         bt, jnp.clip(at, 0, bt.capacity - 1))
                     unread.append(plain_read)
-                rcols = [c.gather(rows) for c in bt.batch.columns]
+                rcols = [c.gather(rows, joinops.read_entries)
+                         for c in bt.batch.columns]
                 rcols = [c.replace(validity=c.validity & matched)
                          for c in rcols]
                 # nd.schema carries the planner's nullability (left
@@ -1716,13 +1718,24 @@ class FusedSingleChipExecutor:
 
             def one(b, plan):
                 bets = []
-                for key, bt, slots, jp in zip(join_keys, builds,
-                                              build_slots, plan):
+                for key, nd, bt, slots, jp in zip(join_keys, join_nodes,
+                                                  builds, build_slots, plan):
                     jp["buildSlots"] = slots
                     by_position = isinstance(bt, joinops.BuildPositions)
                     jp["probe"] = "position" if by_position else "search"
-                    jp["probeSteps"] = 1 if by_position \
-                        else joinops.search_reads(slots)
+                    # the arrays a probe compares: several key columns
+                    # are one where they were packed (joinops.KeyPacking)
+                    arrays = 1 if by_position else len(bt.keys)
+                    jp["keyColumns"] = len(nd.right_keys)
+                    jp["keyArrays"] = arrays
+                    jp["packedBits"] = bt.packing.bits if bt.packing else 0
+                    # one array is searched a row of 128 keys a level; a
+                    # tuple of them a key of each a step, a step a bit
+                    # of the slots (joinops._binary_search)
+                    jp["probeSteps"] = (
+                        1 if by_position
+                        else joinops.search_reads(slots) if arrays == 1
+                        else max(1, slots.bit_length()) * arrays)
                     # the one read of a probe by position: a row of the
                     # table where it has few enough, else an entry
                     jp["tableRows"] = joinops.table_rows(

@@ -402,8 +402,9 @@ class _DeviceJoinBase(PhysicalPlan):
         """The build side indexed where it lies, for a lookup join that
         reads its columns at the rows a probe matched, or (semi, anti,
         existence: `reads_columns` false) reads none."""
-        work_r, rk = self._prepare_keys(right, self.right_keys)
-        idx = joinops.build_index(work_r, rk, live)
+        work_r, rk, packing = joinops.packed_key(
+            *self._prepare_keys(right, self.right_keys))
+        idx = joinops.build_index(work_r, rk, live)._replace(packing=packing)
         if not reads_columns:
             return idx._replace(batch=None, perm=None)
         return idx._replace(batch=right)
@@ -411,18 +412,26 @@ class _DeviceJoinBase(PhysicalPlan):
     def _build_positions(self, right: ColumnBatch, live,
                          reads_columns: bool) -> joinops.BuildPositions:
         """The build side as a row-or-absent table over its key's
-        stamped range (`build_key_range` is not None), read by
-        position where `_build_index`'s is searched."""
-        work_r, rk = self._prepare_keys(right, self.right_keys)
+        stamped range, or its keys' packed one (`build_key_range` is
+        not None), read by position where `_build_index`'s is
+        searched."""
+        work_r, rk, packing = joinops.packed_key(
+            *self._prepare_keys(right, self.right_keys))
         pos = joinops.build_positions(work_r, rk, live)
-        return pos._replace(batch=right if reads_columns else None)
+        return pos._replace(batch=right if reads_columns else None,
+                            packing=packing)
 
     def build_key_range(self, right: ColumnBatch):
         """The stamped (lo, hi) of the build side's one plain integer
-        key column, or None."""
+        key column, or (0, entries - 1) of its several ones packed into
+        one key (joinops.key_packing), or None."""
         if not all(isinstance(k, BoundReference) for k in self.right_keys):
             return None
-        return joinops.key_range(right, [k.ordinal for k in self.right_keys])
+        ordinals = [k.ordinal for k in self.right_keys]
+        packing = joinops.key_packing(right, ordinals)
+        if packing is not None:
+            return 0, packing.entries - 1
+        return joinops.key_range(right, ordinals)
 
     def build_is_filtered(self) -> bool:
         """Whether the build side sits under a filter: the join then

@@ -23,19 +23,111 @@ data-dependent output sizes (SURVEY.md section 7 hard part #1/#2).
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from spark_rapids_tpu.columnar.batch import ColumnBatch
+from spark_rapids_tpu.columnar.batch import ColumnBatch, DeviceColumn
 from spark_rapids_tpu.ops.common import (
     equality_keys,
     normalize_floating,
     sort_permutation,
     sorted_with_permutation,
 )
+from spark_rapids_tpu.sqltypes import StructField, StructType
+from spark_rapids_tpu.sqltypes.datatypes import integer, long
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class KeyPacking:
+    """Several plain integer key columns read as ONE key: each value
+    less its column's stamped `lo`, laid side by side in mixed radix
+    (the first column the most significant), so that the key is one
+    array of `bits` bits where a tuple of them would be searched a key
+    of each a step. A value outside ANY one column's range is no
+    match: it is refused before it is packed, so two keys never alias.
+    Static: the chain program that probes a build side packed so is
+    traced for these ranges."""
+
+    ranges: Tuple[Tuple[int, int], ...]
+
+    @property
+    def entries(self) -> int:
+        """Distinct packed keys: the product of the columns' spans."""
+        return math.prod(hi - lo + 1 for lo, hi in self.ranges)
+
+    @property
+    def bits(self) -> int:
+        return (self.entries - 1).bit_length()
+
+    def pack(self, vals: List[jnp.ndarray]
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """The 64-bit orderable values of each column -> (the packed
+        key, int32 where it fits; whether every value lay in its
+        column's range)."""
+        dtype = jnp.int32 if self.bits <= 31 else jnp.int64
+        packed = jnp.zeros(vals[0].shape, jnp.int64)
+        inside = jnp.ones(vals[0].shape, bool)
+        for (lo, hi), v in zip(self.ranges, vals):
+            ok = (v >= lo) & (v <= hi)
+            packed = packed * (hi - lo + 1) + jnp.where(ok, v - lo, 0)
+            inside = inside & ok
+        return packed.astype(dtype), inside
+
+
+def key_packing(batch: ColumnBatch, key_idxs: Sequence[int]
+                ) -> Optional[KeyPacking]:
+    """How a build side's SEVERAL plain integer key columns, each with
+    a stamped range, pack into one key of at most 63 bits; None: one
+    key, or keys that keep the tuple search."""
+    if len(key_idxs) < 2:
+        return None
+    ranges = [key_range(batch, [i]) for i in key_idxs]
+    if None in ranges:
+        return None
+    packing = KeyPacking(tuple((int(lo), int(hi)) for lo, hi in ranges))
+    fits = packing.bits <= 63 and all(hi - lo < 2 ** 63 - 1
+                                      for lo, hi in packing.ranges)
+    return packing if fits else None
+
+
+def packed_key(batch: ColumnBatch, key_idxs: Sequence[int]
+               ) -> Tuple[ColumnBatch, List[int], Optional[KeyPacking]]:
+    """A build side's keys as the index or the table of positions is
+    made of them: where they pack (`key_packing`), the batch with the
+    packed key appended, stamped with its range (0, entries - 1), and
+    that one key's ordinal; else all as given, and None."""
+    packing = key_packing(batch, key_idxs)
+    if packing is None:
+        return batch, list(key_idxs), None
+    vals, valid = _join_keys(batch, key_idxs, jnp.ones(
+        (batch.capacity,), bool))
+    packed, _ = packing.pack(vals)
+    col = DeviceColumn(integer if packed.dtype == jnp.int32 else long,
+                       packed, valid, vrange=(0, packing.entries - 1))
+    schema = StructType(list(batch.schema.fields)
+                        + [StructField("__packed_key", col.dtype, True)])
+    return (ColumnBatch(schema, list(batch.columns) + [col],
+                        batch.num_rows),
+            [len(batch.columns)], packing)
+
+
+def _probe_keys(build, probe: ColumnBatch, key_idxs: Sequence[int],
+                live: jnp.ndarray) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
+    """`_join_keys` of the probe side as the build side was keyed: one
+    packed key where its keys were packed, a probe row with a value
+    outside a column's range then counted with the null-keyed ones."""
+    vals, all_valid = _join_keys(probe, key_idxs, live)
+    packing = getattr(build, "packing", None)
+    if packing is None:
+        return vals, all_valid
+    packed, inside = packing.pack(vals)
+    return [packed], all_valid & inside
 
 
 class BuildTable(NamedTuple):
@@ -59,6 +151,7 @@ class BuildIndex(NamedTuple):
     #                                row of `batch`; None with the batch
     valid_bound: jnp.ndarray       # scalar int32: rows with non-null keys
     num_rows: jnp.ndarray          # scalar int32: live rows
+    packing: Optional[KeyPacking] = None  # several keys read as `keys`
 
     @property
     def capacity(self) -> int:
@@ -67,10 +160,11 @@ class BuildIndex(NamedTuple):
 
 class BuildPositions(NamedTuple):
     """Build side of a lookup join whose ONE integer key's stamped
-    range is small enough for a table of it: `table[key - lo]` is the
-    build row that holds `key`, so a probe is one read where the
-    sorted index takes a search of log2(slots) steps. The batch stays
-    as it lies, as `BuildIndex`'s does."""
+    range (or its keys' packed one, `KeyPacking`) is small enough for
+    a table of it: `table[key - lo]` is the build row that holds
+    `key`, so a probe is one read where the sorted index takes a
+    search of log2(slots) steps. The batch stays as it lies, as
+    `BuildIndex`'s does."""
 
     batch: Optional[ColumnBatch]   # UNSORTED; None: the join reads no column
     table: jnp.ndarray             # int32 [hi - lo + 1]: the row holding
@@ -79,6 +173,7 @@ class BuildPositions(NamedTuple):
     lo: jnp.ndarray                # scalar int64: the stamped range's low end
     valid_bound: jnp.ndarray       # scalar int32: rows with non-null keys
     num_rows: jnp.ndarray          # scalar int32: live rows
+    packing: Optional[KeyPacking] = None  # several keys read as one
 
     @property
     def capacity(self) -> int:
@@ -165,16 +260,13 @@ def probe_positions(build: BuildPositions, probe: ColumnBatch,
     `rows_at` give them together: one read of the table, a row of 128
     entries whose lane the slot picks where the table has no more than
     `_POSITION_ROWS` of them (`table_rows`), else the entry alone."""
-    live = probe.live_mask()
-    vals, all_valid = _join_keys(probe, key_idxs, live)
+    vals, all_valid = _probe_keys(build, probe, key_idxs,
+                                  probe.live_mask())
     size = build.table.shape[0]
     at = vals[0] - build.lo
     inside = all_valid & (at >= 0) & (at < size)
-    at = jnp.clip(at, 0, size - 1).astype(jnp.int32)
-    if table_rows(size):
-        held = _entries_by_row(_as_rows(build.table, -1), at)
-    else:
-        held = jnp.take(build.table, at)
+    held = read_entries(build.table,
+                        jnp.clip(at, 0, size - 1).astype(jnp.int32))
     matched = inside & (held != -1)
     row = jnp.where(held < -1, -2 - held, held)
     return jnp.maximum(row, 0), matched, matched & (held < -1)
@@ -197,6 +289,16 @@ def _entries_by_row(rows: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
     blocks = -(-n // _PICK_BLOCK)
     at = jnp.pad(at, (0, blocks * _PICK_BLOCK - n))
     return lax.map(pick, at.reshape(blocks, _PICK_BLOCK)).reshape(-1)[:n]
+
+
+def read_entries(array: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
+    """`array[at]` of a 1-D int32 array (a table of positions, or a
+    build column read as one array: `DeviceColumn.gather`'s `take`): a
+    row of 128 entries a slot and its lane where the array has no more
+    than `_POSITION_ROWS` of them (`table_rows`), else the entry."""
+    if table_rows(array.shape[0]):
+        return _entries_by_row(_as_rows(array, -1), at)
+    return jnp.take(array, at)
 
 
 def _join_keys(batch: ColumnBatch, key_idxs: Sequence[int],
@@ -571,7 +673,7 @@ def probe_matched(build: Union[BuildTable, BuildIndex],
     (`_count_below`), for no read of its own; a tuple of key arrays
     reads the keys at `lo`. `with_windowed`: -> also how many of the
     search's blocks took a window of the keys (`search_blocks`)."""
-    vals, all_valid = _join_keys(probe, key_idxs, probe.live_mask())
+    vals, all_valid = _probe_keys(build, probe, key_idxs, probe.live_mask())
     if len(build.keys) == 1:
         lo, equal, *took = _count_below(
             build.keys[0], vals[0], build.valid_bound, upper=False,
@@ -596,11 +698,24 @@ def second_match(build: Union[BuildTable, BuildIndex], probe: ColumnBatch,
                  matched: jnp.ndarray) -> jnp.ndarray:
     """Per probe row that `probe_matched` matched at `lo`, whether the
     build key at `lo + 1` equals it as well: the build keys are not
-    unique for this row. One gather, which a caller that has brought
-    its matches to the front of a small batch pays there and not at
-    the probe's full width."""
-    vals, _ = _join_keys(probe, key_idxs, probe.live_mask())
-    return matched & _equal_at(build, lo + 1, vals)
+    unique for this row. That gather (7 to 25 ns a slot at a probe's
+    full width: 1.6 s a query at TPC-H Q5's 62.9M slots) runs only
+    where the index holds some key twice (`holds_a_key_twice`); where
+    it holds none, no row has a second match."""
+    vals, _ = _probe_keys(build, probe, key_idxs, probe.live_mask())
+    return lax.cond(holds_a_key_twice(build),
+                    lambda: matched & _equal_at(build, lo + 1, vals),
+                    lambda: jnp.zeros_like(matched))
+
+
+def holds_a_key_twice(build: Union[BuildTable, BuildIndex]) -> jnp.ndarray:
+    """Whether two of the index's rows with valid keys hold one key: a
+    scalar, one pass over the sorted keys."""
+    n = build.keys[0].shape[0]
+    same = jnp.arange(1, n) < build.valid_bound
+    for k in build.keys:
+        same = same & (k[1:] == k[:-1])
+    return jnp.any(same)
 
 
 def probe_unique(build: Union[BuildTable, BuildIndex], probe: ColumnBatch,

@@ -513,15 +513,18 @@ class TpuOverrides:
         chosen_by = "written"
         if join_type == "inner" and left_keys:
             rows = [L.estimate_rows(c) for c in node.children]
-            # a count that is only a bound (a filter below) may hide a
-            # far smaller side: where the written build side alone has
-            # one, the order of writing stands; and it stands against
-            # anything short of twice the rows
-            if None not in rows and (
-                    L.rows_are_a_bound(node.children[0])
-                    or not L.rows_are_a_bound(node.children[1])):
-                chosen_by = "rows"
+            # the order of writing stands against anything short of
+            # twice the rows. A count that is only a bound (a filter
+            # below) may hide a far smaller side: where the written
+            # build side alone has one, it stands unless the other
+            # side's EXACT count is at most half that bound (TPC-H Q5:
+            # 1.5M customers against 15M orders under a date filter,
+            # whose non-unique `o_custkey` would expand the join)
+            if None not in rows:
                 swapped = 2 * rows[0] <= rows[1]
+                if (swapped or L.rows_are_a_bound(node.children[0])
+                        or not L.rows_are_a_bound(node.children[1])):
+                    chosen_by = "rows"
         if swapped:
             left, right = right, left
             left_keys, right_keys = right_keys, left_keys

@@ -43,10 +43,14 @@ def _write_parts(d, table, nfiles):
 
 
 def _probe_build_tables():
+    """A probe side of more than half the build side's rows: an exact
+    count at most half a filtered side's bound is built statically
+    (plan/overrides.py `_convert_join`), and nothing is left to
+    promote."""
     rng = np.random.default_rng(3)
     probe_t = pa.table({
-        "k": pa.array(rng.integers(0, 500, 20_000), type=pa.int64()),
-        "v": pa.array(rng.random(20_000))})
+        "k": pa.array(rng.integers(0, 500, 30_000), type=pa.int64()),
+        "v": pa.array(rng.random(30_000))})
     build_t = pa.table({
         "k": pa.array(np.arange(40_000) % 500, type=pa.int64()),
         "w": pa.array(np.arange(40_000), type=pa.int64())})

@@ -612,14 +612,17 @@ def test_q3s_own_chain_at_sf10_sorts_one_operand_and_probes_by_position(
     (sort,) = [ln for ln in c.as_text().splitlines() if " sort(" in ln]
     assert "u32[1024]" in sort and "[57344]" not in sort
     # both bets' row ids read a row of the mask's prefix sum a level
-    # (the part's 3,670,016 slots, then the 57,344 that were left), and
-    # both probes read a row of their table of positions a slot
+    # (the part's 3,670,016 slots, then the 57,344 that were left), both
+    # probes read a row of their table of positions a slot, and so does
+    # each of the three build columns the chain reads (`i_brand_id`,
+    # `i_brand`'s codes, `d_year`: DeviceColumn.gather through
+    # joinops.read_entries)
     assert [joinops.table_rows(t.table.shape[0]) for t in tables] == [
         1_024, 32_768]
     assert len([ln for ln in c.as_text().splitlines()
                 if " gather(" in ln and "slice_sizes={1,128}" in ln]) == (
         joinops.search_reads(STAR_PART) + joinops.search_reads(57_344)
-        + len(tables))
+        + len(tables) + 3)
     assert seconds < 30, seconds         # 3.0-3.9 s when written
     assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
 
@@ -792,7 +795,9 @@ def test_tpch_q3s_own_chain_at_sf10_searches_a_row_a_level_at_full_width(
     # own capacity, outside the loop
     assert len(reads("", 122_880)) == joinops.search_reads(H3_PART) == 3
     assert len(rows) == 2 * joinops.search_reads(H3_BUILD) + 2 + 3
-    assert sum(" conditional(" in ln for ln in lines) == 1
+    # the search's two branches, and the key after a match read only
+    # where the index holds a key twice (joinops.second_match)
+    assert sum(" conditional(" in ln for ln in lines) == 2
     # whether a probe matched is read off the row the search fetched
     # last, and the key after a match is read at the survivors' width:
     # no gather of one element a full-width slot (27 ns a slot, 1.7 s a
@@ -803,6 +808,131 @@ def test_tpch_q3s_own_chain_at_sf10_searches_a_row_a_level_at_full_width(
     (sort,) = [ln for ln in lines if " sort(" in ln]
     assert "[122880]" in sort
     assert seconds < 120, seconds        # 33 s when written (sandbox)
+    assert c.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
+# ------------- TPC-H Q5's own chain (lineitem into four joins, one packed)
+
+H5_TABLE = 131_072 * 32   # SF10's packed (s_suppkey, s_nationkey) range
+H5_SUPPLIERS = 100_000    # one PLAIN file: capacity == rows
+
+
+@pytest.fixture(scope="module")
+def tpch_q5_chain():
+    """`tpch_q5_join_resident`'s big chain program — `lineitem`'s search
+    of the customers' orders at full width, the supplier join on two
+    keys packed into one and probed by position, `nation`, `region`'s
+    bet, the partial aggregate — as a hot query of a 1,000,000-row
+    rehearsal traces it on this CPU: (the function the engine hands to
+    jit, the inputs of one call)."""
+    from benchmark import run
+    from spark_rapids_tpu.ops import joinops
+    from spark_rapids_tpu.runtime import jit_cache
+
+    seen = []
+    real = jit_cache.cached_jit
+
+    def spy(key, build, **kw):
+        jitted = real(key, build, **kw)
+
+        def call(*inputs):
+            if key[1] == "chain" and any(
+                    isinstance(i, joinops.BuildPositions) and i.packing
+                    for i in inputs):
+                seen.append((build(), inputs))
+            return jitted(*inputs)
+        return call
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jit_cache, "cached_jit", spy)
+    mp.setattr(run, "session_conf",
+               lambda config: dict(config["session_conf"]))
+    try:
+        res = run.run_cell("tpch_q5_join_resident", 2_147_483_777, 0.1,
+                           False, rows=H3_ROWS, any_platform=True)
+    finally:
+        mp.undo()
+    assert res["correct"] and seen
+    return seen[-1]
+
+
+def test_tpch_q5s_own_chain_at_sf10_reads_two_columns_an_element_a_slot(
+        tpch_q5_chain, one_chip, no_persistent_cache):
+    """The program Q5 spends its time in, lowered again at SF10's
+    widths: of everything its four joins read at a part's full width
+    (7,864,320 slots), only the index's row ids and the ONE 32-bit
+    array `c_nationkey` is read as are gathers of an element a slot
+    (from 15.7M-slot arrays: no table of them is small enough for
+    rows). The two supplier keys are packed into one and read by
+    position, a row of the 4,194,304-entry table a slot, and every
+    column of `supplier` and `nation` a later join reads is a row of
+    128 entries a slot: the first tree read 13 such element gathers, 7
+    of them 64-bit halves and validity, and the key after each match,
+    9,293 ms a query (PERF.md section 6); that key is now read only
+    where the index holds a key twice."""
+    from spark_rapids_tpu.exec.fused import survivor_capacity
+    from spark_rapids_tpu.ops import joinops
+
+    fn, (probe, index, suppliers, nation, region) = tpch_q5_chain
+    plan = fn.__kwdefaults__["_plan"]
+    assert [(jp["probe"], jp["bet"]) for jp in plan] == [
+        ("search", ""), ("position", ""), ("position", ""),
+        ("position", "buildFilter")]
+    assert suppliers.packing.ranges[1] == (0, 31)
+    small, built = probe.capacity, index.capacity
+    dims = {small: H3_PART, built: H3_BUILD,
+            suppliers.batch.capacity: H5_SUPPLIERS,
+            suppliers.table.shape[0]: H5_TABLE,
+            survivor_capacity(small): survivor_capacity(H3_PART)}
+    assert len(dims) == 5
+    keys = {suppliers.packing.ranges[0]: (0, 131_071),
+            (0, 1_048_575): (0, 67_108_863), (0, 32_767): (0, 2_097_151)}
+    at_sf10 = type(fn)(fn.__code__, fn.__globals__, fn.__name__,
+                       fn.__defaults__, fn.__closure__)
+    at_sf10.__kwdefaults__ = dict(fn.__kwdefaults__, _plan=[dict(
+        {k: dims.get(v, v) if type(v) is int else v for k, v in jp.items()},
+        searchBlocks=joinops.search_blocks(H3_PART)
+        if jp["probe"] == "search" else 0) for jp in plan])
+
+    def widened(tree, dims):
+        def column(c):
+            if not isinstance(c, DeviceColumn):
+                return _sds(tuple(dims.get(d, d) for d in c.shape), c.dtype,
+                            one_chip)
+            leaves, aux = c._tree_flatten()
+            vr = keys.get(aux[4], aux[4])
+            new = [widened(leaf, dims) for leaf in leaves]
+            if vr == (0, 131_071) and new[0].dtype == jnp.int16:
+                # SF10's supplier keys no longer fit the 16-bit upload
+                new[0] = _sds(new[0].shape, jnp.int32, one_chip)
+            return DeviceColumn._tree_unflatten(
+                aux[:4] + (vr,) + aux[5:], new)
+        return jax.tree_util.tree_map(
+            column, tree, is_leaf=lambda x: isinstance(x, DeviceColumn))
+
+    inputs = [widened(probe, {small: H3_PART}),
+              widened(index, {built: H3_BUILD}),
+              widened(suppliers, dims)._replace(
+                  packing=joinops.KeyPacking(((0, 131_071), (0, 31)))),
+              widened(nation, {}), widened(region, {})]
+    assert inputs[2].packing.entries == H5_TABLE
+    c, _, seconds = _timed_compile(at_sf10, *inputs)
+    lines = c.as_text().splitlines()
+    elements = [ln for ln in lines if " gather(" in ln
+                and f"[{H3_PART}]" in ln.split(" gather(")[0]]
+    # the third is the key after each match, in a branch taken only
+    # where the index holds a key twice (joinops.second_match): the
+    # orders of SF10 hold none. The other conditional is the search's
+    assert len(elements) == 3, elements
+    assert sum(" conditional(" in ln for ln in lines) == 2
+    rows = [ln for ln in lines if " gather(" in ln
+            and "slice_sizes={1,128}" in ln]
+    tables = {ln.split(" gather(")[0].split("=")[1].split("{")[0].strip()
+              for ln in rows}
+    # rows of the suppliers' table and of their columns, 8,192 slots a
+    # block (joinops._PICK_BLOCK)
+    assert "s32[8192,128]" in tables
+    assert seconds < 120, seconds        # 8-10 s when written, on a CPU host
     assert c.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 

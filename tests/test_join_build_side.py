@@ -9,6 +9,7 @@ more groups than `fusedExec.groupCapacity` in one run. Every answer
 against the CPU oracle session (exec/cpu_eval.py)."""
 
 import collections
+import os
 
 import numpy as np
 import pyarrow as pa
@@ -440,3 +441,57 @@ def test_plain_files_of_nearly_equal_length_share_one_capacity(
     total = df.agg(F.sum("a").alias("s"), F.count("b").alias("n")) \
         .collect_arrow()
     assert total.column("n").to_pylist() == [2_400_001]
+
+
+# --- the benchmark's cells, at SF10's row counts ---
+
+#: (buildSide, chosenBy) of each join of each query, outermost first, as
+#: the planner chooses them at the configurations' own row counts. Every
+#: cell's but Q5's first join (`customer` x `orders`) is as it was before
+#: an exact side of at most half a filtered side's bound was built.
+CELL_SIDES = {
+    "tpch_q12_join_resident": {"tpch_q12": [("right", "rows")]},
+    "tpcds_star_resident": {
+        q: [("right", "rows"), ("right", "written")]
+        for q in ("tpcds_spec_q3", "tpcds_spec_q42", "tpcds_spec_q52",
+                  "tpcds_spec_q55")},
+    "tpch_q3_join_resident": {"tpch_q3": [("left", "rows"),
+                                          ("left", "rows")]},
+    "tpch_q5_join_resident": {"tpch_q5": [
+        ("right", "rows"), ("right", "rows"), ("right", "rows"),
+        ("left", "rows"), ("left", "rows")]},
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SIDES))
+def test_every_cells_build_sides_at_its_own_row_counts(cell, tmp_path,
+                                                       monkeypatch):
+    """Each parquet file counts the rows its table has at SF10 (the
+    configuration's `<table>_rows` over its files): the planner decides
+    on those, as it does on the chip. TPC-H Q5's first join builds the
+    1.5M customers against 15M orders under a date filter, by rows."""
+    from benchmark import run
+
+    config = run.load_cell(cell)["config"]
+
+    def sf10_rows(path, mtime_ns, size):
+        where = os.path.dirname(path)
+        table = os.path.basename(where)
+        return config["scale"][table + "_rows"] // len(os.listdir(where))
+
+    monkeypatch.setattr(L, "_parquet_rows", sf10_rows)
+    generator = run.load_module("datagen", config["generator"])
+    dirs = generator.generate(config, 2_147_483_777, str(tmp_path),
+                              rows=20_000)
+    session = TpuSparkSession(dict(config["session_conf"]))
+    try:
+        tables = {t: session.read.parquet(d) for t, d in dirs.items()}
+        got = {}
+        for q in CELL_SIDES[cell]:
+            phys, _ = run.load_module("queries", q).build(
+                session, tables)._physical()
+            got[q] = [(j.build_side, j.chosen_by)
+                      for j in find(phys, J._DeviceJoinBase)]
+    finally:
+        session.stop()
+    assert got == CELL_SIDES[cell]
