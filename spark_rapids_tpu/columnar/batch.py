@@ -231,16 +231,8 @@ class DeviceColumn:
         slot. Making that array is a pass over the whole column, which
         a read of about the column's own width repays and a gather of
         a few rows from a large column does not."""
-        if take is not None and self._reads_as_one_array():
-            lo = self.vrange[0]
-            wide = jnp.int64 if self.data.dtype.itemsize > 4 else jnp.int32
-            coded = jnp.where(self.validity, self.data.astype(wide) - lo,
-                              -1).astype(jnp.int32)
-            got = take(coded, indices)
-            valid = got >= 0
-            data = jnp.where(valid, got.astype(wide) + lo, 0)
-            return self.replace(data=data.astype(self.data.dtype),
-                                validity=valid)
+        if take is not None and self.reads_as_one_array():
+            return self.decoded(take(self.coded(), indices))
         return DeviceColumn(
             self.dtype,
             jnp.take(self.data, indices, axis=0),
@@ -260,16 +252,33 @@ class DeviceColumn:
             epoch=self.epoch,
         )
 
-    def _reads_as_one_array(self) -> bool:
+    def reads_as_one_array(self) -> bool:
         """A flat integer column with a stamped range of fewer than
         2^31 values: its value less the range's low end, or -1, fits
-        one int32."""
+        one int32 (`coded`)."""
         vr = self.vrange
         return (vr is not None and self.data.ndim == 1
                 and jnp.issubdtype(self.data.dtype, jnp.integer)
                 and vr[1] - vr[0] < 2 ** 31 - 1 and self.lengths is None
                 and self.elem_validity is None and self.map_values is None
                 and self.children is None and self.elem_lengths is None)
+
+    def _wide(self):
+        return jnp.int64 if self.data.dtype.itemsize > 4 else jnp.int32
+
+    def coded(self) -> jnp.ndarray:
+        """The column as ONE int32 array (`reads_as_one_array`): each
+        value less the stamped range's low end, -1 where it is null."""
+        return jnp.where(self.validity,
+                         self.data.astype(self._wide()) - self.vrange[0],
+                         -1).astype(jnp.int32)
+
+    def decoded(self, got: jnp.ndarray) -> "DeviceColumn":
+        """The column whose `coded` array read `got` at some rows."""
+        valid = got >= 0
+        data = jnp.where(valid, got.astype(self._wide()) + self.vrange[0], 0)
+        return self.replace(data=data.astype(self.data.dtype),
+                            validity=valid)
 
     def _tree_flatten(self):
         leaves = [self.data, self.validity]

@@ -45,7 +45,7 @@ import functools
 import hashlib
 import os
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -406,8 +406,34 @@ _NO_BUILD_COLUMN = ("left_semi", "left_anti", "existence")
 def build_gather(join_type: str) -> str:
     """What a lookup join moves of its build side, for the join's
     record and the program keys: "matched", the columns read at the
-    rows a probe matched (`perm[lo]`), or "none"."""
+    rows a probe matched (`perm[lo]`, or `lo` in `index_order`), or
+    "none"."""
     return "none" if join_type in _NO_BUILD_COLUMN else "matched"
+
+
+def index_order(parts: List[ColumnBatch], columns_read,
+                read_slots: int) -> Tuple[int, ...]:
+    """The build columns a lookup join over a sorted index reads
+    (`columns_read`, None: all), where its index is to carry them in
+    its own order (`joinops.BuildIndex.coded`), else (). So it is
+    where the join reads them at more slots than its build side
+    `parts` hold (`read_slots`: its output, all parts) and each reads
+    as one 32-bit array, a plain column of one stamped range in every
+    part (as `concat_in_place` lays them end to end, a dictionary may
+    not survive): they ride the index's sort in place of the row ids,
+    one elementwise pass over the build side, and a probe reads each
+    at `lo` where it would read `perm[lo]` and then the row (TPC-H
+    Q5's `lineitem`: 62.9M slots from 15.7M; the bets of Q3 and Q12
+    read 983,040)."""
+    if not parts:
+        return ()
+    read = tuple(range(len(parts[0].columns)) if columns_read is None
+                 else sorted(columns_read))
+    slots = sum(p.capacity for p in parts)
+    fits = all(c.reads_as_one_array() and c.encoding is None
+               and c.vrange == parts[0].columns[i].vrange
+               for i in read for c in (p.columns[i] for p in parts))
+    return read if read and fits and read_slots > slots else ()
 
 
 @functools.lru_cache(maxsize=4096)
@@ -1164,7 +1190,9 @@ class FusedSingleChipExecutor:
                 build side as the buildprep program indexed it, ONCE
                 per join and not once per probe partition: the batch
                 lies as it lay and is read at `perm[lo]`, or at the row
-                its table of positions names (joinops.BuildPositions).
+                its table of positions names (joinops.BuildPositions),
+                or the columns the chain reads were sorted with the
+                keys and are read at `lo` (`coded`: index order).
                 `bet_to`: the join's build side sits under a filter,
                 so its MATCHES are brought to the front of a batch of
                 that capacity before any build column is read
@@ -1233,13 +1261,22 @@ class FusedSingleChipExecutor:
                     uniq = uniq | jnp.any(
                         second(b, at, matched) & visible(b, mask))
                 # inner / left: unique-build single-match gather
-                rows = at
-                if not by_position:
-                    rows, plain_read = joinops.rows_at(
-                        bt, jnp.clip(at, 0, bt.capacity - 1))
+                if by_position:
+                    rcols = [c.gather(at, joinops.read_entries)
+                             for c in bt.batch.columns]
+                else:
+                    lo = jnp.clip(at, 0, bt.capacity - 1)
+                    if bt.coded is None:
+                        rows, plain_read = joinops.rows_at(bt, lo)
+                        rcols = [c.gather(rows, joinops.read_entries)
+                                 for c in bt.batch.columns]
+                    else:
+                        # in index order: the columns read at `lo` itself
+                        got, plain_read = joinops.coded_at(bt, lo)
+                        rcols = [nothing_read(c, lo) if g is None
+                                 else c.decoded(g)
+                                 for c, g in zip(bt.batch.columns, got)]
                     unread.append(plain_read)
-                rcols = [c.gather(rows, joinops.read_entries)
-                         for c in bt.batch.columns]
                 rcols = [c.replace(validity=c.validity & matched)
                          for c in rcols]
                 # nd.schema carries the planner's nullability (left
@@ -1247,6 +1284,12 @@ class FusedSingleChipExecutor:
                 b = ColumnBatch(nd.schema, list(b.columns) + rcols,
                                 b.num_rows)
                 return b, mask, uniq, over
+
+            def nothing_read(c, rows):
+                """A build column the chain does not read, of a build
+                side in index order, which carries none of it: null."""
+                col = c.gather(jnp.zeros_like(rows))
+                return col.replace(validity=jnp.zeros_like(col.validity))
 
             def survivors(b, mask, cap):
                 """The rows the pending mask lets through, at the front
@@ -1703,7 +1746,9 @@ class FusedSingleChipExecutor:
                 build_table(n, sum(plan[i]["searchedSlots"] or 0
                                    for plan in plans),
                             sum(b.capacity for b in base),
-                            build_columns_read(n))
+                            build_columns_read(n),
+                            sum(plan[i]["outputCapacity"] or 0
+                                for plan in plans))
                 for i, n in enumerate(join_nodes)]
             builds = [bt for bt, _ in built]
             build_slots = [slots for _, slots in built]
@@ -1723,6 +1768,8 @@ class FusedSingleChipExecutor:
                     jp["buildSlots"] = slots
                     by_position = isinstance(bt, joinops.BuildPositions)
                     jp["probe"] = "position" if by_position else "search"
+                    jp["buildOrder"] = "index" if not by_position \
+                        and bt.coded is not None else "asLies"
                     # the arrays a probe compares: several key columns
                     # are one where they were packed (joinops.KeyPacking)
                     arrays = 1 if by_position else len(bt.keys)
@@ -1773,6 +1820,9 @@ class FusedSingleChipExecutor:
                 if plan:
                     marked += (("buildGather",
                                 tuple(jp["buildGather"] for jp in plan)),)
+                if any(jp["buildOrder"] == "index" for jp in plan):
+                    marked += (("buildOrder",
+                                tuple(jp["buildOrder"] for jp in plan)),)
                 if masked is not None:
                     marked += (("masked", tuple(sorted(masked))),)
                 return run_program("chain", marked, stage_fn,
@@ -1786,7 +1836,8 @@ class FusedSingleChipExecutor:
             return [one(b, plan) for b, plan in zip(base, plans)]
 
         def build_table(jn: PhysicalPlan, probed_slots: int,
-                        chain_slots: int, columns_read=None):
+                        chain_slots: int, columns_read=None,
+                        read_slots: int = 0):
             """-> (the build side of one lookup join, indexed where it
             lies, its slots) — ONE buildprep program per join per run,
             shared by every per-partition chain program as an extra
@@ -1806,7 +1857,9 @@ class FusedSingleChipExecutor:
             its parts over uncompacted, each with its mask, and
             computes only the columns in `columns_read` (None: all),
             its keys and what its filters read (`chain_traced`,
-            `masked`)."""
+            `masked`). A sorted index whose columns the chain reads at
+            more slots than it holds (`read_slots`) may be built in
+            index order (`index_order`)."""
             # filters right above the build side's source are taken
             # into this program as its mask of live rows: the index
             # sends the rows they drop last, or leaves them out of its
@@ -1860,6 +1913,13 @@ class FusedSingleChipExecutor:
                 by_position = (
                     entries < min(probed_slots * steps, chain_slots)
                     and (chunks == 1 or chunks * slots <= probed_slots))
+            in_index_order = () if by_position or gather != "matched" \
+                else index_order(parts, columns_read, read_slots)
+            # the program cache keeps `bp_fn`, so it closes over no
+            # device array: over `parts`, it kept the first run's build
+            # side alive for the session (0.68 GB more HBM at TPC-H Q5,
+            # whose first query builds two layouts)
+            n_parts, has_masks = len(parts), bool(masks)
 
             def bp_fn(*ps):
                 from spark_rapids_tpu.expr import EvalContext
@@ -1868,20 +1928,23 @@ class FusedSingleChipExecutor:
                 # sort sends every dead row last anyway, and its table
                 # of positions takes no dead row
                 cb, live = concat_in_place(
-                    concat_inputs(list(ps[:len(parts)])))
-                if masks:
-                    live = live & jnp.concatenate(ps[len(parts):])
+                    concat_inputs(list(ps[:n_parts])))
+                if has_masks:
+                    live = live & jnp.concatenate(ps[n_parts:])
                 for f in filters:
                     pred = f.condition.eval(EvalContext(cb))
                     live = live & pred.data & pred.validity
-                index = (jn._build_positions if by_position
-                         else jn._build_index)
-                return (index(cb, live, gather == "matched"),
-                        jnp.zeros((), bool))
+                if by_position:
+                    index = jn._build_positions(cb, live, gather == "matched")
+                else:
+                    index = jn._build_index(cb, live, gather == "matched",
+                                            in_index_order)
+                return index, jnp.zeros((), bool)
 
             # in the key, as in the chain's: no cache may hand a chain
             # that reads `perm[lo]` the sorted batch this program made
-            # before it made an index, nor a table of positions
+            # before it made an index, nor a table of positions, nor
+            # an index in the other order
             marked = plan_key_memo(jn)[:2]
             if filters:
                 marked += (("buildFilter", tuple(
@@ -1889,6 +1952,10 @@ class FusedSingleChipExecutor:
             if by_position:
                 marked += (("probe", "position"),)
             marked += (("buildGather", gather),)
+            if in_index_order:
+                # and which columns ride the sort: the chain above, not
+                # this join's plan, chose them
+                marked += (("buildOrder", "index", in_index_order),)
             if masks:
                 marked += (("masked",),)
             return run_program("buildprep", marked, bp_fn,

@@ -397,17 +397,26 @@ class _DeviceJoinBase(PhysicalPlan):
                 bt.keys, bt.valid_bound)
         return bt
 
-    def _build_index(self, right: ColumnBatch, live,
-                     reads_columns: bool) -> joinops.BuildIndex:
+    def _build_index(self, right: ColumnBatch, live, reads_columns: bool,
+                     in_index_order=()) -> joinops.BuildIndex:
         """The build side indexed where it lies, for a lookup join that
         reads its columns at the rows a probe matched, or (semi, anti,
-        existence: `reads_columns` false) reads none."""
+        existence: `reads_columns` false) reads none. `in_index_order`:
+        the ordinals of the columns the join reads, each of which
+        reads as one array, where they are to be sorted with the keys
+        (`joinops.BuildIndex.coded`)."""
         work_r, rk, packing = joinops.packed_key(
             *self._prepare_keys(right, self.right_keys))
-        idx = joinops.build_index(work_r, rk, live)._replace(packing=packing)
+        carried = [right.columns[i].coded() for i in in_index_order]
+        idx = joinops.build_index(work_r, rk, live, carried)._replace(
+            packing=packing, batch=right)
         if not reads_columns:
             return idx._replace(batch=None, perm=None)
-        return idx._replace(batch=right)
+        if not in_index_order:
+            return idx
+        moved = dict(zip(in_index_order, idx.coded))
+        return idx._replace(coded=tuple(
+            moved.get(i) for i in range(len(right.columns))))
 
     def _build_positions(self, right: ColumnBatch, live,
                          reads_columns: bool) -> joinops.BuildPositions:

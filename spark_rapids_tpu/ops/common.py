@@ -180,11 +180,23 @@ def sorted_with_permutation(key_arrays: List[jnp.ndarray], capacity: int
     permutation). The sorted key is the sort's own output: a caller
     of `sort_permutation` that wants it gathers it a second time.
     Several keys never go to one `lax.sort`: `sort_permutation`."""
+    keys, (perm,) = sorted_carrying(
+        key_arrays, [jnp.arange(capacity, dtype=jnp.int32)], capacity)
+    return keys, perm
+
+
+def sorted_carrying(key_arrays: List[jnp.ndarray],
+                    carried: List[jnp.ndarray], capacity: int
+                    ) -> Tuple[List[jnp.ndarray], List[jnp.ndarray]]:
+    """Stable sort on ONE key array that takes `carried` (arrays of its
+    length) into its order as the sort's own payload operands: -> (the
+    key, sorted; each carried array, sorted). A payload is not a key:
+    the sort is noted as `sorted_with_permutation`'s, which carries
+    the row ids."""
     (key,) = key_arrays
-    iota = jnp.arange(capacity, dtype=jnp.int32)
     note_sort("packed", 1, 8 * key.dtype.itemsize, capacity)
-    out = lax.sort((key, iota), num_keys=1, is_stable=True)
-    return [out[0]], out[1]
+    out = lax.sort((key, *carried), num_keys=1, is_stable=True)
+    return [out[0]], list(out[1:])
 
 
 def sort_permutation(key_arrays: List[jnp.ndarray],

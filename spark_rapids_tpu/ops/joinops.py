@@ -36,6 +36,7 @@ from spark_rapids_tpu.ops.common import (
     equality_keys,
     normalize_floating,
     sort_permutation,
+    sorted_carrying,
     sorted_with_permutation,
 )
 from spark_rapids_tpu.sqltypes import StructField, StructType
@@ -142,16 +143,24 @@ class BuildIndex(NamedTuple):
     """Build side indexed for probing and left as it lies: a probe
     that found sorted position `lo` reads the batch's row `perm[lo]`,
     so only the rows a probe matched are ever moved (the fused
-    engine's lookup join, exec/fused.py `lookup_join`)."""
+    engine's lookup join, exec/fused.py `lookup_join`). In INDEX
+    ORDER the columns the join reads were sorted with the keys, each
+    as its one int32 array (`coded`), and a probe reads them at `lo`:
+    one read a slot where `perm[lo]` and the row take two."""
 
     batch: Optional[ColumnBatch]   # UNSORTED, live rows where `live` left
     #                                them; None: the join reads no column
     keys: List[jnp.ndarray]        # sorted orderable keys (excl. null rank)
     perm: Optional[jnp.ndarray]    # int32 [capacity]: sorted position ->
-    #                                row of `batch`; None with the batch
+    #                                row of `batch`; None with the batch,
+    #                                and in index order
     valid_bound: jnp.ndarray       # scalar int32: rows with non-null keys
     num_rows: jnp.ndarray          # scalar int32: live rows
     packing: Optional[KeyPacking] = None  # several keys read as `keys`
+    coded: Optional[Tuple[Optional[jnp.ndarray], ...]] = None
+    #    index order: for each column of `batch`, its `DeviceColumn.coded`
+    #    array in the keys' order, None where the join reads it not (the
+    #    batch is then only what decodes them); None: as it lies
 
     @property
     def capacity(self) -> int:
@@ -330,11 +339,14 @@ def _fits_32_bits(batch: ColumnBatch, key_idxs: Sequence[int]) -> bool:
 
 
 def build_index(batch: ColumnBatch, key_idxs: Sequence[int],
-                live: Optional[jnp.ndarray] = None) -> BuildIndex:
+                live: Optional[jnp.ndarray] = None,
+                carried: Sequence[jnp.ndarray] = ()) -> BuildIndex:
     """Everything of `build_side` up to and including the sort; the
     batch stays as it is. `live` marks the batch's rows where they are
     not at its front (plan_compiler.concat_in_place): the sort sends
-    the others last."""
+    the others last. `carried`: int32 arrays of the batch's length
+    that the sort takes into the keys' order as its payload, in place
+    of the row ids (`coded`, and no `perm`)."""
     cap = batch.capacity
     rows = batch.num_rows
     if live is None:
@@ -349,14 +361,21 @@ def build_index(batch: ColumnBatch, key_idxs: Sequence[int],
         # with the probe side's 64-bit ones as they are (promotion).
         vals = [jnp.where(all_valid, vals[0].astype(jnp.int32),
                           jnp.int32(_ABOVE_32))]
-        sorted_keys, perm = sorted_with_permutation(vals, cap)
+        if carried:
+            sorted_keys, moved = sorted_carrying(vals, list(carried), cap)
+        else:
+            sorted_keys, perm = sorted_with_permutation(vals, cap)
     else:
         # Sort null-keyed / dead rows to the end: leading rank 0 valid,
         # 1 not; one stable pass a key array, never one sort on all
         rank = jnp.where(all_valid, 0, 1).astype(jnp.int64)
         perm = sort_permutation([rank] + vals, cap)
         sorted_keys = [jnp.take(v, perm) for v in vals]
+        moved = [jnp.take(a, perm) for a in carried]
     valid_bound = jnp.sum(all_valid).astype(jnp.int32)
+    if carried:
+        return BuildIndex(batch, sorted_keys, None, valid_bound, rows,
+                          coded=tuple(moved))
     return BuildIndex(batch, sorted_keys, perm, valid_bound, rows)
 
 
@@ -742,6 +761,20 @@ def rows_at(build: BuildIndex, pos: jnp.ndarray
     7.1 (PERF.md, PR 30). tests/test_chip_compile.py holds the compiler
     to it at Q12's width."""
     return jnp.take(build.perm, pos), build.perm[0] < 0
+
+
+def coded_at(build: BuildIndex, pos: jnp.ndarray
+             ) -> Tuple[List[Optional[jnp.ndarray]], jnp.ndarray]:
+    """-> (each coded array of a build side in index order read at
+    sorted position `pos`, None where it carries none; `rows_at`'s
+    scalar: a plain read of each array, which the caller returns and
+    nothing reads). A coded array that is only gathered is the entry
+    parameter the compiler would prefetch in place of the sorted keys,
+    as it would the permutation."""
+    arrays = [a for a in build.coded if a is not None]
+    plain = jnp.any(jnp.stack([a[0] < -1 for a in arrays]))
+    return [None if a is None else read_entries(a, pos)
+            for a in build.coded], plain
 
 
 def front_row_ids(keep: jnp.ndarray, capacity: int

@@ -345,6 +345,96 @@ def test_late_lookup_join_at_q12_width(one_chip, as_tpu):
     _searches_read_rows(c.as_text())
 
 
+Q5_PART = Q12_PART        # `lineitem`'s parts, read at full width
+Q5_BUILD = Q12_BUILD      # `orders` under `customer`, 8 parts end to end
+
+
+def _q5_build(one_chip):
+    """The derived build side of TPC-H Q5's `lineitem` join as buildprep
+    sees it: `o_orderkey`, stamped to fit 32 bits, and `c_nationkey`,
+    the one column the chain reads."""
+    return _batch([_col(long, jnp.int64, Q5_BUILD, one_chip,
+                        vrange=(0, 2 ** 26 - 1)),
+                   _col(long, jnp.int64, Q5_BUILD, one_chip,
+                        vrange=(0, 31))],
+                  ["o_orderkey", "c_nationkey"], one_chip)
+
+
+def test_build_index_in_index_order_sorts_one_pair_at_q5_width(one_chip,
+                                                               as_tpu):
+    """`joinops.build_index` carrying the column the join reads, coded
+    as one 32-bit array (`DeviceColumn.coded`), in place of the row
+    ids: still ONE sort of two 32-bit operands, whose outputs are the
+    sorted keys and the column in their order, and no 15.7M-slot
+    gather."""
+    from spark_rapids_tpu.ops import joinops
+
+    batch = _q5_build(one_chip)
+    assert batch.columns[1].reads_as_one_array()
+
+    def kernel(batch, live):
+        idx = joinops.build_index(batch, [0], live,
+                                  [batch.columns[1].coded()])
+        assert idx.perm is None
+        return idx.keys[0], idx.coded[0], idx.valid_bound, idx.num_rows
+
+    c, lowered, _ = _timed_compile(kernel, batch,
+                                   _sds((Q5_BUILD,), jnp.bool_, one_chip))
+    assert _sort_operands(lowered) == [2]
+    text = c.as_text()
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert len(sorts) == 1 and "s64" not in sorts[0].split(" sort(")[0]
+    assert " gather(" not in text
+    assert c.memory_analysis().temp_size_in_bytes < (256 << 20)
+
+
+def test_late_lookup_join_in_index_order_at_q5_width(one_chip, as_tpu):
+    """The chain's side over that index, at a part's full width: the
+    search, then `c_nationkey` read at `lo` itself — ONE element a slot
+    where `perm[lo]` and then the column were two (541 + 449 ms of
+    Q5's query: PERF.md section 5) — and the plain read of the moved
+    column that `rows_at` makes of the permutation (`coded_at`). The
+    other element read is the key after each match, in the branch
+    taken only where the index holds a key twice."""
+    from spark_rapids_tpu.ops import joinops
+
+    batch = _q5_build(one_chip)
+    build = joinops.BuildIndex(
+        batch, [_sds((Q5_BUILD,), jnp.int32, one_chip)], None,
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        coded=(None, _sds((Q5_BUILD,), jnp.int32, one_chip)))
+    probe = _batch([_col(long, jnp.int64, Q5_PART, one_chip)],
+                   ["l_orderkey"], one_chip)
+
+    def kernel(bt, probe):
+        lo, matched = joinops.probe_matched(bt, probe, [0])
+        dup = joinops.second_match(bt, probe, [0], lo, matched)
+        got, plain_read = joinops.coded_at(
+            bt, jnp.clip(lo, 0, bt.capacity - 1))
+        col = bt.batch.columns[1].decoded(got[1])
+        # as the chain returns them: the flags, then what nothing reads
+        return (col.data, col.validity & matched,
+                jnp.stack([jnp.any(dup), plain_read]))
+
+    c, _, _ = _timed_compile(kernel, build, probe)
+    lines = c.as_text().splitlines()
+    entry = lines[next(i for i, ln in enumerate(lines)
+                       if ln.startswith("ENTRY")):]
+    params = [ln.split(" = ")[0].strip() for ln in entry
+              if " parameter(" in ln and f"s32[{Q5_BUILD}]" in ln]
+    assert len(params) == 2          # the keys and the moved column
+    elements = [ln for ln in lines if " gather(" in ln
+                and "slice_sizes={1}" in ln
+                and f"[{Q5_PART}]" in ln.split(" gather(")[0]]
+    assert len(elements) == 2, elements
+    assert sum(" conditional(" in ln for ln in lines) == 2
+    plain = [ln for ln in entry if " slice(" in ln
+             and ln.split(" = ")[1].startswith("s32[1]")
+             and any(f"slice({p})" in ln for p in params)]
+    assert len(plain) == 1, plain
+    assert c.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
 def _searches_read_rows(text):
     """What a Q12 chain program's time hangs on since PR 34, at SF10's
     widths: the survivors' row-ids and the one search of the build
@@ -860,10 +950,12 @@ def test_tpch_q5s_own_chain_at_sf10_reads_two_columns_an_element_a_slot(
         tpch_q5_chain, one_chip, no_persistent_cache):
     """The program Q5 spends its time in, lowered again at SF10's
     widths: of everything its four joins read at a part's full width
-    (7,864,320 slots), only the index's row ids and the ONE 32-bit
-    array `c_nationkey` is read as are gathers of an element a slot
-    (from 15.7M-slot arrays: no table of them is small enough for
-    rows). The two supplier keys are packed into one and read by
+    (7,864,320 slots), only the ONE 32-bit array `c_nationkey` is a
+    gather of an element a slot (from a 15.7M-slot array: too large
+    to be read as rows), at `lo` itself: the index carries it in its
+    own order (`buildOrder: index`), where the row ids at `lo` and
+    then the column were two such gathers, 541 + 449 ms of a query
+    (PERF.md section 5). The two supplier keys are packed into one and read by
     position, a row of the 4,194,304-entry table a slot, and every
     column of `supplier` and `nation` a later join reads is a row of
     128 entries a slot: the first tree read 13 such element gathers, 7
@@ -878,6 +970,9 @@ def test_tpch_q5s_own_chain_at_sf10_reads_two_columns_an_element_a_slot(
     assert [(jp["probe"], jp["bet"]) for jp in plan] == [
         ("search", ""), ("position", ""), ("position", ""),
         ("position", "buildFilter")]
+    assert [jp["buildOrder"] for jp in plan] == ["index"] + ["asLies"] * 3
+    assert index.perm is None
+    assert [a is not None for a in index.coded].count(True) == 1
     assert suppliers.packing.ranges[1] == (0, 31)
     small, built = probe.capacity, index.capacity
     dims = {small: H3_PART, built: H3_BUILD,
@@ -920,10 +1015,10 @@ def test_tpch_q5s_own_chain_at_sf10_reads_two_columns_an_element_a_slot(
     lines = c.as_text().splitlines()
     elements = [ln for ln in lines if " gather(" in ln
                 and f"[{H3_PART}]" in ln.split(" gather(")[0]]
-    # the third is the key after each match, in a branch taken only
+    # the second is the key after each match, in a branch taken only
     # where the index holds a key twice (joinops.second_match): the
     # orders of SF10 hold none. The other conditional is the search's
-    assert len(elements) == 3, elements
+    assert len(elements) == 2, elements
     assert sum(" conditional(" in ln for ln in lines) == 2
     rows = [ln for ln in lines if " gather(" in ln
             and "slice_sizes={1,128}" in ln]

@@ -221,6 +221,156 @@ def test_a_build_side_with_a_key_twice_falls_to_the_expanding_join(spark):
                                np.sum((k % 3 == 0) & (k < 30)))
 
 
+# --- index order: an index read at more slots than it holds ---
+
+WIDE_ROWS = 200_000  # 262,144 probe slots, twice the build side's
+SPARSE = 3_000       # key spacing: a range of 9M, searched, not a table
+
+
+def wide_tables(extra=None, twice=False):
+    """A probe side with no filter whose every slot reads the build
+    side, which is two parquet halves of 3,000 sparse keys (131,072
+    slots): the key and `bv`, nulls in both, and `extra`, a column
+    that does not read as one 32-bit array (`tag`, a dictionary
+    string; `d`, a double). `twice`: ten keys held twice."""
+    rng = np.random.default_rng([42, twice])
+    bk = np.arange(BUILD_ROWS, dtype=np.int64) * SPARSE
+    k = rng.choice(np.concatenate([bk, bk + 1]), WIDE_ROWS)
+    probe = pa.table({
+        "k": pa.array(k, mask=rng.random(WIDE_ROWS) < 0.05),
+        "v": pa.array(rng.random(WIDE_ROWS)),
+        "row": pa.array(np.arange(WIDE_ROWS, dtype=np.int64))})
+    order = rng.permutation(BUILD_ROWS)
+    if twice:
+        order = np.concatenate([order, order[:10]])
+    cols = {"bk": pa.array(bk[order], mask=rng.random(len(order)) < 0.05),
+            "bv": pa.array(order.astype(np.int64) * 10,
+                           mask=rng.random(len(order)) < 0.1)}
+    if extra == "tag":
+        cols["tag"] = pa.array([TAGS[i % 4] for i in order])
+    elif extra == "d":
+        cols["d"] = pa.array(order / 7.0)
+    build = pa.table(cols)
+    return probe, [build.slice(0, 1_000), build.slice(1_000)]
+
+
+def wide_join(spark, probe, halves, how, at, probe_share=None,
+              build_below=None):
+    """`probe_share`: a filter on the probe side (the `probeFilter`
+    bet); `build_below`: one on the build side's `bv` (`buildFilter`)."""
+    p = spark.createDataFrame(probe)
+    if probe_share is not None:
+        p = p.filter(F.col("v") < probe_share)
+    os.makedirs(str(at), exist_ok=True)
+    for i, half in enumerate(halves):
+        pq.write_table(half, os.path.join(str(at), f"part-{i}.parquet"))
+    b = spark.read.parquet(str(at))
+    if build_below is not None:
+        b = b.filter(F.col("bv") < build_below)
+    return p.join(b, F.col("k") == F.col("bk"), how)
+
+
+def wide_rows(table) -> collections.Counter:
+    names = [c for c in ("row", "bk", "bv", "tag", "d")
+             if c in table.column_names]
+    return collections.Counter(
+        zip(*(table.column(c).to_pylist() for c in names)))
+
+
+def plain_wide_join(probe, halves, how, probe_share=None,
+                    build_below=None) -> collections.Counter:
+    build = pa.concat_tables(halves)
+    names = build.column_names
+    by_key = collections.defaultdict(list)
+    for r in zip(*(build.column(c).to_pylist() for c in names)):
+        bk, bv = r[0], r[1]
+        if bk is not None and (build_below is None
+                               or (bv is not None and bv < build_below)):
+            by_key[bk].append(r)
+    out = collections.Counter()
+    for k, v, row in zip(*(probe.column(c).to_pylist()
+                           for c in ("k", "v", "row"))):
+        if probe_share is not None and not v < probe_share:
+            continue
+        matches = by_key.get(k) or ([(None,) * len(names)]
+                                    if how == "left" else [])
+        for m in matches:
+            out[(row,) + m] += 1
+    return out
+
+
+def as_lies(monkeypatch):
+    """The session's answers with every index left as it lies."""
+    monkeypatch.setattr(fused, "index_order", lambda *a: ())
+
+
+@pytest.mark.parametrize("build", ["unique", "a_key_twice"])
+@pytest.mark.parametrize("how", ["inner", "left"])
+def test_an_index_read_at_more_slots_than_it_holds_is_in_index_order(
+        tmp_path, monkeypatch, how, build):
+    """Every probe slot reads the build columns, 262,144 of them from
+    131,072: the index carries them in its own order and they are read
+    at `lo`, and the rows are those of the index as it lies, a null in
+    a build column and a left join's misses included. A key held twice
+    still loses the bet on unique keys, and the join falls to the
+    expanding one."""
+    probe, halves = wide_tables(twice=build == "a_key_twice")
+    want = plain_wide_join(probe, halves, how)
+    answers = []
+    for lies in (False, True):
+        if lies:
+            as_lies(monkeypatch)
+        spark = TpuSparkSession(CONF)
+        try:
+            got = wide_join(spark, probe, halves, how,
+                            tmp_path / str(lies)).collect_arrow()
+            rec = spark.last_execution["join"]
+            j = the_join(spark)
+        finally:
+            spark.stop()
+        answers.append(wide_rows(got))
+        if build == "a_key_twice":
+            assert rec["rerunReasons"] == ["uniquenessLost"]
+            assert j["lowering"] == "expand" and "buildOrder" not in j
+        else:
+            assert rec["runs"] == 1
+            assert (j["lowering"], j["probe"]) == ("lookup", "search")
+            assert j["outputCapacity"] > j["buildSlots"] == 2 * SLOTS
+            assert j["buildOrder"] == ("asLies" if lies else "index")
+    assert answers[0] == answers[1] == want
+    assert sum(want.values()) > WIDE_ROWS // 3
+
+
+@pytest.mark.parametrize("bet", ["probeFilter", "buildFilter"])
+def test_a_join_whose_bet_holds_keeps_its_index_as_it_lies(
+        spark, keyed, tmp_path, bet):
+    """TPC-H Q12's shape (a filter on the probe side) and Q3's (on the
+    build side): the columns are read at the 1/64 the bet brings to
+    the front, fewer slots than the build side holds."""
+    share, below = (0.01, None) if bet == "probeFilter" else (None, 300)
+    probe, halves = wide_tables()
+    got = wide_join(spark, probe, halves, "inner", tmp_path,
+                    probe_share=share, build_below=below).collect_arrow()
+    j = the_join(spark)
+    assert (j["lowering"], j["bet"]) == ("lookupSurvivors", bet)
+    assert j["outputCapacity"] < j["buildSlots"]
+    assert j["buildOrder"] == "asLies"
+    assert keyed and "buildOrder" not in repr([k for _, k, _ in keyed])
+    assert wide_rows(got) == plain_wide_join(
+        probe, halves, "inner", share, below)
+
+
+@pytest.mark.parametrize("extra", ["tag", "d"])
+def test_a_column_not_read_as_one_array_keeps_the_index_as_it_lies(
+        spark, tmp_path, extra):
+    probe, halves = wide_tables(extra)
+    got = wide_join(spark, probe, halves, "left", tmp_path).collect_arrow()
+    j = the_join(spark)
+    assert j["outputCapacity"] > j["buildSlots"]
+    assert (j["probe"], j["buildOrder"]) == ("search", "asLies")
+    assert wide_rows(got) == plain_wide_join(probe, halves, "left")
+
+
 # --- the program keys ---
 
 @pytest.fixture()
@@ -237,22 +387,37 @@ def keyed(monkeypatch):
     return seen
 
 
-@pytest.mark.parametrize("how", ["inner", "left_semi"])
-def test_program_keys_carry_what_the_join_reads(spark, keyed, tmp_path, how):
+@pytest.mark.parametrize("how, order", [("inner", "asLies"),
+                                        ("left_semi", "asLies"),
+                                        ("inner", "index")])
+def test_program_keys_carry_what_the_join_reads(spark, keyed, tmp_path, how,
+                                                order):
     """The parent's `buildprep` made a sorted batch under the key
     without the mark: the mark names these programs apart from it
     (`fused_buildprep_<digest>`, `fused_chain_<digest>` in the trace
-    and the ledger's breakdown)."""
-    probe, halves = tables(0)
-    query(spark, probe, halves, how, at=tmp_path).collect_arrow()
-    gather = the_join(spark)["buildGather"]
+    and the ledger's breakdown). An index in index order is marked
+    after it, with the columns that ride its sort: no cache hands a
+    chain that reads at `lo` an index made as it lies, or the
+    reverse."""
+    if order == "index":
+        probe, halves = wide_tables()
+        wide_join(spark, probe, halves, how, tmp_path).collect_arrow()
+    else:
+        probe, halves = tables(0)
+        query(spark, probe, halves, how, at=tmp_path).collect_arrow()
+    j = the_join(spark)
+    gather = j["buildGather"]
+    assert j["buildOrder"] == order
     named = list(keyed)
-    marks = {"buildprep": ("buildGather", gather),
-             "chain": ("buildGather", (gather,))}
+    marks = {"buildprep": [("buildGather", gather)],
+             "chain": [("buildGather", (gather,))]}
+    if order == "index":
+        marks["buildprep"].append(("buildOrder", "index", (0, 1)))
+        marks["chain"].append(("buildOrder", ("index",)))
     for kind, mark in marks.items():
         ((key, name),) = {(key, name) for tag, key, name in named
                           if tag == kind}
-        assert key[-1] == mark
+        assert list(key[-len(mark):]) == mark
         assert name != fused.program_name(kind, key[:-1])
     assert not [key for tag, key, _ in named
                 if tag not in marks and "buildGather" in repr(key)]
@@ -324,3 +489,35 @@ def test_build_index_leaves_the_batch_and_returns_the_sorts_own_keys(
                               np.asarray(up_front.data)[hit])
         assert np.array_equal(np.asarray(late.validity)[hit],
                               np.asarray(up_front.validity)[hit])
+
+
+@pytest.mark.parametrize("base, narrow", [(0, True), (2 ** 33, False)],
+                         ids=["32_bit", "64_bit"])
+def test_build_index_carries_coded_columns_in_the_keys_order(base, narrow):
+    """In index order the sort's payload is each carried column's one
+    32-bit array, and it comes out as the batch's rows in the keys'
+    order: what the permutation would have read, with no permutation."""
+    _, halves = tables(base)
+    build = pa.concat_tables(halves)
+    batch = arrow_to_device(build)
+    if narrow:
+        bk = build.column("bk").drop_null().to_numpy()
+        batch = ColumnBatch(batch.schema, [batch.columns[0].replace(
+            vrange=(int(bk.min()), int(bk.max())))] + list(
+                batch.columns[1:]), batch.num_rows)
+    bv = batch.columns[1].replace(vrange=(0, 10 * BUILD_ROWS))
+    assert bv.reads_as_one_array()
+    live = jnp.asarray(np.random.default_rng(4).random(batch.capacity) < 0.9
+                       ) & batch.live_mask()
+    lies = joinops.build_index(batch, [0], live)
+    ordered = joinops.build_index(batch, [0], live, [bv.coded()])
+    assert ordered.perm is None and len(ordered.coded) == 1
+    assert np.array_equal(np.asarray(ordered.keys[0]),
+                          np.asarray(lies.keys[0]))
+    n = int(lies.valid_bound)
+    perm = np.asarray(lies.perm)[:n]
+    got = bv.decoded(ordered.coded[0][:n])
+    assert np.array_equal(np.asarray(got.validity),
+                          np.asarray(bv.validity)[perm])
+    assert np.array_equal(np.asarray(got.data)[np.asarray(got.validity)],
+                          np.asarray(bv.data)[perm][np.asarray(got.validity)])
