@@ -751,7 +751,8 @@ class DataFrame:
 
         rec = {"engine": None, "fallbacks": [], "compile": None,
                "degradations": [], "scheduler": None, "plan": None,
-               "join": None, "agg": None, "groups": None, "sort": None}
+               "join": None, "agg": None, "groups": None, "sort": None,
+               "union": None, "expand": None}
         self._last_exec = rec
         self.session.last_execution = rec
         # admission front door (runtime/admission.py): the OUTERMOST
@@ -1044,6 +1045,8 @@ class DataFrame:
                     rec["agg"] = ex.last_agg_metrics
                     rec["groups"] = ex.last_group_metrics
                     rec["sort"] = ex.last_sort_metrics
+                    rec["union"] = ex.last_union_metrics
+                    rec["expand"] = ex.last_expand_metrics
                     breaker.record_success(fkey)
                     return ran("fused", out)
                 except FusedCompileError as e:

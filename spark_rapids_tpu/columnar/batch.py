@@ -172,6 +172,26 @@ class DeviceColumn:
             else self.elem_lengths[:cap],
             encoding=self.encoding, epoch=self.epoch)
 
+    def padded(self, cap: int) -> "DeviceColumn":
+        """`truncate`'s converse: every per-row leaf grown to `cap` rows
+        with zeros (trace-safe; a pad, not a gather), so the rows added
+        are null and their values zero; the dictionary rides
+        unchanged."""
+        def pad(x):
+            if x is None or x.shape[0] == cap:
+                return x
+            return jnp.pad(x, ((0, cap - x.shape[0]),)
+                           + ((0, 0),) * (x.ndim - 1))
+
+        return DeviceColumn(
+            self.dtype, pad(self.data), pad(self.validity),
+            pad(self.lengths), pad(self.elem_validity),
+            pad(self.map_values), self.vrange,
+            None if self.children is None
+            else [c.padded(cap) for c in self.children],
+            pad(self.elem_lengths), encoding=self.encoding,
+            epoch=self.epoch)
+
     def device_size_bytes(self) -> int:
         n = self.data.size * self.data.dtype.itemsize
         n += self.validity.size  # bool = 1 byte

@@ -67,7 +67,7 @@ from spark_rapids_tpu.exec import agg_pushdown
 from spark_rapids_tpu.exec import joins as J
 from spark_rapids_tpu.exec import operators as ops
 from spark_rapids_tpu.exec.base import PhysicalPlan
-from spark_rapids_tpu.ops import filterops, joinops
+from spark_rapids_tpu.ops import common, filterops, joinops
 from spark_rapids_tpu.runtime import faults
 from spark_rapids_tpu.runtime.errors import TpuSplitAndRetryOOM
 from spark_rapids_tpu.sqltypes import StringType, StructType
@@ -346,6 +346,10 @@ def shrink_traced(batch: ColumnBatch, cap2: int):
 _SURVIVOR_SHARE = 64
 _SURVIVOR_ALIGN = 1024
 
+#: a Union whose parts hold this many slots or fewer hands them on as
+#: one batch (`emit_parts`)
+_UNION_LAID_SLOTS = 1 << 20
+
 
 def survivor_capacity(n: int) -> Optional[int]:
     """The static capacity a batch of capacity `n` brings its filter's
@@ -515,6 +519,18 @@ class FusedSingleChipExecutor:
         #: session.last_execution["groups"]
         self.last_group_metrics = None
         self._run_groups: List[dict] = []
+        #: the settled run's UNION ALLs, one {"branches", "parts",
+        #: "branchSlots", "laidIntoOne", "paddedSlots"} each in the
+        #: order they were emitted, under "unions", or None where it had
+        #: none: session.last_execution["union"]
+        self.last_union_metrics = None
+        self._run_unions: List[dict] = []
+        #: the settled run's Expands, one {"program", "sets",
+        #: "inputSlots", "outputSlots"} a dispatch and Expand under
+        #: "lowerings", or None where it had none:
+        #: session.last_execution["expand"]
+        self.last_expand_metrics = None
+        self._run_expands: List[dict] = []
 
     # --- source preparation (once; survives expansion retries) ---
 
@@ -757,6 +773,12 @@ class FusedSingleChipExecutor:
                         s["operands"] for s in self._run_sorts),
                     "lowerings": self._run_sorts,
                 } if self._run_sorts else None
+                self.last_union_metrics = {
+                    "unions": self._run_unions,
+                } if self._run_unions else None
+                self.last_expand_metrics = {
+                    "lowerings": self._run_expands,
+                } if self._run_expands else None
                 return out
             except SurvivorOverflow as e:
                 self._wide_joins.update(e.joins)
@@ -843,6 +865,10 @@ class FusedSingleChipExecutor:
                 sp.set(groups=self.last_group_metrics)
             if self.last_sort_metrics is not None:
                 sp.set(sort=self.last_sort_metrics)
+            if self.last_union_metrics is not None:
+                sp.set(union=self.last_union_metrics)
+            if self.last_expand_metrics is not None:
+                sp.set(expand=self.last_expand_metrics)
             return out
 
     def _oom_injection_eager_fallback(self, phys: PhysicalPlan):
@@ -990,6 +1016,8 @@ class FusedSingleChipExecutor:
         self._run_groups = []
         self._run_agg = collections.Counter()
         self._run_sorts = []
+        self._run_unions = []
+        self._run_expands = []
         ansi_on = self._ansi
         # ANSI checks see pre-join row visibility; the pushdown's
         # pre-aggregate would evaluate agg inputs on probe rows the
@@ -1106,6 +1134,12 @@ class FusedSingleChipExecutor:
                 sp.set(sort=sorts)
                 self._run_sorts.extend(dict(s, program=name)
                                        for s in sorts)
+            # and so were its Expands
+            expands = jc.expand_lowerings(key)
+            if expands:
+                sp.set(expand=expands)
+                self._run_expands.extend(dict(e, program=name)
+                                         for e in expands)
             # fl: scalar=[cap] | [cap, uniq, push] (chain programs), then
             # one lost-bet flag for each of `survivor_joins`, then what
             # nothing reads (joinops.rows_at); rest: the ANSI vector
@@ -1331,9 +1365,12 @@ class FusedSingleChipExecutor:
                     b = nd._run(b)  # row-preserving; mask stays aligned
                 elif isinstance(nd, ops.TpuExpandExec):
                     b, mask = materialized(b, mask), None
+                    slots = b.capacity
                     b = concat_traced(
                         [nd._run(b, i)
                          for i in range(len(nd.projections))])
+                    common.note_expand(len(nd.projections), slots,
+                                       b.capacity)
                 elif isinstance(nd, ops.TpuCoalesceBatchesExec):
                     pass  # identity: the stage input is one batch
                 elif isinstance(nd, ops.TpuGenerateExec):
@@ -1400,7 +1437,48 @@ class FusedSingleChipExecutor:
                 # single chip: every partition is already co-resident
                 return emit_parts(node.children[0], read)
             if isinstance(node, ops.UnionExec):
-                return [b for c in node.children for b in emit_parts(c)]
+                # the branches' parts laid end to end, each going on to
+                # the programs above as it is. Above the Union every
+                # part SHAPE is a program of its own, a compile (15-45 s
+                # on a TPU v5e at TPC-DS q5's sizes) and a launch, so
+                # parts this small are laid into ONE batch, and larger
+                # ones of unequal capacities (a fact's parts and its
+                # returns') are padded to the widest where that adds at
+                # most half the slots the Union holds
+                branches = [emit_parts(c) for c in node.children]
+                parts = [b for ps in branches for b in ps]
+                slots = [sum(b.capacity for b in ps) for ps in branches]
+                widest = max(b.capacity for b in parts)
+                pad = sum(widest - b.capacity for b in parts)
+                laid = len(parts) > 1 and sum(slots) <= _UNION_LAID_SLOTS
+                padded = not laid and 0 < 2 * pad <= sum(slots)
+                self._run_unions.append({
+                    "branches": len(branches), "parts": len(parts),
+                    "branchSlots": slots, "laidIntoOne": laid,
+                    "paddedSlots": pad if padded else 0})
+                if laid:
+                    def union_fn(*ps):
+                        return (concat_traced(concat_inputs(list(ps))),
+                                jnp.zeros((), bool))
+
+                    return [run_program("union", ("union",), union_fn,
+                                        parts)]
+                if padded:
+                    def pad_fn(b, _to=widest):
+                        # a pad and not a gather: an element gather of
+                        # every column at 3.67M slots took 1.4 s of a q5
+                        # query on a TPU v5e
+                        return (ColumnBatch(b.schema,
+                                            [c.padded(_to)
+                                             for c in b.columns],
+                                            b.num_rows),
+                                jnp.zeros((), bool))
+
+                    parts = [b if b.capacity == widest
+                             else run_program("pad", ("pad", widest),
+                                              pad_fn, [b])
+                             for b in parts]
+                return parts
             if chainable(node):
                 nodes, cur = collect_chain(node)
                 base = emit_parts(cur)
@@ -1776,6 +1854,12 @@ class FusedSingleChipExecutor:
                     jp["keyColumns"] = len(nd.right_keys)
                     jp["keyArrays"] = arrays
                     jp["packedBits"] = bt.packing.bits if bt.packing else 0
+                    # the width of the one key a probe compares: 8 is
+                    # the emulated 64-bit path on a TPU v5e
+                    jp["keyBytes"] = (
+                        8 if bt.packing and bt.packing.bits > 31 else 4
+                    ) if by_position else max(
+                        k.dtype.itemsize for k in bt.keys)
                     # one array is searched a row of 128 keys a level; a
                     # tuple of them a key of each a step, a step a bit
                     # of the slots (joinops._binary_search)
@@ -1848,7 +1932,9 @@ class FusedSingleChipExecutor:
             no more than `probed_slots`, the slots the chain programs
             probe it from, times the steps of a search of the sorted
             index — and it has no more entries than the chain's input
-            has slots (`chain_slots`: 4 bytes a fact row at most). A
+            has slots (`chain_slots`: 4 bytes a fact row at most), or
+            where the table, one chunk, has fewer entries than the
+            build side has slots, whatever the probes. A
             sparse key under a selective probe-side filter (TPC-H
             Q12's `o_orderkey`: 67M values for 983,040 survivors x 24
             steps) keeps the sorted index and its search
@@ -1912,7 +1998,13 @@ class FusedSingleChipExecutor:
                 chunks = -(-(entries + 1) // joinops.TABLE_CHUNK)
                 by_position = (
                     entries < min(probed_slots * steps, chain_slots)
-                    and (chunks == 1 or chunks * slots <= probed_slots))
+                    and (chunks == 1 or chunks * slots <= probed_slots)
+                    # a table of one chunk with no more entries than the
+                    # build side has slots costs less to write than the
+                    # sort it saves, however many slots probe it (the
+                    # probes of a join above an aggregate are not known:
+                    # TPC-DS q5's 102 stores, 65,536 slots)
+                    or (chunks == 1 and entries < slots))
             in_index_order = () if by_position or gather != "matched" \
                 else index_order(parts, columns_read, read_slots)
             # the program cache keeps `bp_fn`, so it closes over no

@@ -281,6 +281,30 @@ def note_sort(how: str, operands: int, key_bits: int, slots: int,
                       "slots": slots})
 
 
+_EXPAND_NOTES = contextvars.ContextVar("srtpu_expand_notes", default=None)
+
+
+@contextmanager
+def noting_expands():
+    """Collect the Expands traced inside, as `noting_sorts` collects
+    sorts: a list of {"sets", "inputSlots", "outputSlots"}
+    (runtime/jit_cache.py keeps it with the program, exec/fused.py
+    reports it as `expand`)."""
+    notes: list = []
+    tok = _EXPAND_NOTES.set(notes)
+    try:
+        yield notes
+    finally:
+        _EXPAND_NOTES.reset(tok)
+
+
+def note_expand(sets: int, input_slots: int, output_slots: int) -> None:
+    notes = _EXPAND_NOTES.get()
+    if notes is not None:
+        notes.append({"sets": sets, "inputSlots": input_slots,
+                      "outputSlots": output_slots})
+
+
 def _u32(x: jnp.ndarray) -> jnp.ndarray:
     return x.astype(jnp.uint32)
 

@@ -120,10 +120,12 @@ def _make_entry(key: Tuple, build: Callable[[], Callable],
                 # reported with every later dispatch stays with it
                 with obs_events.span("compile", kind=tag) as sp, \
                         _segmented().noting_sum_lowerings() as noted, \
-                        common.noting_sorts() as sorts:
+                        common.noting_sorts() as sorts, \
+                        common.noting_expands() as expands:
                     out = jitted(*args, **kwargs)
                     entry.sum_lowerings = noted
                     entry.sort_lowerings = sorts
+                    entry.expand_lowerings = expands
                     # async dispatch returns once tracing+compilation
                     # are done (execution overlaps) — the cold-start
                     # quantity
@@ -169,6 +171,14 @@ def sort_lowerings(key: Tuple) -> list:
     group-bys (ops/common.py `noting_sorts`), as `sum_lowerings`."""
     fn = _cache.get(_mem_key(key + _env_token()))
     return getattr(fn, "sort_lowerings", [])
+
+
+def expand_lowerings(key: Tuple) -> list:
+    """The Expands the resident program for `key` traced, with their
+    grouping sets and slots (ops/common.py `noting_expands`), as
+    `sum_lowerings`."""
+    fn = _cache.get(_mem_key(key + _env_token()))
+    return getattr(fn, "expand_lowerings", [])
 
 
 def cache_size() -> int:

@@ -1144,3 +1144,26 @@ def test_all_to_all_batch_on_four_chips(topo, as_tpu):
     per_device = c.memory_analysis().argument_size_in_bytes
     whole = total * (8 + 1 + 8 + 1) + 4 * n
     assert per_device <= whole // n + 1024, (per_device, whole)
+
+
+def test_a_union_part_padded_to_its_siblings_width_is_a_pad(one_chip,
+                                                             as_tpu):
+    """TPC-DS q5's `store_returns` part padded to the sales parts'
+    3,670,016 slots (`DeviceColumn.padded`, the Union's `fused_pad_*`
+    program): pads, no gather. An element gather of every column at
+    that width took 1.4 s of a query on the chip (PERF.md, PR 43)."""
+    n, to = 2_883_584, 3_670_016
+    cols = [_col(long, jnp.int64, n, one_chip, vrange=(0, 127)),
+            _col(double, jnp.float64, n, one_chip)]
+    batch = _batch(cols, ["store_sk", "sales_price"], one_chip)
+
+    def kernel(b):
+        return ColumnBatch(b.schema, [c.padded(to) for c in b.columns],
+                           b.num_rows)
+
+    text = _compile(kernel, batch).as_text()
+    assert " gather(" not in text and " pad(" in text
+    out = jax.eval_shape(kernel, batch)
+    assert all(c.data.shape == (to,) and c.validity.shape == (to,)
+               for c in out.columns)
+    assert out.columns[0].vrange == (0, 127)

@@ -287,6 +287,47 @@ def test_a_two_key_join_probes_as_one_packed_key_where_it_fits(
         else slots.bit_length() * 2)
 
 
+# (spans of the two keys) -> (packedBits, keyBytes): 29 bits search a
+# 32-bit key, 33 the 64-bit one a TPU v5e emulates
+LEFT_LAYOUTS = {"int32_key": ((2 ** 17, 2 ** 12), 29, 4),
+                "int64_key": ((2 ** 21, 2 ** 12), 33, 8)}
+
+
+@pytest.mark.parametrize("layout", sorted(LEFT_LAYOUTS))
+def test_a_left_join_on_a_packed_key_keeps_every_probe_row(spark, layout):
+    """TPC-DS q5's `web_returns LEFT OUTER JOIN web_sales` on (item,
+    order number): a row that matches nothing keeps its place, its
+    build columns null, as a plain numpy join of the pairs gives."""
+    spans, bits, width = LEFT_LAYOUTS[layout]
+    child, parent = two_key_tables(*spans)
+    c, p = spark.createDataFrame(child), spark.createDataFrame(parent)
+    got = c.join(p, (F.col("ck1") == F.col("pk1"))
+                 & (F.col("ck2") == F.col("pk2")), "left") \
+        .select("row", "pv").collect_arrow()
+    rec = spark.last_execution
+    assert rec["engine"] == "fused" and not rec["fallbacks"]
+    (j,) = rec["join"]["joins"]
+    assert (j["lowering"], j["joinType"], j["probe"]) == \
+        ("lookup", "left", "search")
+    assert (j["keyArrays"], j["packedBits"], j["keyBytes"]) == \
+        (1, bits, width)
+    span2 = spans[1]
+    pk = (parent.column("pk1").to_numpy() * span2
+          + parent.column("pk2").to_numpy())
+    ck = (child.column("ck1").to_numpy() * span2
+          + child.column("ck2").to_numpy())
+    order = np.argsort(pk)
+    at = np.clip(np.searchsorted(pk[order], ck), 0, len(pk) - 1)
+    hit = pk[order][at] == ck
+    pv = parent.column("pv").to_numpy()[order][at]
+    want = dict(zip(child.column("row").to_pylist(),
+                    [int(v) if h else None for v, h in zip(pv, hit)]))
+    assert 0.3 < hit.mean() < 0.7  # rows that match and rows that do not
+    assert got.num_rows == child.num_rows
+    assert dict(zip(got.column("row").to_pylist(),
+                    got.column("pv").to_pylist())) == want
+
+
 def test_a_duplicated_pair_expands_and_stays_right(spark):
     child, parent = two_key_tables(200, 25, dup=True)
     got = joined(spark, child, parent).collect_arrow()

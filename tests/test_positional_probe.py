@@ -318,3 +318,33 @@ def test_a_join_that_lost_its_bet_yields_to_the_next_that_still_bets(spark):
             65_536, CAPACITY)
         assert joins[0]["bet"] == "buildFilter" and joins[1]["bet"] == ""
     assert rec["compile"]["programsCompiled"] == 0
+
+
+def test_a_join_above_an_aggregate_reads_a_small_dimension_by_position(
+        spark):
+    """The probes of a join above an aggregate are not known, so the
+    rule that weighs a table against them cannot take it; a table of
+    one chunk with fewer entries than the build side's slots costs less
+    to write than the sort it saves, and is read by position (TPC-DS
+    q5: 102 stores after the pushed-down pre-aggregate)."""
+    rng = np.random.default_rng(5)
+    facts = pa.table({"k": pa.array(rng.integers(0, 100, PROBE_ROWS)),
+                      "v": pa.array(rng.random(PROBE_ROWS))})
+    dim = pa.table({"dk": pa.array(np.arange(100, dtype=np.int64)),
+                     "name": pa.array([f"n{i % 7}" for i in range(100)])})
+    got = (spark.createDataFrame(facts).groupBy("k")
+           .agg(F.sum("v").alias("v"))
+           .join(spark.createDataFrame(dim), F.col("k") == F.col("dk"))
+           .groupBy("name").agg(F.sum("v").alias("v"))).collect_arrow()
+    rec = spark.last_execution
+    assert rec["engine"] == "fused" and not rec["fallbacks"]
+    (j,) = rec["join"]["joins"]
+    assert j["probeSlots"] is None and j["probe"] == "position"
+    want = collections.defaultdict(float)
+    for k, v in zip(facts.column("k").to_pylist(),
+                    facts.column("v").to_pylist()):
+        want[f"n{k % 7}"] += v
+    answer = dict(zip(got.column("name").to_pylist(),
+                      got.column("v").to_pylist()))
+    assert answer.keys() == want.keys()
+    assert all(answer[n] == pytest.approx(want[n]) for n in want)
